@@ -74,6 +74,8 @@ import os
 import sys
 import traceback
 
+from repro.launch import compile_cache
+
 MODULES = [
     "benchmarks.bench_pe_tables",       # paper Tables 4-9
     "benchmarks.bench_fig11",           # paper Fig 11 (CPF/FPC/%peak/alpha)
@@ -233,6 +235,7 @@ def main() -> None:
         # before the benchmark modules import and touch ops: the tuner reads
         # the env at first kernel call
         os.environ["REPRO_AUTOTUNE"] = "1"
+    compile_cache.enable()
     filters = args.only.split(",") if args.only else None
     print("name,us_per_call,derived")
     failed = []

@@ -47,7 +47,6 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import quant as _quant
 
@@ -109,11 +108,11 @@ def all_gather_gemm(a, b, mesh, axis: str = "model"):
         return jnp.dot(a_loc, b_full, preferred_element_type=acc).astype(out_dt)
 
     b_spec = _qt_spec(b, P(axis, None)) if packed else P(axis, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), b_spec),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 
@@ -153,11 +152,11 @@ def ring_gemm(a, b, mesh, axis: str = "model"):
         return out.astype(out_dt)
 
     b_spec = _qt_spec(b, P(axis, None)) if packed else P(axis, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), b_spec),
         out_specs=P(axis, None),
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 
@@ -179,11 +178,11 @@ def psum_gemm(a, b, mesh, axis: str = "model"):
         return jax.lax.psum(part, axis).astype(out_dt)
 
     b_spec = _qt_spec(b, P(axis, None)) if packed else P(axis, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), b_spec),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 
@@ -229,11 +228,11 @@ def block_parallel_gemm(a, b, mesh, row_axis: str = "data", col_axis: str = "mod
 
     b_spec = (_qt_spec(b, P(row_axis, col_axis)) if packed
               else P(row_axis, col_axis))
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(row_axis, col_axis), b_spec),
         out_specs=P(row_axis, col_axis),
-        check_rep=False,
+        check_vma=False,
     )(a, b)
 
 

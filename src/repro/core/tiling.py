@@ -33,11 +33,14 @@ from typing import Callable, Optional, Sequence
 
 import jax.numpy as jnp
 
-# TPU v5e-class constants (targets; the container is CPU-only).
+# TPU v5e-class constants.
 MXU_DIM = 128          # systolic array edge
 SUBLANE = 8            # f32 sublane count; bf16 packs 16
-VMEM_BYTES = 128 * 1024 * 1024  # per-core VMEM (v5e ~128 MiB usable is optimistic; budget below)
-DEFAULT_VMEM_BUDGET = 96 * 1024 * 1024  # leave headroom for semaphores/double buffers
+# The ONE scoped-VMEM number: blocks are planned against it here, and every
+# pallas_call passes it as `vmem_limit_bytes` (kernels._compat), so a planned
+# block is one the compiler may place.  Half of the v5e's 128 MiB per core;
+# a 2048^3 bf16 GEMM block (58.7 MB working set) compiles under it.
+DEFAULT_VMEM_BUDGET = 64 * 1024 * 1024
 
 
 def round_up(x: int, m: int) -> int:

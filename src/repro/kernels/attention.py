@@ -25,21 +25,26 @@ Two byte levers live here on top of the blocking:
     (after the weight stream, quantized in PR 4) at roughly half the HBM
     traffic, with no extra launches;
   - GQA without materialization: `kv_groups` > 1 maps `g` consecutive query
-    heads onto one stored K/V head via the BlockSpec index_map, so grouped-
-    query attention never expands the cache to the full head count in HBM.
+    heads onto one stored K/V head (the head walk inside the block), so
+    grouped-query attention never expands the cache to the full head count
+    in HBM.
 
-Operand layouts: the flat (BH, T, D) layout, or — `q.ndim == 4` — the KV
-cache's NATIVE (B, T, H, D) layout, where the index maps decompose the grid
-row into (slot, head) so the kernel streams the cache exactly as it sits in
-HBM (no moveaxis/reshape materialization between the cache and the launch:
-the layout half of the co-design, same as QuantSpec.transpose for weights).
+Operand layout: the KV cache's NATIVE (B, T, H, D) layout, where one grid
+row is one slot and each block carries all of its heads, so the kernel
+streams the cache exactly as it sits in HBM (no moveaxis/reshape
+materialization between the cache and the launch: the layout half of the
+co-design, same as QuantSpec.transpose for weights).  Whole-head blocks
+keep the block's last two dims equal to the array's (KVH, D), which the TPU
+compiler accepts at any key-block or page size; a one-head block
+(1, bk, 1, D) is refused.
 
-Per-slot serving lengths: `kv_lens` (one real KV length per grid row)
-replaces the static kv_len/offset pair with an in-kernel scalar read, so a
-continuous-batching decode step — every slot at its own ragged position —
-runs the ragged grid in ONE launch with per-slot causal alignment.
+Per-slot serving lengths: `kv_lens` (one real KV length per slot)
+replaces the static kv_len/offset pair with an in-kernel SMEM scalar read,
+so a continuous-batching decode step — every slot at its own ragged
+position — runs the ragged grid in ONE launch with per-slot causal
+alignment.
 
-Paged KV (ISSUE 7): with `page_table` the K/V operands are a GLOBAL page
+Paged KV: with `page_table` the K/V operands are a GLOBAL page
 pool `(num_pages, page_size, KVH, D)` shared by every slot, and the table
 `(B, max_pages)` names which physical page holds each slot's j-th logical
 key block.  The key-block size is pinned to the page size and the KV index
@@ -71,36 +76,28 @@ def _flash_kernel(
     *args,
     nk: int, bq: int, bk: int, scale: float, causal: bool, prefix_len: int,
     q_len: int, offset: int, kv_len: int, quantized: bool, dynamic_len: bool,
-    cache_layout: bool, paged: bool = False,
+    heads: int, groups: int, paged: bool,
 ):
+    # scalar-prefetch operands come first: [page_table] [kv_lens]
+    args = list(args)
     if paged:
-        # scalar-prefetch page table: consumed entirely by the index maps —
-        # the kernel body never touches it (positions are logical already)
-        args = args[1:]
+        # the page table is consumed entirely by the index maps — the kernel
+        # body never touches it (positions are logical already)
+        args.pop(0)
+    len_ref = args.pop(0) if dynamic_len else None
     q_ref, k_ref, v_ref, *refs = args
-    # refs: [k_scales] [v_scales] [kv_lens] o m l acc
-    refs = list(refs)
+    # refs: [k_scales] [v_scales] o m l acc
     ks_ref = refs.pop(0) if quantized else None
     vs_ref = refs.pop(0) if quantized else None
-    len_ref = refs.pop(0) if dynamic_len else None
     o_ref, m_ref, l_ref, acc_ref = refs
 
-    def tile(ref):
-        # (1, bt, d) block in the flat layout, (1, bt, 1, d) in cache layout
-        return ref[0, :, 0] if cache_layout else ref[0]
+    def tile(ref, head):
+        # one head's (bt, d) tile of a (1, bt, H, d) block
+        return ref[0, :, head, :]
 
-    ik = pl.program_id(2)
+    r = pl.program_id(0)
     iq = pl.program_id(1)
-    if dynamic_len:
-        # per-slot real KV length: the causal offset and the key mask become
-        # per-grid-row scalars instead of launch-time constants
-        kvl = len_ref[0, 0]
-        off = kvl - q_len
-        mask_k = True
-    else:
-        kvl = kv_len
-        off = offset
-        mask_k = kv_len < nk * bk  # keys beyond kv_len are tile padding
+    ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -108,83 +105,102 @@ def _flash_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # Block culling (paper AE3 analog: skip whole-block work/DMAs that the
-    # dependency structure proves dead): causally-invisible blocks, and
-    # blocks lying entirely in the key padding.
-    first_k = ik * bk
-    last_q = iq * bq + bq - 1 + off
-    visible = first_k < kvl
-    if causal:
-        causal_vis = first_k <= last_q
-        if prefix_len:
-            # prefix-LM: blocks inside the bidirectional prefix stay live
-            # even above the causal diagonal
-            causal_vis = jnp.logical_or(causal_vis, first_k < prefix_len)
-        visible = jnp.logical_and(visible, causal_vis)
+    def attend(hh):
+        kv_head = hh // groups
+        if dynamic_len:
+            # per-slot real KV length: the causal offset and the key mask
+            # become per-slot scalars instead of launch-time constants
+            kvl = len_ref[r]
+            off = kvl - q_len
+            mask_k = True
+        else:
+            kvl = kv_len
+            off = offset
+            mask_k = kv_len < nk * bk  # keys beyond kv_len are tile padding
 
-    @pl.when(visible)
-    def _body():
-        q = tile(q_ref).astype(jnp.float32) * scale         # (bq, d)
-        k = tile(k_ref).astype(jnp.float32)                 # (bk, d)
-        v = tile(v_ref).astype(jnp.float32)                 # (bk, d)
-        if quantized:
-            # packed int8 K/V tiles: one per-(token, head) scale row each —
-            # dequantized on the fly against the f32 accumulator
-            k = k * tile(ks_ref)                            # (bk, 1) broadcast
-            v = v * tile(vs_ref)
-        if dynamic_len or mask_k:
-            # cdiv grid, no caller padding: fringe rows of the V tile are
-            # undefined OOB reads and must be zeroed — a masked score only
-            # guards the K side (p=0 still poisons the PV dot as 0 * NaN).
-            # Garbage K columns are covered by the kpos mask on s below.
-            kcol = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-            v = jnp.where(kcol < kvl, v, 0.0)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                   # (bq, bk)
-        if causal or mask_k:
-            qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + off
-            kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            keep = jnp.full((bq, bk), True)
-            if causal:
-                cmask = qpos >= kpos
-                if prefix_len:
-                    # bidirectional within the first prefix_len absolute key
-                    # positions, causal after (the `kpos < kvl` key-validity
-                    # mask below still bounds the prefix to real keys)
-                    cmask |= kpos < prefix_len
-                keep &= cmask
-            if mask_k:
-                keep &= kpos < kvl
-            s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[...]                                 # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                              # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)                     # (bq, 1)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_new
+        # Block culling (paper AE3 analog: skip whole-block work that the
+        # dependency structure proves dead): causally-invisible blocks, and
+        # blocks lying entirely in the key padding.
+        first_k = ik * bk
+        last_q = iq * bq + bq - 1 + off
+        visible = first_k < kvl
+        if causal:
+            causal_vis = first_k <= last_q
+            if prefix_len:
+                # prefix-LM: blocks inside the bidirectional prefix stay live
+                # even above the causal diagonal
+                causal_vis = jnp.logical_or(causal_vis, first_k < prefix_len)
+            visible = jnp.logical_and(visible, causal_vis)
+
+        @pl.when(visible)
+        def _body():
+            q = tile(q_ref, hh).astype(jnp.float32) * scale      # (bq, d)
+            k = tile(k_ref, kv_head).astype(jnp.float32)          # (bk, d)
+            v = tile(v_ref, kv_head).astype(jnp.float32)          # (bk, d)
+            if quantized:
+                # packed int8 K/V tiles: one per-(token, head) scale row each
+                # — dequantized on the fly against the f32 accumulator
+                k = k * tile(ks_ref, kv_head)                     # (bk, 1)
+                v = v * tile(vs_ref, kv_head)
+            if dynamic_len or mask_k:
+                # cdiv grid, no caller padding: fringe rows of the V tile are
+                # undefined OOB reads and must be zeroed — a masked score
+                # only guards the K side (p=0 still poisons the PV dot as
+                # 0 * NaN).  Garbage K columns are covered by the kpos mask.
+                kcol = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+                v = jnp.where(kcol < kvl, v, 0.0)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                     # (bq, bk)
+            if causal or mask_k:
+                qpos = (iq * bq + off
+                        + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+                kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+                keep = jnp.full((bq, bk), True)
+                if causal:
+                    cmask = qpos >= kpos
+                    if prefix_len:
+                        # bidirectional within the first prefix_len absolute
+                        # key positions, causal after (the `kpos < kvl`
+                        # key-validity mask below still bounds the prefix)
+                        cmask |= kpos < prefix_len
+                    keep &= cmask
+                if mask_k:
+                    keep &= kpos < kvl
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[hh]                                    # (bq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)                                # (bq, bk)
+            alpha = jnp.exp(m_prev - m_new)                       # (bq, 1)
+            l_ref[hh] = alpha * l_ref[hh] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:, hh, :] = alpha * acc_ref[:, hh, :] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[hh] = m_new
+
+    for hh in range(heads):
+        attend(hh)
 
     @pl.when(ik == nk - 1)
     def _flush():
-        out = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-        if cache_layout:
-            o_ref[0, :, 0] = out
-        else:
-            o_ref[0] = out
+        # normalize each head in the f32 accumulator, then write the block
+        # with ONE whole-block store: a per-head store into a packed bf16
+        # (bq, H, D) block is a sublane-strided write Mosaic refuses
+        for hh in range(heads):
+            acc_ref[:, hh, :] = acc_ref[:, hh, :] / l_ref[hh]
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 def attention(
-    q: jnp.ndarray,  # (BH, Tq, D), or (B, Tq, H, D) cache layout
-    k: jnp.ndarray,  # (BH // kv_groups, Tk, D) / (B, Tk, H // kv_groups, D);
-                     # int8 when k_scales is given
+    q: jnp.ndarray,  # (B, Tq, H, D)
+    k: jnp.ndarray,  # (B, Tk, H // kv_groups, D); int8 when k_scales is given
     v: jnp.ndarray,  # same layout as k
     *,
     k_scales: jnp.ndarray = None,  # k's layout with D -> 1, f32
     v_scales: jnp.ndarray = None,
-    kv_lens: jnp.ndarray = None,   # (BH,) int32 per-grid-row real KV lengths
+    kv_lens: jnp.ndarray = None,   # (B,) int32 per-slot KV lengths
     page_table: jnp.ndarray = None,  # (B, max_pages) int32: k/v are the pool
     kv_groups: int = 1,            # query heads per stored K/V head (GQA)
     causal: bool = True,
@@ -203,39 +219,31 @@ def attention(
     at positions >= kv_len are masked to -inf and their V rows zeroed; the
     causal offset aligns the real query range to the END of the real key
     range, independent of any extra rows on either side.
-    `kv_lens` makes the real length per-grid-row (the continuous-batching
-    ragged slot grid) instead of a launch constant; with `k_scales`/
-    `v_scales` the K/V tiles are packed int8 (core.quant.quantize_kv) and
-    dequantize in-kernel.  `prefix_len` (with causal=True) makes the first
-    `prefix_len` ABSOLUTE key positions bidirectionally visible (prefix-LM);
-    it is ignored when causal=False (everything is visible already).  4-D operands stream the KV cache's native
-    (B, T, H, D) layout — the grid row decomposes into (slot, head) inside
-    the index maps, so no transposed copy is ever materialized.
+    `kv_lens` makes the real length per slot — the continuous-batching
+    ragged slot grid — instead of a launch constant; it rides in
+    SMEM as a scalar-prefetch operand.  With `k_scales`/`v_scales` the K/V
+    tiles are packed int8 (core.quant.quantize_kv) and dequantize
+    in-kernel.  `prefix_len` (with causal=True) makes the first `prefix_len`
+    ABSOLUTE key positions bidirectionally visible (prefix-LM); it is
+    ignored when causal=False (everything is visible already).
+
+    The operands stream the KV cache's native (B, T, H, D) layout.  One
+    grid row is one slot, and its blocks hold EVERY head: `(1, bq, H, D)` for q
+    and `(1, bk, KVH, D)` for K/V, so the last two block dims always equal
+    the array's (the TPU's (8, 128) tiling rule holds at any block_q,
+    block_k or page size) and no transposed copy of the cache is ever
+    materialized.  The body walks the heads with 2-D tiles.
     With `page_table` the k/v (and scale) operands are the PAGE POOL
     `(num_pages, page_size, KVH, D)` and the logical key stream of slot b is
     `pool[page_table[b, 0]], pool[page_table[b, 1]], ...` — block_k is pinned
     to page_size and the KV index map does the one table lookup.
     """
-    cache_layout = q.ndim == 4
     paged = page_table is not None
+    b, tq, h, d = q.shape
+    _, tk, kvh, _ = k.shape
     if paged:
-        if not cache_layout:
-            raise ValueError("page_table requires the (B, Tq, H, D) q layout")
-        b, tq, h, d = q.shape
-        _, page_size, kvh, _ = k.shape
-        assert h == kvh * kv_groups, (q.shape, k.shape, kv_groups)
-        bh = b * h
-        tk = page_table.shape[-1] * page_size  # logical per-slot capacity
-    elif cache_layout:
-        b, tq, h, d = q.shape
-        _, tk, kvh, _ = k.shape
-        assert h == kvh * kv_groups, (q.shape, k.shape, kv_groups)
-        bh = b * h
-    else:
-        bh, tq, d = q.shape
-        _, tk, _ = k.shape
-        assert bh == k.shape[0] * kv_groups, (q.shape, k.shape, kv_groups)
-        h = None
+        tk *= page_table.shape[-1]  # logical per-slot capacity
+    assert h == kvh * kv_groups, (q.shape, k.shape, kv_groups)
     quantized = k_scales is not None
     assert (k_scales is None) == (v_scales is None)
     q_len = tq if q_len is None else q_len
@@ -248,7 +256,7 @@ def attention(
     # cdiv grids, no divisibility contract: the key fringe is masked
     # in-kernel (kpos/kvl on the scores, zeroed V rows) and the ragged
     # query-block rows are clipped by Pallas on the output write
-    grid = (bh, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
+    grid = (b, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
     dynamic_len = kv_lens is not None
     kernel = functools.partial(
         _flash_kernel,
@@ -263,76 +271,46 @@ def attention(
         kv_len=kv_len,
         quantized=quantized,
         dynamic_len=dynamic_len,
-        cache_layout=cache_layout,
+        heads=h,
+        groups=kv_groups,
         paged=paged,
     )
-    g = kv_groups
+    # index maps take the grid indices and then the scalar-prefetch refs
+    q_spec = pl.BlockSpec((1, block_q, h, d), lambda r, i, j, *_: (r, i, 0, 0))
     if paged:
-        # the ONE page-table lookup: logical key block j of slot r // h lives
-        # in physical page pt[r // h, j] of the pool — everything else
-        # (masking, GQA fold, scales layout) is the cache-layout path verbatim
-        q_spec = pl.BlockSpec(
-            (1, block_q, 1, d), lambda r, i, j, pt: (r // h, i, r % h, 0))
-        kv_idx = lambda r, i, j, pt: (pt[r // h, j], 0, (r % h) // g, 0)
-        kv_spec = pl.BlockSpec((1, block_k, 1, d), kv_idx)
-        s_spec = pl.BlockSpec((1, block_k, 1, 1), kv_idx)
-        out_shape = q.shape
-    elif cache_layout:
-        # grid row r = slot * H + head; K/V fold the GQA group on top — the
-        # cache streams exactly as it sits in HBM
-        q_spec = pl.BlockSpec((1, block_q, 1, d), lambda r, i, j: (r // h, i, r % h, 0))
-        kv_idx = lambda r, i, j: (r // h, j, (r % h) // g, 0)
-        kv_spec = pl.BlockSpec((1, block_k, 1, d), kv_idx)
-        s_spec = pl.BlockSpec((1, block_k, 1, 1), kv_idx)
-        out_shape = q.shape
+        # the ONE page-table lookup: logical key block j of slot r lives in
+        # physical page pt[r, j] of the pool — masking, GQA fold and scales
+        # layout are the dense cache path verbatim
+        kv_idx = lambda r, i, j, pt, *_: (pt[r, j], 0, 0, 0)
     else:
-        q_spec = pl.BlockSpec((1, block_q, d), lambda r, i, j: (r, i, 0))
-        # GQA: g consecutive query heads read the same stored K/V head — the
-        # index_map folds the group, so the cache never expands in HBM
-        kv_spec = pl.BlockSpec((1, block_k, d), lambda r, i, j: (r // g, j, 0))
-        s_spec = pl.BlockSpec((1, block_k, 1), lambda r, i, j: (r // g, j, 0))
-        out_shape = (bh, tq, d)
+        kv_idx = lambda r, i, j, *_: (r, j, 0, 0)
+    kv_spec = pl.BlockSpec((1, block_k, kvh, d), kv_idx)
+    s_spec = pl.BlockSpec((1, block_k, kvh, 1), kv_idx)
+    prefetch = []
+    if paged:
+        prefetch.append(page_table.astype(jnp.int32))
+    if dynamic_len:
+        prefetch.append(kv_lens.astype(jnp.int32).reshape(b))
     operands = [q, k, v]
     in_specs = [q_spec, kv_spec, kv_spec]
     if quantized:
         operands += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
         in_specs += [s_spec, s_spec]
-    if dynamic_len:
-        operands.append(kv_lens.astype(jnp.int32).reshape(bh, 1))
-        lens_idx = (lambda r, i, j, pt: (r, 0)) if paged else (
-            lambda r, i, j: (r, 0))
-        in_specs.append(pl.BlockSpec((1, 1), lens_idx))
-    scratch_shapes = [
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, 1), jnp.float32),
-        pltpu.VMEM((block_q, d), jnp.float32),
-    ]
-    compiler_params = _compat.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
-    if paged:
-        # the page table rides as a scalar-prefetch operand so the index
-        # maps above can read it before the grid's DMAs are issued
-        return pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=grid,
-                in_specs=in_specs,
-                out_specs=q_spec,
-                scratch_shapes=scratch_shapes,
-            ),
-            out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-            compiler_params=compiler_params,
-            interpret=interpret,
-        )(page_table.astype(jnp.int32), *operands)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-        scratch_shapes=scratch_shapes,
-        compiler_params=compiler_params,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((h, block_q, 1), jnp.float32),
+                pltpu.VMEM((h, block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, h, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=_compat.compiler_params(
+            "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, *operands)

@@ -221,8 +221,7 @@ def bgemm(
         out_specs=pl.BlockSpec((1, block_m, block_n), lambda i, j, bi, k: (bi, i, j)),
         out_shape=jax.ShapeDtypeStruct((batch, m, n), out_dt),
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)

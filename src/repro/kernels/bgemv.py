@@ -234,9 +234,7 @@ def bgemv(
         out_specs=pl.BlockSpec(out_block, out_idx),
         out_shape=jax.ShapeDtypeStruct(out_shape, out_dt),
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)
     return out[:, 0, :] if transpose_a else out[:, :, 0]

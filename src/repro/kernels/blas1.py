@@ -62,9 +62,7 @@ def _reduce(x, y, mode, block_n, interpret):
         out_specs=pl.BlockSpec((1, 1), lambda j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.promote_types(jnp.float32, x.dtype))],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
+        compiler_params=_compat.compiler_params("arbitrary"),
         interpret=interpret,
     )(x[None, :], y[None, :])
     return out[0, 0]
@@ -99,9 +97,7 @@ def axpy(alpha, x: jnp.ndarray, y: jnp.ndarray, *, block_n: int = 2048, interpre
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, n), x.dtype),
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
+        compiler_params=_compat.compiler_params("parallel"),
         interpret=interpret,
     )(alpha, x[None, :], y[None, :])
     return out[0]

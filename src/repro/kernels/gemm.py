@@ -188,9 +188,7 @@ def gemm(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dt),
         scratch_shapes=scratch,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)
 

@@ -150,9 +150,7 @@ def gemv(
         out_specs=pl.BlockSpec((block_m, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), out_dt),
         scratch_shapes=[pltpu.VMEM((block_m, 1), acc_dt)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(*operands)
     return out[:, 0]

@@ -93,8 +93,6 @@ def ssd(
         out_specs=pl.BlockSpec((1, chunk, p), lambda bb, i: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(x, a_log, b, c)

@@ -484,10 +484,10 @@ def axpy(alpha, x: jnp.ndarray, y: jnp.ndarray, *, block_n=2048):
 def flash_attention(q, k, v, *, k_scales=None, v_scales=None, kv_lens=None,
                     page_table=None, kv_groups=1, causal=True, prefix_len=None,
                     block_q=128, block_k=128):
-    """(BH, Tq, D) x (BHkv, Tk, D) -> (BH, Tq, D).  4-D operands select the
-    KV cache's native (B, T, H, D) layout instead — the kernel's index maps
-    decompose the grid row into (slot, head), so the cache streams as it
-    sits in HBM (no transposed copy materialized).
+    """(B, Tq, H, D) x (B, Tk, KVH, D) -> (B, Tq, H, D), in the KV cache's
+    native layout — one grid row per slot, each block holding all of its
+    heads, so the cache streams as it sits in HBM (no transposed copy
+    materialized).
 
     No padding at all: the kernel runs cdiv grids and masks the ragged key
     fringe in-kernel (scores via kpos < kv_len, V rows zeroed), with ragged
@@ -498,8 +498,8 @@ def flash_attention(q, k, v, *, k_scales=None, v_scales=None, kv_lens=None,
     core.quant.quantize_kv), K/V are packed int8 streamed at 1 byte/element
     and dequantized in-kernel.  `kv_groups` > 1 shares each stored K/V head
     across that many consecutive query heads (GQA) via the index map — no
-    materialized repeat.  `kv_lens` (BH,) replaces the shared real KV
-    length with a per-row one (continuous-batching ragged slot decode).
+    materialized repeat.  `kv_lens` (B,) replaces the shared real KV
+    length with a per-slot one (continuous-batching ragged slot decode).
     `prefix_len` relaxes the causal mask over the first prefix_len absolute
     key positions (prefix-LM, e.g. the paligemma patch prefix).
 

@@ -109,8 +109,6 @@ def rwkv6(
         out_specs=pl.BlockSpec((1, chunk, vv), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, vv), r.dtype),
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=_compat.compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(r, k, v, w_log, u[:, None, :])

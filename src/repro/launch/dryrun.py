@@ -167,12 +167,8 @@ def _compile(arch, shape, mesh, cfg, microbatches):
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """compiled.cost_analysis() normalized across jax versions (older jax
-    returns [dict], newer returns dict)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """compiled.cost_analysis(), or {} when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _cost_dict(compiled):

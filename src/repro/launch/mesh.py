@@ -20,11 +20,8 @@ from jax.sharding import PartitionSpec as P  # noqa: F401  (re-exported)
 
 
 def _mesh(shape, axes):
-    # jax >= 0.5 wants explicit axis_types; older jax has no AxisType at all.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,7 +21,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -78,11 +77,11 @@ def pipeline_apply(stacked_params, x_micro, block_fn, mesh, axis: str = "stage")
         return jax.lax.psum(out_buf * mask, axis)
 
     spec_params = jax.tree.map(lambda _: P(axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         pipe, mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, x_micro)
 
 

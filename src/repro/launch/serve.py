@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import blas
+from repro.launch import compile_cache
 from repro.launch import draft as draft_lib
 from repro.launch import faults as faults_lib
 from repro.launch import paging
@@ -1621,6 +1622,7 @@ def main():
                          f"(default: ${faults_lib.FAULTS_ENV}); kinds: "
                          f"{', '.join(faults_lib.KINDS)}")
     args = ap.parse_args()
+    compile_cache.enable()
     serve(args.arch, args.variant, args.requests, args.batch, args.prompt_len,
           args.gen, backend=args.backend, scheduler=args.scheduler,
           quantize=args.quantize, kv_cache=args.kv_cache,

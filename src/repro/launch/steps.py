@@ -185,7 +185,6 @@ def make_eval_step(cfg: ModelConfig):
 import dataclasses  # noqa: E402
 
 from jax.sharding import PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from repro.core import distributed  # noqa: E402
 from repro.launch.mesh import make_test_mesh  # noqa: E402
@@ -222,8 +221,8 @@ def _tp_wrap(build, cfg: ModelConfig, mesh, in_specs, out_specs):
             fn = build(tp_local_config(cfg, p))
             return fn(*argv)
 
-    return shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_tp_prefill_step(cfg: ModelConfig, mesh, pspecs, cspecs):

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro import checkpoint
 from repro.configs.base import SHAPES, ShapeCell
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch import compile_cache
 from repro.launch import steps as steps_lib
 from repro.models import transformer as tf
 from repro.models.registry import get_config
@@ -127,6 +128,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     train(
         arch=args.arch, variant=args.variant, steps=args.steps, seq=args.seq,
         batch=args.batch, ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

@@ -409,13 +409,11 @@ def _flash_eligible(cfg: "AttnConfig") -> bool:
     return blas.get_backend() == "pallas" and not cfg.full_scores
 
 
-def _expand_kv_lens(pos, t: int, b: int, h: int) -> jnp.ndarray:
-    """Per-grid-row real KV length AFTER this step's write: scalar pos
-    broadcasts, a (B,) per-slot vector expands over that slot's query heads
-    (the continuous-batching ragged slot grid)."""
-    return jnp.broadcast_to(
-        (jnp.asarray(pos, jnp.int32) + t).reshape(-1, 1), (b, h)
-    ).reshape(b * h)
+def _slot_kv_lens(pos, t: int, b: int) -> jnp.ndarray:
+    """(B,) real KV length per slot AFTER this step's write: a scalar pos
+    broadcasts, a (B,) per-slot vector is the continuous-batching ragged
+    slot grid."""
+    return jnp.broadcast_to(jnp.asarray(pos, jnp.int32) + t, (b,))
 
 
 def _flash_cache_attention(q, kv, vv, pos, t: int, groups: int, *,
@@ -427,11 +425,12 @@ def _flash_cache_attention(q, kv, vv, pos, t: int, groups: int, *,
     or (with ks/vs (B, S, KVH, 1) per-(token, head) scales) PACKED int8
     values dequantized in-kernel at 1 byte/element.  pos is the pre-write
     cache position (scalar, or (B,) for the continuous-batching ragged slot
-    grid).  Everything streams in the cache's NATIVE layout — the kernel's
-    4-D BlockSpecs decompose the grid row into (slot, head), so no
+    grid).  Everything streams in the cache's NATIVE layout — one grid row
+    per slot, each block holding all of that slot's heads — so no
     transposed copy of the cache is ever materialized between the scatter
-    and the launch; GQA head sharing folds into the index map (no repeat_kv
-    materialization), per-row real lengths mask the dead capacity tail, and
+    and the launch; GQA head sharing folds into the kernel's head walk (no
+    repeat_kv materialization), per-row real lengths mask the dead capacity
+    tail, and
     `causal`/`prefix_len` select the mask in-kernel (satellite fix: the old
     packed path hardcoded causal=True and eligibility-gated everything
     else out to the dequant fallback).
@@ -441,8 +440,7 @@ def _flash_cache_attention(q, kv, vv, pos, t: int, groups: int, *,
     map does the one table lookup — ragged + paged + quantized is still ONE
     launch.
     """
-    b, tq, h, hd = q.shape
-    lens = _expand_kv_lens(pos, t, b, h)
+    lens = _slot_kv_lens(pos, t, q.shape[0])
     from repro.kernels import ops
     out = ops.flash_attention(q, kv, vv, k_scales=ks, v_scales=vs,
                               kv_lens=lens, page_table=page_table,
@@ -470,8 +468,8 @@ def attention_dispatch(
     doubles as the real-KV-length seed — flash masks the dead capacity tail
     via per-row kv_lens, the oracle via its causal offset."""
     if blas.get_backend() == "pallas" and not full_scores:
-        b, tq, h, _ = q.shape
-        kv_lens = None if q_offset is None else _expand_kv_lens(q_offset, tq, b, h)
+        kv_lens = (None if q_offset is None
+                   else _slot_kv_lens(q_offset, q.shape[1], q.shape[0]))
         from repro.kernels import ops
         return ops.flash_attention(
             q, k, v, kv_lens=kv_lens, kv_groups=groups, causal=causal,

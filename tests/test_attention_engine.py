@@ -49,8 +49,10 @@ def test_flash_prefix_lm_matches_ref(tq, tk, pfx):
     q = jax.random.normal(ks[0], (4, tq, 32), F32)
     k = jax.random.normal(ks[1], (4, tk, 32), F32)
     v = jax.random.normal(ks[2], (4, tk, 32), F32)
-    out = ops.flash_attention(q, k, v, causal=True, prefix_len=pfx,
-                              block_q=32, block_k=32)
+    # the kernel's (B, T, H, D) layout, one head per slot
+    out = ops.flash_attention(q[:, :, None], k[:, :, None], v[:, :, None],
+                              causal=True, prefix_len=pfx,
+                              block_q=32, block_k=32)[:, :, 0]
     _cmp(out, ref.attention(q, k, v, causal=True, prefix_len=pfx))
     # the prefix mask must actually change the result vs plain causal
     plain = ref.attention(q, k, v, causal=True)
@@ -66,9 +68,10 @@ def test_flash_prefix_lm_with_kv_lens():
     q = jax.random.normal(ks[0], (B, T, H, d), F32)
     k = jax.random.normal(ks[1], (B, S, KV, d), F32)
     v = jax.random.normal(ks[2], (B, S, KV, d), F32)
-    lens = jnp.repeat(jnp.asarray([12, 31], jnp.int32), H)
-    out = ops.flash_attention(q, k, v, kv_lens=lens, kv_groups=g, causal=True,
-                              prefix_len=pfx, block_k=16)
+    slot_lens = jnp.asarray([12, 31], jnp.int32)
+    out = ops.flash_attention(q, k, v, kv_lens=slot_lens, kv_groups=g,
+                              causal=True, prefix_len=pfx, block_k=16)
+    lens = jnp.repeat(slot_lens, H)  # the oracle's per-(slot, head) rows
     flat = lambda z: jnp.moveaxis(z, 2, 1).reshape(-1, z.shape[1], z.shape[3])
     want = ref.attention_lens(
         flat(q), jnp.repeat(flat(k), g, axis=0), jnp.repeat(flat(v), g, axis=0),

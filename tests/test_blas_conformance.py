@@ -166,7 +166,7 @@ def _cancel(n):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_level1_f64_accumulation(backend):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x, y = _f64(0, (131,)), _f64(1, (131,))
         cx = jnp.asarray(_cancel(131))
         with blas.use_backend(backend):
@@ -189,7 +189,7 @@ def test_level1_f64_accumulation(backend):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_level23_f64_accumulation(backend):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         A = _f64(2, (7, 131))
         B = _f64(3, (131, 9))
         Ab = _f64(4, (3, 7, 131))
@@ -272,7 +272,7 @@ def test_bgemm_plans_blocks_for_operand_width(monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(tiling, "autotune_block_shape", spy)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         ops.bgemm(jnp.ones((2, 9, 130), jnp.float64), jnp.ones((130, 5), jnp.float64))
     assert seen and seen[-1] == 8, seen
 

@@ -130,7 +130,6 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_psum_grads():
     run8("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_test_mesh
     from repro.optim import compression
@@ -143,8 +142,8 @@ def test_compressed_psum_grads():
         tree, new_ef = compression.compressed_psum({"g": g_loc[0]}, {"g": ef_loc[0]}, "data", 8)
         return tree["g"][None], new_ef["g"][None]
 
-    reduced, new_ef = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                                out_specs=(P("data"), P("data")), check_rep=False)(g, ef)
+    reduced, new_ef = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                                    out_specs=(P("data"), P("data")), check_vma=False)(g, ef)
     exact = np.asarray(g).mean(0)
     got = np.asarray(reduced)[0]
     # quantization error bounded by ~|g|_max/127

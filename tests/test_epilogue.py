@@ -146,7 +146,7 @@ def test_bgemv_epilogue_sweep(dtype, transpose_a, a_batched, act, use_bias,
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_fused_epilogue_f64(backend):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(0)
         A = jnp.asarray(rng.standard_normal((7, 131)))
         B = jnp.asarray(rng.standard_normal((131, 9)))

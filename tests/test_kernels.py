@@ -123,6 +123,20 @@ def test_bgemm_block_shape_invariance():
 # Flash attention
 # --------------------------------------------------------------------------
 
+def _flash_flat(q, k, v, *, kv_groups=1, kv_lens=None, k_scales=None,
+                v_scales=None, **kw):
+    """Flat (BH, T, D) cases through the kernel's (B, T, H, D) layout: each
+    slot holds one stored K/V head and the `kv_groups` query heads that
+    share it, so per-row lengths (kv_groups=1) are per-slot lengths."""
+    g, slots = kv_groups, k.shape[0]
+    to4 = lambda z, h: jnp.moveaxis(z.reshape(slots, h, *z.shape[1:]), 1, 2)
+    sc = lambda z: None if z is None else to4(z, 1)
+    out = ops.flash_attention(
+        to4(q, g), to4(k, 1), to4(v, 1), k_scales=sc(k_scales),
+        v_scales=sc(v_scales), kv_lens=None if kv_lens is None else kv_lens[::g],
+        kv_groups=g, **kw)
+    return jnp.moveaxis(out, 2, 1).reshape(q.shape)
+
 @pytest.mark.parametrize("dtype", [F32, BF16])
 @pytest.mark.parametrize("tq,tk,d,causal", [
     (256, 256, 64, True),
@@ -135,7 +149,7 @@ def test_flash_attention_sweep(tq, tk, d, causal, dtype):
     q = jax.random.normal(ks[0], (3, tq, d), F32).astype(dtype)
     k = jax.random.normal(ks[1], (3, tk, d), F32).astype(dtype)
     v = jax.random.normal(ks[2], (3, tk, d), F32).astype(dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, block_q=max(1, min(64, tq)), block_k=64)
+    out = _flash_flat(q, k, v, causal=causal, block_q=max(1, min(64, tq)), block_k=64)
     _cmp(out, ref.attention(q, k, v, causal=causal), dtype)
 
 
@@ -153,7 +167,7 @@ def test_flash_attention_padded_lengths(tq, tk, causal):
     q = jax.random.normal(ks[0], (2, tq, 64), F32)
     k = jax.random.normal(ks[1], (2, tk, 64), F32)
     v = jax.random.normal(ks[2], (2, tk, 64), F32)
-    out = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = _flash_flat(q, k, v, causal=causal, block_q=64, block_k=64)
     assert np.isfinite(np.asarray(out)).all()
     _cmp(out, ref.attention(q, k, v, causal=causal), F32)
 
@@ -163,7 +177,7 @@ def test_flash_attention_block_invariance():
     q, k, v = (jax.random.normal(kk, (2, 256, 64), F32) for kk in ks)
     out_ref = ref.attention(q, k, v, causal=True)
     for bq, bk in [(32, 32), (64, 128), (128, 64), (256, 256)]:
-        out = ops.flash_attention(q, k, v, block_q=bq, block_k=bk)
+        out = _flash_flat(q, k, v, block_q=bq, block_k=bk)
         _cmp(out, out_ref, F32)
 
 
@@ -194,8 +208,8 @@ def test_flash_attention_int8_kv_matches_dequant_oracle(tq, tk, d, causal):
     ks = jax.random.split(jax.random.PRNGKey(tq * 131 + tk), 2)
     q = jax.random.normal(ks[0], (3, tq, d), F32)
     _, _, k8, ksc, v8, vsc = _packed_kv(ks[1], 3, tk, d)
-    out = ops.flash_attention(q, k8, v8, k_scales=ksc, v_scales=vsc,
-                              causal=causal, block_q=64, block_k=64)
+    out = _flash_flat(q, k8, v8, k_scales=ksc, v_scales=vsc,
+                      causal=causal, block_q=64, block_k=64)
     want = ref.attention_kv_dequant(q, k8, ksc, v8, vsc, causal=causal)
     _cmp(out, want, F32)
 
@@ -208,8 +222,8 @@ def test_flash_attention_int8_kv_within_analytic_bound():
     ks = jax.random.split(jax.random.PRNGKey(5), 2)
     q = jax.random.normal(ks[0], (4, 32, 64), F32)
     k, v, k8, ksc, v8, vsc = _packed_kv(ks[1], 4, 128, 64)
-    out = ops.flash_attention(q, k8, v8, k_scales=ksc, v_scales=vsc,
-                              causal=True, block_q=32, block_k=64)
+    out = _flash_flat(q, k8, v8, k_scales=ksc, v_scales=vsc,
+                      causal=True, block_q=32, block_k=64)
     want = ref.attention(q, k, v, causal=True)
     v_hat = v8.astype(F32) * vsc
     bound = np.asarray(quant.attention_error_bound(q, ksc, v_hat, vsc))
@@ -229,11 +243,11 @@ def test_flash_attention_gqa_groups_share_kv(quantized):
     q = jax.random.normal(ks[0], (B * H, 16, d), F32)
     k, v, k8, ksc, v8, vsc = _packed_kv(ks[1], B * KV, tk, d)
     if quantized:
-        out = ops.flash_attention(q, k8, v8, k_scales=ksc, v_scales=vsc,
-                                  kv_groups=g, causal=True, block_k=64)
+        out = _flash_flat(q, k8, v8, k_scales=ksc, v_scales=vsc,
+                          kv_groups=g, causal=True, block_k=64)
         want = ref.attention_kv_dequant(q, k8, ksc, v8, vsc, causal=True)
     else:
-        out = ops.flash_attention(q, k, v, kv_groups=g, causal=True, block_k=64)
+        out = _flash_flat(q, k, v, kv_groups=g, causal=True, block_k=64)
         want = ref.attention(q, jnp.repeat(k, g, axis=0),
                              jnp.repeat(v, g, axis=0), causal=True)
     _cmp(out, want, F32)
@@ -243,8 +257,8 @@ def test_flash_attention_cache_layout_gqa_lens():
     """The 4-D cache-layout path (no moveaxis/reshape of the cache) with
     GQA groups AND per-slot lens — the exact decode configuration
     layers._flash_cache_attention launches — must match the flat-layout
-    dequant oracle.  Guards the (r % h) // g head decomposition in the 4-D
-    index maps, which no MHA serve config exercises."""
+    dequant oracle.  Guards the query-head -> KV-head fold (hh // g) of the
+    kernel's head walk, which no MHA serve config exercises."""
     from repro.core import quant
     B, H, KV, Tq, S, d = 2, 6, 2, 1, 100, 32
     g = H // KV
@@ -253,10 +267,11 @@ def test_flash_attention_cache_layout_gqa_lens():
     k4 = jax.random.normal(ks[1], (B, S, KV, d), F32)
     v4 = jax.random.normal(ks[2], (B, S, KV, d), F32)
     kq, vq = quant.quantize_kv(k4), quant.quantize_kv(v4)
-    lens = jnp.repeat(jnp.asarray([37, 100], jnp.int32), H)  # per-slot
+    slot_lens = jnp.asarray([37, 100], jnp.int32)
     out = ops.flash_attention(q4, kq.values, vq.values, k_scales=kq.scales,
-                              v_scales=vq.scales, kv_lens=lens, kv_groups=g,
-                              causal=True, block_k=64)
+                              v_scales=vq.scales, kv_lens=slot_lens,
+                              kv_groups=g, causal=True, block_k=64)
+    lens = jnp.repeat(slot_lens, H)  # the oracle's per-(slot, head) rows
     assert out.shape == (B, Tq, H, d)
     flat = lambda z: jnp.moveaxis(z, 2, 1).reshape(z.shape[0] * z.shape[2],
                                                    z.shape[1], z.shape[3])
@@ -278,12 +293,12 @@ def test_flash_attention_per_row_kv_lens(quantized):
     k, v, k8, ksc, v8, vsc = _packed_kv(ks[1], bh, tk, d)
     lens = jnp.asarray([5, 37, 64, 160, 1, 97], jnp.int32)
     if quantized:
-        out = ops.flash_attention(q, k8, v8, k_scales=ksc, v_scales=vsc,
-                                  kv_lens=lens, causal=True, block_k=64)
+        out = _flash_flat(q, k8, v8, k_scales=ksc, v_scales=vsc,
+                          kv_lens=lens, causal=True, block_k=64)
         want = ref.attention_kv_dequant(q, k8, ksc, v8, vsc, kv_lens=lens,
                                         causal=True)
     else:
-        out = ops.flash_attention(q, k, v, kv_lens=lens, causal=True, block_k=64)
+        out = _flash_flat(q, k, v, kv_lens=lens, causal=True, block_k=64)
         want = ref.attention_lens(q, k, v, lens, causal=True)
     assert np.isfinite(np.asarray(out)).all()
     _cmp(out, want, F32)

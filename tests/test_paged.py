@@ -161,7 +161,8 @@ def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0):
                 pool[perm[s, j]] = dense[s, j * page_size:(j + 1) * page_size]
         return jnp.asarray(pool)
 
-    kv_lens = jnp.asarray(np.repeat(lens, h))
+    kv_lens = jnp.asarray(lens)                  # the kernel's, per slot
+    row_lens = jnp.asarray(np.repeat(lens, h))   # the oracle's, per head
     kw = dict(kv_groups=groups, causal=True, block_k=page_size)
     if quantized:
         kq, vq = quant.quantize_kv(kd), quant.quantize_kv(vd)
@@ -176,7 +177,7 @@ def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0):
         oracle = ref.attention_paged_kv_dequant(
             q, to_pool(np.asarray(kq.values)), to_pool(np.asarray(kq.scales)),
             to_pool(np.asarray(vq.values)), to_pool(np.asarray(vq.scales)),
-            table, kv_lens, causal=True)
+            table, row_lens, causal=True)
     else:
         paged = ops.flash_attention(q, to_pool(np.asarray(kd)),
                                     to_pool(np.asarray(vd)),
@@ -184,7 +185,7 @@ def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0):
         dense = ops.flash_attention(q, kd, vd, kv_lens=kv_lens, **kw)
         oracle = ref.attention_paged(q, to_pool(np.asarray(kd)),
                                      to_pool(np.asarray(vd)),
-                                     table, kv_lens, causal=True)
+                                     table, row_lens, causal=True)
     return paged, dense, oracle
 
 

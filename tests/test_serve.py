@@ -123,11 +123,15 @@ def test_eos_frees_slot_early():
     # pick an eos id that actually appears in the unconstrained outputs
     free = serve(ARCH, "smoke", batch=2, gen_lens=gen_lens, eos=NO_EOS,
                  verbose=False, scheduler="continuous", prompts=prompts)
-    eos = free["outputs"][0][2]
+    # (at its FIRST occurrence: request 0 stops there)
+    first = free["outputs"][0]
+    eos = first[2]
+    stop = first.index(eos)
     stats = serve(ARCH, "smoke", batch=2, gen_lens=gen_lens, eos=eos,
                   verbose=False, scheduler="continuous", prompts=prompts)
     assert stats["completed"] == 4
-    assert len(stats["outputs"][0]) == 3  # stopped at the EOS, not the budget
+    # stopped at the EOS, not the budget
+    assert len(stats["outputs"][0]) == stop + 1 < gen_lens[0]
     assert stats["outputs"][0][-1] == eos
     want = _sequential_oracle(prompts, gen_lens, eos=eos)
     assert stats["outputs"] == want

@@ -1,0 +1,141 @@
+"""Compile-only checks of the serving path's Pallas kernels for a TPU v5e.
+
+Each test lowers one kernel at stablelm-1.6b's published widths (d=2048,
+32 heads of 64, d_ff=5632) with `interpret=False` and compiles it for a
+DESCRIBED v5e chip: the TPU compiler is installed here even where no chip
+is attached, so it refuses what the chip would refuse (block shapes off the
+(8, 128) tiling, scoped VMEM overruns).  Nothing runs, so these tests say
+nothing about results or speed; the interpret-mode suites cover results.
+
+The topology is described only inside the module fixture below: loading the
+TPU library at import time would make every pytest-xdist worker contend
+for it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import epilogue, quant, tiling
+from repro.kernels import attention, bgemm, bgemv, gemm
+
+D, H, HD, FF = 2048, 32, 64, 5632   # stablelm-1.6b widths
+B, T, CACHE = 8, 128, 2048          # serving batch, prompt, KV capacity
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip: keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, *args):
+    """Lower and compile `fn` for the described chip; the kernel must be
+    there as a Mosaic custom call (no XLA fallback took its place)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _spec(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _packed(sharding, k, n):
+    """Shapes of a serving weight packed as layers.quantize_weights packs it:
+    output-major int8 values with one f32 scale per 64 output rows."""
+    qt = jax.eval_shape(
+        lambda w: quant.quantize(w, quant.QuantSpec(64, None, transpose=True)),
+        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+    return (_spec(sharding, qt.values.shape, qt.values.dtype),
+            _spec(sharding, qt.scales.shape, qt.scales.dtype), qt.block)
+
+
+def test_prefill_gemm_compiles_at_planner_block(one_chip):
+    """A 2048-token QKV GEMM at the planner's block (2048^3 under the
+    64 MiB budget): compiles only because every kernel passes the budget as
+    its scoped-VMEM limit."""
+    m, n, k = 2048, 3 * D, D
+    blk = tiling.choose_block_shape(m, n, k)
+    assert blk == tiling.BlockShape(2048, 2048, 2048)
+    _compile(lambda a, b: gemm.gemm(a, b, block_m=blk.bm, block_n=blk.bn,
+                                    block_k=blk.bk),
+             _spec(one_chip, (m, k)), _spec(one_chip, (k, n)))
+
+
+def test_prefill_bgemm_swiglu_dual_b_compiles(one_chip):
+    """The prefill SwiGLU: one dual-B bgemm launch with the silu gate fused
+    into the flush, at the block the planner gives the fused variant."""
+    blk = tiling.rank_block_shapes(T, FF, D, gate=True)[0]
+    epi = epilogue.make("silu", gate=True)
+    _compile(lambda a, b, b2: bgemm.bgemm(
+        a, b, b2=b2, epilogue=epi, block_m=blk.bm, block_n=blk.bn,
+        block_k=blk.bk),
+        _spec(one_chip, (B, T, D)), _spec(one_chip, (D, FF)),
+        _spec(one_chip, (D, FF)))
+
+
+def test_decode_bgemv_transpose_a_compiles(one_chip):
+    """The bf16 decode projection: broadcast weight streamed in its HBM
+    (d, f) layout under transpose_a, with the SwiGLU gate fused."""
+    epi = epilogue.make("silu", gate=True)
+    _compile(lambda w, w2, x: bgemv.bgemv(w, x, a2=w2, epilogue=epi,
+                                          transpose_a=True),
+             _spec(one_chip, (D, FF)), _spec(one_chip, (D, FF)),
+             _spec(one_chip, (B, D)))
+
+
+def test_decode_bgemv_int8_packed_compiles(one_chip):
+    """The int8 decode projection: packed output-major weight and its scale
+    grid streamed with in-kernel dequantization, SwiGLU gate fused."""
+    epi = epilogue.make("silu", gate=True)
+    w, ws, q_block = _packed(one_chip, D, FF)
+    _compile(lambda w, ws, w2, w2s, x: bgemv.bgemv(
+        w, x, a2=w2, scales=ws, a2_scales=w2s, q_block=q_block,
+        epilogue=epi),
+        w, ws, w, ws, _spec(one_chip, (B, D)))
+
+
+def test_flash_cache_layout_decode_compiles(one_chip):
+    """Ragged-slot decode over the dense (B, T, H, D) cache: one query per
+    slot, per-slot lengths in SMEM, whole-head K/V blocks."""
+    _compile(lambda q, k, v, lens: attention.attention(q, k, v, kv_lens=lens),
+             _spec(one_chip, (B, 1, H, HD)),
+             _spec(one_chip, (B, CACHE, H, HD)),
+             _spec(one_chip, (B, CACHE, H, HD)),
+             _spec(one_chip, (B,), jnp.int32))
+
+
+@pytest.mark.parametrize("page_size", [1, 16])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_flash_paged_decode_compiles(one_chip, page_size, kv):
+    """Paged decode through the page table, bf16 and packed int8 KV.  Page
+    size 1 is the smallest a pool can have: whole-head blocks keep the
+    block's last two dims at (KVH, D) whatever the page size."""
+    max_pages = CACHE // page_size
+    pool = (1 + B * max_pages, page_size, H, HD)
+    q = _spec(one_chip, (B, 1, H, HD))
+    lens = _spec(one_chip, (B,), jnp.int32)
+    table = _spec(one_chip, (B, max_pages), jnp.int32)
+    if kv == "int8":
+        kq = _spec(one_chip, pool, jnp.int8)
+        ks = _spec(one_chip, pool[:-1] + (1,), jnp.float32)
+        _compile(lambda q, k, v, ks, vs, lens, pt: attention.attention(
+            q, k, v, k_scales=ks, v_scales=vs, kv_lens=lens, page_table=pt),
+            q, kq, kq, ks, ks, lens, table)
+    else:
+        kb = _spec(one_chip, pool)
+        _compile(lambda q, k, v, lens, pt: attention.attention(
+            q, k, v, kv_lens=lens, page_table=pt),
+            q, kb, kb, lens, table)
