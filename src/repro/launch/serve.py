@@ -47,6 +47,7 @@ from repro.launch import draft as draft_lib
 from repro.launch import faults as faults_lib
 from repro.launch import paging
 from repro.launch import sharding as sharding_lib
+from repro.launch import spans as spans_lib
 from repro.launch import steps as steps_lib
 from repro.models import transformer as tf
 from repro.models.registry import get_config
@@ -169,7 +170,9 @@ def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, bat
     Returns a stats dict: completed/tokens/prefills/decode_steps counters,
     tok_s, mean live-slot `occupancy`, per-request `ttft` (seconds to first
     generated token), `outputs` (greedy token ids per request, in submission
-    order) and per-request admit/finish decode-step indices.
+    order) and per-request admit/finish decode-step indices.  The
+    continuous scheduler also returns its program spans, `spans` (one per
+    decode round, token read-back and admission group; `launch/spans.py`).
     """
     cfg = get_config(arch, variant)
     rng = np.random.default_rng(seed)
@@ -673,10 +676,15 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
 
     occ = []
     t0 = time.time()
-    # inter-token stall trackers for LIVE slots: wall clock of the previous
-    # decode step, and admission-prefill tokens processed since it
-    last_decode = [None]
+    spans = spans_lib.Spans(t0)
+    # inter-token stall trackers for LIVE slots: the first record of the
+    # current busy period (a stall is measured between the ends of two
+    # `serve.round.wait`s inside one), and admission-prefill tokens
+    # processed since the last decode step
+    busy_from = [0]
     prefill_gap = [0]
+    # an admission group was grafted since the last decode round
+    grafted = [False]
 
     def free_slot(s):
         """Release slot s's pages and repoint its table row at trash so the
@@ -788,7 +796,14 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         called from the main loop AND between admission prefill chunks.
         Round boundaries are where deadlines are enforced, injected faults
         fire, page runs grow, and (under --check-invariants) the full
-        invariant sweep runs."""
+        invariant sweep runs.  A round that steps a slot is one
+        `serve.round` span."""
+        with spans.span("serve.round", step=stats["decode_steps"]) as rnd:
+            if not step_round(rnd):
+                rnd.drop()
+
+    def step_round(rnd):
+        """The body of `decode_round`; False when no slot was stepped."""
         nonlocal tok_dev, cache, active_dev
         step_idx = stats["decode_steps"]
         # deadline sweep FIRST: boundaries are the only cut points, so a
@@ -801,7 +816,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         if not active.any():
             active_dev = jnp.asarray(active)
             dirty[0] = False
-            return
+            return False
         # injected faults for THIS round.  preempt@K is the only way a
         # dense-cache slot is ever preempted (no pool to pressure).
         if plan.at_step("preempt", step_idx):
@@ -831,8 +846,15 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
             dirty[0] = False
         stepped = active.copy()
         if not stepped.any():
-            return
+            return False
         occ.append(stepped.sum() / batch)
+        # KV positions each stepped slot attends: its cache length after
+        # this round's write (k+1 positions under speculation, read once)
+        rnd.set(live=int(stepped.sum()),
+                kv_tokens=int((slot_pos[stepped] + 1 + spec).sum()),
+                after_admit=grafted[0])
+        grafted[0] = False
+        prev_wait = spans.last("serve.round.wait", busy_from[0])
         if spec:
             # verify window per live slot: [last committed token] + k
             # drafts.  One H2D for the grid — the drafts are host state
@@ -846,21 +868,21 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                     win[s, 1:] = drafter.propose(int(slot_req[s]), spec)
             preds, acc, cache = fn(params, jnp.asarray(win), cache,
                                    active_dev)
-            tok_np = np.asarray(preds)          # (B, k+1) greedy argmaxes
-            acc_np = np.asarray(acc)            # (B,) accepted draft counts
+            with spans.span("serve.round.wait") as wait:
+                tok_np = np.asarray(preds)      # (B, k+1) greedy argmaxes
+                acc_np = np.asarray(acc)        # (B,) accepted draft counts
         else:
             tok_dev, cache = fn(params, tok_dev, cache, active_dev)
-            tok_np = np.asarray(tok_dev)[:, 0]
+            with spans.span("serve.round.wait") as wait:
+                tok_np = np.asarray(tok_dev)[:, 0]
         stats["decode_steps"] += 1
-        now = time.time()
-        if last_decode[0] is not None:
+        t_now = wait.record["end"]
+        if prev_wait is not None:
             stats["max_stall_ms"] = max(stats["max_stall_ms"],
-                                        (now - last_decode[0]) * 1e3)
-        last_decode[0] = now
+                                        (t_now - prev_wait["end"]) * 1e3)
         stats["max_stall_prefill_tokens"] = max(
             stats["max_stall_prefill_tokens"], prefill_gap[0])
         prefill_gap[0] = 0
-        t_now = now - t0
         for s in range(batch):
             if not stepped[s]:
                 continue
@@ -909,6 +931,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                 alloc=alloc if paged else None,
                 table=cache.get("page_table"), active=active,
                 slot_pages=slot_pages if paged else None, cache=cache)
+        return True
 
     def _reclaimable():
         """Pages preemption could free RIGHT NOW: the non-shared pages of
@@ -922,7 +945,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     while pending or active.any():
         if not active.any():
             # nobody live to stall: an admission from an idle grid is free
-            last_decode[0] = None
+            busy_from[0] = len(spans.records)
             prefill_gap[0] = 0
         # admission: every free slot takes the next pending request at this
         # step boundary — no waiting for the batch to drain.  Like decode,
@@ -975,196 +998,201 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
             by_len.setdefault(len(adm_t[2]), []).append(adm_t)
         for plen in sorted(by_len):
             group = by_len[plen]
-            block = np.zeros((batch, plen), np.int32)
-            slots = np.full(batch, -1, np.int32)
-            for i, (s, _, adm, _) in enumerate(group):
-                block[i] = adm
-                slots[i] = s
-            csize = plen if prefill_chunk is None else min(prefill_chunk, plen)
-            mini = mini_zero
-            tok0 = None
-            for start in range(0, plen, csize):
-                if start and active.any():
-                    # a chunk boundary is a decode opportunity: every live
-                    # slot advances one token before the next prefill chunk
-                    decode_round()
-                batch_in = {"tokens": jnp.asarray(block[:, start:start + csize])}
-                if start == 0:
-                    # patches/frames ride on the first chunk only (the vlm
-                    # prefix sits at the front of the sequence)
-                    batch_in.update(_prefill_extras(cfg, rng, batch, 0))
-                tok0, mini = prefill_fn(params, batch_in, mini)
-                stats["prefills"] += 1
-                if active.any():
-                    prefill_gap[0] += min(csize, plen - start)
-            placed = [True] * len(group)
-            requeue = []
-            if paged:
-                # page-pointer admission: match the prompt against registered
-                # prefixes, take fresh pages for the rest, and graft ONLY the
-                # unshared suffix tokens out of the mini cache — matched
-                # pages are already resident in the pool.
-                total = plen + n_prefix
-                rows_l, toks_l, pages_l, offs_l = [], [], [], []
-                cow_src, cow_dst = [], []
-                table_rows = np.zeros((len(group), max_pages_row), np.int64)
-                cow_reserve = 0  # pages earlier members' pass-2 CoWs will take
-                for i, (s, rid, adm, n_em) in enumerate(group):
-                    need = total // page_size + 1
-                    will_decode = gen_lens[rid] - n_em - 1 > 0
-                    while True:
-                        matched, covered = (alloc.match_prefix(adm) if share
-                                            else ([], 0))
-                        # partial-page keys are exact-tail, so a matched
-                        # partial page always covers the whole prompt: the
-                        # graft below never appends into a shared page
-                        assert covered == total or covered % page_size == 0, \
-                            (covered, total)
-                        cow_tail = (will_decode and covered == total
-                                    and total % page_size != 0)
-                        need_new = need - len(matched) + (1 if cow_tail else 0)
-                        if need_new + cow_reserve <= alloc.free_pages():
-                            break
-                        if not free_up(need_new + cow_reserve):
-                            matched = None
-                            break
-                        # free_up's victims may have freed registered pages:
-                        # re-match before trusting the matched list
-                    if matched is None:
-                        # the watermark admitted optimistically but the pool
-                        # moved under us: back out, requeue at the head
-                        slots[i] = -1
-                        placed[i] = False
-                        requeue.append(rid)
-                        continue
-                    alloc.retain(matched)
-                    plist = matched + alloc.alloc(need - len(matched))
-                    if cow_tail:
-                        # the +1 in need_new is NOT allocated here — the CoW
-                        # happens in the second pass, after every member has
-                        # matched; carry the reservation so later members'
-                        # fresh allocations can't eat the page out from under
-                        # it (group CoWs never exceed group reservations: a
-                        # shared write page is always a matched partial tail)
-                        cow_reserve += 1
-                    if share:
-                        alloc.register_prefix(adm, plist[:-(-plen // page_size)])
-                    slot_pages[s] = plist
-                    table_rows[i, :len(plist)] = plist
-                    for p in range(covered, total):
-                        rows_l.append(i)
-                        toks_l.append(p)
-                        pages_l.append(plist[p // page_size])
-                        offs_l.append(p % page_size)
-                # second placement pass — AFTER every member has matched and
-                # registered, so identical same-group prompts share their
-                # partial tail before anyone mutates it: resolve each
-                # member's first-decode-write hazard (pos == total) inside
-                # the reservation cow_tail sized — CoW a shared write page,
-                # unpublish an owned registered tail.  The graft never
-                # touches page widx when a CoW happens (covered == total
-                # means nothing is grafted), so coords stay valid.
-                widx = total // page_size
-                for i, (s, rid, adm, n_em) in enumerate(group):
-                    if not placed[i] or gen_lens[rid] - n_em - 1 <= 0:
-                        continue
-                    plist = slot_pages[s]
-                    p = plist[widx]
-                    if alloc.shared(p):
-                        newp = alloc.cow(p)
-                        cow_src.append(p)
-                        cow_dst.append(newp)
-                        plist[widx] = newp
-                        table_rows[i, widx] = newp
-                    else:
-                        alloc.invalidate(p)
-                if plan.take("graft"):
-                    # simulated graft failure, injected BEFORE the donating
-                    # graft call: the device cache is untouched, so recovery
-                    # is pure bookkeeping — back out every placement and
-                    # requeue the whole group at the queue head
+            with spans.span("serve.admit", plen=plen, rows=len(group),
+                            rids=[rid for _, rid, _, _ in group]):
+                block = np.zeros((batch, plen), np.int32)
+                slots = np.full(batch, -1, np.int32)
+                for i, (s, _, adm, _) in enumerate(group):
+                    block[i] = adm
+                    slots[i] = s
+                csize = plen if prefill_chunk is None else min(prefill_chunk, plen)
+                mini = mini_zero
+                tok0 = None
+                for start in range(0, plen, csize):
+                    if start and active.any():
+                        # a chunk boundary is a decode opportunity: every live
+                        # slot advances one token before the next prefill chunk
+                        decode_round()
+                    batch_in = {"tokens": jnp.asarray(block[:, start:start + csize])}
+                    if start == 0:
+                        # patches/frames ride on the first chunk only (the vlm
+                        # prefix sits at the front of the sequence)
+                        batch_in.update(_prefill_extras(cfg, rng, batch, 0))
+                    tok0, mini = prefill_fn(params, batch_in, mini)
+                    stats["prefills"] += 1
+                    if active.any():
+                        prefill_gap[0] += min(csize, plen - start)
+                placed = [True] * len(group)
+                requeue = []
+                if paged:
+                    # page-pointer admission: match the prompt against registered
+                    # prefixes, take fresh pages for the rest, and graft ONLY the
+                    # unshared suffix tokens out of the mini cache — matched
+                    # pages are already resident in the pool.
+                    total = plen + n_prefix
+                    rows_l, toks_l, pages_l, offs_l = [], [], [], []
+                    cow_src, cow_dst = [], []
+                    table_rows = np.zeros((len(group), max_pages_row), np.int64)
+                    cow_reserve = 0  # pages earlier members' pass-2 CoWs will take
                     for i, (s, rid, adm, n_em) in enumerate(group):
-                        if placed[i]:
+                        need = total // page_size + 1
+                        will_decode = gen_lens[rid] - n_em - 1 > 0
+                        while True:
+                            matched, covered = (alloc.match_prefix(adm) if share
+                                                else ([], 0))
+                            # partial-page keys are exact-tail, so a matched
+                            # partial page always covers the whole prompt: the
+                            # graft below never appends into a shared page
+                            assert covered == total or covered % page_size == 0, \
+                                (covered, total)
+                            cow_tail = (will_decode and covered == total
+                                        and total % page_size != 0)
+                            need_new = need - len(matched) + (1 if cow_tail else 0)
+                            if need_new + cow_reserve <= alloc.free_pages():
+                                break
+                            if not free_up(need_new + cow_reserve):
+                                matched = None
+                                break
+                            # free_up's victims may have freed registered pages:
+                            # re-match before trusting the matched list
+                        if matched is None:
+                            # the watermark admitted optimistically but the pool
+                            # moved under us: back out, requeue at the head
+                            slots[i] = -1
+                            placed[i] = False
+                            requeue.append(rid)
+                            continue
+                        alloc.retain(matched)
+                        plist = matched + alloc.alloc(need - len(matched))
+                        if cow_tail:
+                            # the +1 in need_new is NOT allocated here — the CoW
+                            # happens in the second pass, after every member has
+                            # matched; carry the reservation so later members'
+                            # fresh allocations can't eat the page out from under
+                            # it (group CoWs never exceed group reservations: a
+                            # shared write page is always a matched partial tail)
+                            cow_reserve += 1
+                        if share:
+                            alloc.register_prefix(adm, plist[:-(-plen // page_size)])
+                        slot_pages[s] = plist
+                        table_rows[i, :len(plist)] = plist
+                        for p in range(covered, total):
+                            rows_l.append(i)
+                            toks_l.append(p)
+                            pages_l.append(plist[p // page_size])
+                            offs_l.append(p % page_size)
+                    # second placement pass — AFTER every member has matched and
+                    # registered, so identical same-group prompts share their
+                    # partial tail before anyone mutates it: resolve each
+                    # member's first-decode-write hazard (pos == total) inside
+                    # the reservation cow_tail sized — CoW a shared write page,
+                    # unpublish an owned registered tail.  The graft never
+                    # touches page widx when a CoW happens (covered == total
+                    # means nothing is grafted), so coords stay valid.
+                    widx = total // page_size
+                    for i, (s, rid, adm, n_em) in enumerate(group):
+                        if not placed[i] or gen_lens[rid] - n_em - 1 <= 0:
+                            continue
+                        plist = slot_pages[s]
+                        p = plist[widx]
+                        if alloc.shared(p):
+                            newp = alloc.cow(p)
+                            cow_src.append(p)
+                            cow_dst.append(newp)
+                            plist[widx] = newp
+                            table_rows[i, widx] = newp
+                        else:
+                            alloc.invalidate(p)
+                    if plan.take("graft"):
+                        # simulated graft failure, injected BEFORE the donating
+                        # graft call: the device cache is untouched, so recovery
+                        # is pure bookkeeping — back out every placement and
+                        # requeue the whole group at the queue head
+                        for i, (s, rid, adm, n_em) in enumerate(group):
+                            if placed[i]:
+                                alloc.release(slot_pages[s])
+                                slot_pages[s] = []
+                                placed[i] = False
+                        for rid in reversed([r for _, r, _, _ in group]):
+                            pending.appendleft((rid, prompts[rid]))
+                        continue
+                    srows = jnp.asarray([s for s, _, _, _ in group])
+                    cache["page_table"] = cache["page_table"].at[srows].set(
+                        jnp.asarray(table_rows, jnp.int32))
+                    cache["pos"] = cache["pos"].at[srows].set(total)
+                    for src, dst in zip(cow_src, cow_dst):
+                        # matched pages are already resident, so the CoW copy
+                        # can run before the graft (which only writes fresh
+                        # pages)
+                        cache = copy_fn(cache, jnp.asarray([src]),
+                                        jnp.asarray([dst]))
+                    # pad the graft to one fixed bucket per prompt length (the
+                    # padding re-writes mini token (0, 0) into the trash page)
+                    # so ragged admission counts don't retrace the jit
+                    pad = batch * total - len(rows_l)
+                    coords = [jnp.asarray(c + [0] * pad, jnp.int32)
+                              for c in (rows_l, toks_l, pages_l, offs_l)]
+                    cache = graft_fn(cache, mini, *coords)
+                    grafted[0] = True
+                    safe = jnp.asarray(np.where(slots < 0, batch, slots))
+                    tok_dev = tok_dev.at[safe].set(tok0, mode="drop")
+                    sample_pages()
+                else:
+                    if plan.take("graft"):
+                        for rid in reversed([r for _, r, _, _ in group]):
+                            pending.appendleft((rid, prompts[rid]))
+                        continue
+                    cache, tok_dev = admit_fn(cache, mini, jnp.asarray(slots),
+                                              tok_dev, tok0)
+                    grafted[0] = True
+                for rid in reversed(requeue):
+                    # placement-failed members go back to the queue head in
+                    # their original order
+                    pending.appendleft((rid, prompts[rid]))
+                tok0_np = np.asarray(tok0)[:, 0]  # sync BEFORE stamping TTFT
+                t_first = time.time() - t0
+                for i, (s, rid, adm, n_em) in enumerate(group):
+                    if not placed[i]:
+                        continue
+                    if stats["ttft"][rid] is None:
+                        # a resumed request keeps its FIRST admission's TTFT and
+                        # admit step — the preemption cost shows up in latency,
+                        # not as a fresh arrival
+                        stats["ttft"][rid] = t_first
+                        stats["admit_step"][rid] = stats["decode_steps"]
+                    rem = gen_lens[rid] - n_em - 1
+                    if spec:
+                        # (re)seed the drafter with the FULL admission context —
+                        # prompt + already-emitted for a resumed request — then
+                        # mirror the prefill token like any committed token
+                        drafter.begin(rid, adm)
+                        drafter.observe(rid, int(tok0_np[i]))
+                    if not _record_token(stats, rid, int(tok0_np[i]), eos, rem,
+                                         preempted=preempted_ever[rid],
+                                         t_now=t_first):
+                        active[s] = True
+                        slot_req[s] = rid
+                        slot_left[s] = rem
+                        slot_last[s] = int(tok0_np[i])
+                        slot_admit_seq[s] = admit_seq[0]
+                        admit_seq[0] += 1
+                        slot_pos[s] = plen + (n_prefix if paged else 0)
+                    elif spec:
+                        drafter.forget(rid)
+                if paged:
+                    for i, (s, rid, _, _) in enumerate(group):
+                        if placed[i] and not active[s]:
+                            # finished on its prefill token: nothing will ever be
+                            # decoded into these pages
                             alloc.release(slot_pages[s])
                             slot_pages[s] = []
-                            placed[i] = False
-                    for rid in reversed([r for _, r, _, _ in group]):
-                        pending.appendleft((rid, prompts[rid]))
-                    continue
-                srows = jnp.asarray([s for s, _, _, _ in group])
-                cache["page_table"] = cache["page_table"].at[srows].set(
-                    jnp.asarray(table_rows, jnp.int32))
-                cache["pos"] = cache["pos"].at[srows].set(total)
-                for src, dst in zip(cow_src, cow_dst):
-                    # matched pages are already resident, so the CoW copy
-                    # can run before the graft (which only writes fresh
-                    # pages)
-                    cache = copy_fn(cache, jnp.asarray([src]),
-                                    jnp.asarray([dst]))
-                # pad the graft to one fixed bucket per prompt length (the
-                # padding re-writes mini token (0, 0) into the trash page)
-                # so ragged admission counts don't retrace the jit
-                pad = batch * total - len(rows_l)
-                coords = [jnp.asarray(c + [0] * pad, jnp.int32)
-                          for c in (rows_l, toks_l, pages_l, offs_l)]
-                cache = graft_fn(cache, mini, *coords)
-                safe = jnp.asarray(np.where(slots < 0, batch, slots))
-                tok_dev = tok_dev.at[safe].set(tok0, mode="drop")
-                sample_pages()
-            else:
-                if plan.take("graft"):
-                    for rid in reversed([r for _, r, _, _ in group]):
-                        pending.appendleft((rid, prompts[rid]))
-                    continue
-                cache, tok_dev = admit_fn(cache, mini, jnp.asarray(slots), tok_dev, tok0)
-            for rid in reversed(requeue):
-                # placement-failed members go back to the queue head in
-                # their original order
-                pending.appendleft((rid, prompts[rid]))
-            tok0_np = np.asarray(tok0)[:, 0]  # sync BEFORE stamping TTFT
-            t_first = time.time() - t0
-            for i, (s, rid, adm, n_em) in enumerate(group):
-                if not placed[i]:
-                    continue
-                if stats["ttft"][rid] is None:
-                    # a resumed request keeps its FIRST admission's TTFT and
-                    # admit step — the preemption cost shows up in latency,
-                    # not as a fresh arrival
-                    stats["ttft"][rid] = t_first
-                    stats["admit_step"][rid] = stats["decode_steps"]
-                rem = gen_lens[rid] - n_em - 1
-                if spec:
-                    # (re)seed the drafter with the FULL admission context —
-                    # prompt + already-emitted for a resumed request — then
-                    # mirror the prefill token like any committed token
-                    drafter.begin(rid, adm)
-                    drafter.observe(rid, int(tok0_np[i]))
-                if not _record_token(stats, rid, int(tok0_np[i]), eos, rem,
-                                     preempted=preempted_ever[rid],
-                                     t_now=t_first):
-                    active[s] = True
-                    slot_req[s] = rid
-                    slot_left[s] = rem
-                    slot_last[s] = int(tok0_np[i])
-                    slot_admit_seq[s] = admit_seq[0]
-                    admit_seq[0] += 1
-                    slot_pos[s] = plen + (n_prefix if paged else 0)
-                elif spec:
-                    drafter.forget(rid)
-            if paged:
-                for i, (s, rid, _, _) in enumerate(group):
-                    if placed[i] and not active[s]:
-                        # finished on its prefill token: nothing will ever be
-                        # decoded into these pages
-                        alloc.release(slot_pages[s])
-                        slot_pages[s] = []
-                        cache["page_table"] = cache["page_table"].at[s].set(
-                            paging.TRASH_PAGE)
-                sample_pages()
-            # refresh the device mask per GROUP (not per round): a later
-            # group's chunk-boundary decode must advance this group's slots
-            active_dev = jnp.asarray(active)
-            dirty[0] = False
+                            cache["page_table"] = cache["page_table"].at[s].set(
+                                paging.TRASH_PAGE)
+                    sample_pages()
+                # refresh the device mask per GROUP (not per round): a later
+                # group's chunk-boundary decode must advance this group's slots
+                active_dev = jnp.asarray(active)
+                dirty[0] = False
         if not active.any():
             continue  # remaining pending requests all finished at prefill
         decode_round()
@@ -1176,6 +1204,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         alloc.leak_check()
     stats["faults_fired"] = list(plan.fired)
     stats["faults_unfired"] = plan.pending()
+    stats["spans"] = spans.records
     return _finalize(stats, occ, t0)
 
 
