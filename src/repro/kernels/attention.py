@@ -47,15 +47,34 @@ alignment.
 Paged KV: with `page_table` the K/V operands are a GLOBAL page
 pool `(num_pages, page_size, KVH, D)` shared by every slot, and the table
 `(B, max_pages)` names which physical page holds each slot's j-th logical
-key block.  The key-block size is pinned to the page size and the KV index
-map gains exactly one lookup — `pt[slot, j]` instead of `slot` — via a
-scalar-prefetch operand (PrefetchScalarGridSpec), so a ragged, paged,
-quantized decode step is STILL one launch: all masking, GQA folding,
-per-slot lengths and in-kernel int8 dequant compose unchanged, because page
-j of a slot holds logical key positions [j*page_size, (j+1)*page_size) and
-the existing kpos/kvl math never needs to know the keys are scattered in
-HBM.  Dead table entries point at page 0 (a reserved trash page), so culled
-blocks stay in-bounds.
+page, so page j of a slot holds logical key positions
+[j*page_size, (j+1)*page_size).  Two launches read the pool, chosen by the
+operands' shapes alone:
+
+  - a launch of more than one query a slot (verify windows) runs the flash
+    kernel above with the key block pinned to the page size: the KV index
+    map does one lookup, `pt[slot, j]` instead of `slot`, through a
+    scalar-prefetch operand (PrefetchScalarGridSpec), and the masking, GQA
+    walk, per-slot lengths and int8 dequant are the dense cache path's;
+  - a launch of ONE query a slot, the continuous scheduler's decode step,
+    takes the decode path (`_paged_decode`).  One page a grid step and a
+    walk of the heads with two tiny dots each cost ~70x the step's K/V
+    bytes at the HBM peak on a v5e, paid in step overhead and serial
+    per-head work, not in bytes.  The decode path's grid is (slot, group):
+    each step reads P pages of one slot, P page-sized blocks of the same
+    pool each looked up in the table, and folds them into every head at
+    once — the scores are the elementwise q * k product reduced over D, the
+    output the p * v product summed over the keys, the online-softmax state
+    (H, 1) and the accumulator (H, D) in f32 as in the flash kernel.  P
+    comes from the page size, KVH, D and dtype under the VMEM budget.  A
+    step past a slot's last live page moves no bytes (its blocks repeat the
+    indices before them) and computes nothing.  The pages go through
+    BlockSpecs, not manual copies: Mosaic refuses a DMA out of a pool whose
+    head size is below the 128 lanes (stablelm-1.6b has 64), as a slice of
+    its padded lane dim.
+
+Dead table entries point at page 0 (a reserved trash page), so every
+lookup stays in bounds.
 """
 
 from __future__ import annotations
@@ -67,6 +86,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import tiling
 from repro.kernels import _compat
 
 NEG_INF = -1e30
@@ -193,6 +213,193 @@ def _flash_kernel(
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+# --------------------------------------------------------------------------
+# Paged decode: one query per slot, many pages a grid step, every head at once
+# --------------------------------------------------------------------------
+
+#: pages of a slot one grid step reads at most: each is an operand of its
+#: own, so this bounds the launch's operand count and the unrolled body
+_MAX_GROUP_PAGES = 16
+#: keys one grid step reads at most
+_GROUP_KEYS = 256
+#: f32 vregs that one chunk's (keys, KVH, D) tile of K or V should fill: the
+#: chunk is the unit of the online-softmax update
+_CHUNK_VREGS = 32
+
+
+def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM bytes of a (rows, cols) slab: rows pad to the dtype's sublane
+    tile (8 for f32, 16 for bf16, 32 for int8), cols to the 128 lanes."""
+    item = jnp.dtype(dtype).itemsize
+    return (tiling.round_up(rows, tiling.SUBLANE * 4 // item)
+            * tiling.round_up(cols, tiling.MXU_DIM) * item)
+
+
+def decode_group_pages(page_size: int, kvh: int, d: int, dtype,
+                       quantized: bool, max_pages: int) -> int:
+    """P, the pages of a slot that one grid step reads: the largest power
+    of two up to `_MAX_GROUP_PAGES` that holds at most `_GROUP_KEYS` keys,
+    that a table row can fill, and whose double-buffered K and V pages (and
+    their scales) take at most a quarter of the VMEM budget."""
+    page = _vmem_tile_bytes(kvh, d, dtype)
+    if quantized:
+        page += _vmem_tile_bytes(kvh, 1, jnp.float32)
+    page *= page_size * 2 * 2            # K and V, two buffers each
+    p = 1
+    while (2 * p <= min(_MAX_GROUP_PAGES, max_pages)
+           and 2 * p * page_size <= _GROUP_KEYS
+           and 2 * p * page <= tiling.DEFAULT_VMEM_BUDGET // 4):
+        p *= 2
+    return p
+
+
+def _decode_chunk_keys(page_size: int, kvh: int, d: int) -> int:
+    """Keys per online-softmax update: the largest divisor of the page size
+    whose f32 (keys, KVH, D) tile fills at most `_CHUNK_VREGS` vregs."""
+    per_key = tiling.cdiv(kvh, tiling.SUBLANE) * tiling.cdiv(d, tiling.MXU_DIM)
+    target = max(1, _CHUNK_VREGS // per_key)
+    return max(c for c in range(1, min(target, page_size) + 1)
+               if page_size % c == 0)
+
+
+def _live_pages(len_ref, r, page_size: int, max_pages: int):
+    """Pages of slot r that hold a key below its length."""
+    return jnp.minimum(pl.cdiv(len_ref[r], page_size), max_pages)
+
+
+def _group_page(r, j, pt_ref, len_ref, *, i: int, pages: int,
+                page_size: int, max_pages: int):
+    """Pool page of operand i at grid step (r, j): the table's entry for
+    column j * P + i of slot r.  A step past the slot's last group repeats
+    that group, and an operand past the last live page repeats its page of
+    the group before, so neither moves a byte (the pipeline copies a block
+    only when its index changes); in a slot's first group it reads the
+    trash page 0, fetched once for as long as the operand stays there."""
+    live = _live_pages(len_ref, r, page_size, max_pages)
+    jj = jnp.minimum(j, jnp.maximum(pl.cdiv(live, pages), 1) - 1)
+    col = jj * pages + i
+    held = jnp.where(jj > 0, pt_ref[r, jnp.maximum(col - pages, 0)], 0)
+    return jnp.where(col < live, pt_ref[r, jnp.minimum(col, max_pages - 1)],
+                     held)
+
+
+def _paged_decode_kernel(
+    pt_ref, len_ref, q_ref, *refs,
+    page_size: int, pages: int, chunk: int, max_pages: int, groups: int,
+    scale: float, quantized: bool,
+):
+    del pt_ref  # read by the index maps alone
+    per_page = 4 if quantized else 2
+    page_refs = [refs[i * per_page:(i + 1) * per_page] for i in range(pages)]
+    o_ref, m_ref, l_ref, acc_ref = refs[pages * per_page:]
+    r = pl.program_id(0)
+    j = pl.program_id(1)
+    kvl = jnp.minimum(len_ref[r], max_pages * page_size)
+    live = _live_pages(len_ref, r, page_size, max_pages)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def attend(refs, first: int, kpos0):
+        """Fold keys [first, first + chunk) of one page, logical positions
+        kpos0 onwards, into every head's softmax state."""
+        tile = [ref[0, first:first + chunk] for ref in refs]
+        k = tile[0].astype(jnp.float32)                  # (C, KVH, D)
+        v = tile[1].astype(jnp.float32)
+        if quantized:
+            k = k * tile[2]                              # (C, KVH, 1) scales
+            v = v * tile[3]
+        # the one query sits at position kvl - 1, so the causal mask is the
+        # length mask; V rows past it are zeroed (p = 0 does not clear a
+        # non-finite row: 0 * NaN)
+        kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1, 1), 0)
+        keep = kpos < kvl
+        v = jnp.where(keep, v, 0.0)
+        q = q_ref[0].astype(jnp.float32) * scale         # (g, KVH, D)
+        for g in range(groups):
+            # all KV heads at once: the scores are the elementwise q * k
+            # product reduced over D, the output the p * v product summed
+            # over the keys
+            s = jnp.sum(q[g][None] * k, axis=-1, keepdims=True)  # (C, KVH, 1)
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[g]                                    # (KVH, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(p, axis=0)
+            acc_ref[g] = alpha * acc_ref[g] + jnp.sum(p * v, axis=0)
+            m_ref[g] = m_new
+
+    for i, refs_i in enumerate(page_refs):
+        col = j * pages + i
+
+        @pl.when(col < live)
+        def _page(refs_i=refs_i, col=col):
+            for first in range(0, page_size, chunk):
+                attend(refs_i, first, col * page_size + first)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _flush():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _paged_decode(q, k, v, k_scales, v_scales, kv_lens, page_table, *,
+                  kv_groups: int, scale: float, interpret: bool):
+    """One query per slot over the page pool: grid (slots, table columns /
+    P), each step reading P pages of one slot through P page-sized blocks
+    of the same pool (module docstring)."""
+    b, _, h, d = q.shape
+    _, page_size, kvh, _ = k.shape
+    max_pages = page_table.shape[1]
+    quantized = k_scales is not None
+    pages = decode_group_pages(page_size, kvh, d, k.dtype, quantized,
+                               max_pages)
+    g = kv_groups
+    # query head kv * g + j attends stored head kv: as (g, KVH, D), each of
+    # the g rows lines up with the stored heads of a K/V page
+    qg = q.reshape(b, kvh, g, d).swapaxes(1, 2)
+    kernel = functools.partial(
+        _paged_decode_kernel, page_size=page_size, pages=pages,
+        chunk=_decode_chunk_keys(page_size, kvh, d), max_pages=max_pages,
+        groups=g, scale=scale, quantized=quantized)
+    q_spec = pl.BlockSpec((1, g, kvh, d), lambda r, j, *_: (r, 0, 0, 0))
+    pools = [k, v]
+    if quantized:
+        pools += [k_scales.astype(jnp.float32), v_scales.astype(jnp.float32)]
+    operands, in_specs = [qg], [q_spec]
+    for i in range(pages):
+        index = functools.partial(_group_page, i=i, pages=pages,
+                                  page_size=page_size, max_pages=max_pages)
+        for pool in pools:
+            operands.append(pool)
+            in_specs.append(pl.BlockSpec(
+                (1, page_size, kvh, pool.shape[-1]),
+                lambda r, j, pt, lens, index=index: (
+                    index(r, j, pt, lens), 0, 0, 0)))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, pl.cdiv(max_pages, pages)),
+            in_specs=in_specs,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((g, kvh, 1), jnp.float32),
+                pltpu.VMEM((g, kvh, 1), jnp.float32),
+                pltpu.VMEM((g, kvh, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=_compat.compiler_params("parallel", "arbitrary"),
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32).reshape(b),
+      *operands)
+    return out.swapaxes(1, 2).reshape(q.shape)
+
+
 def attention(
     q: jnp.ndarray,  # (B, Tq, H, D)
     k: jnp.ndarray,  # (B, Tk, H // kv_groups, D); int8 when k_scales is given
@@ -235,8 +442,10 @@ def attention(
     materialized.  The body walks the heads with 2-D tiles.
     With `page_table` the k/v (and scale) operands are the PAGE POOL
     `(num_pages, page_size, KVH, D)` and the logical key stream of slot b is
-    `pool[page_table[b, 0]], pool[page_table[b, 1]], ...` — block_k is pinned
-    to page_size and the KV index map does the one table lookup.
+    `pool[page_table[b, 0]], pool[page_table[b, 1]], ...`.  One query a
+    slot takes the decode path (P pages a grid step, every head at once;
+    block_q and block_k do not apply); more queries pin block_k to
+    page_size and the KV index map does the one table lookup.
     """
     paged = page_table is not None
     b, tq, h, d = q.shape
@@ -250,6 +459,14 @@ def attention(
     kv_len = tk if kv_len is None else kv_len
     if scale is None:
         scale = d ** -0.5
+    if paged and tq == 1:
+        # one query a slot, the decode step: P pages a grid step and every
+        # head at once (module docstring)
+        if kv_lens is None:
+            kv_lens = jnp.full((b,), kv_len, jnp.int32)
+        return _paged_decode(q, k, v, k_scales, v_scales, kv_lens,
+                             page_table, kv_groups=kv_groups, scale=scale,
+                             interpret=interpret)
     block_q = min(block_q, tq)
     # paged: one key block per page — the block grid IS the page table row
     block_k = k.shape[1] if paged else min(block_k, tk)

@@ -504,10 +504,14 @@ def flash_attention(q, k, v, *, k_scales=None, v_scales=None, kv_lens=None,
     key positions (prefix-LM, e.g. the paligemma patch prefix).
 
     With `page_table` (B, max_pages) the k/v (and scale) operands are the
-    paged KV POOL (num_pages, page_size, KVH, D): the key-block grid walks
-    the table row and the kernel's KV index map does the one physical-page
-    lookup via scalar prefetch — a ragged, paged, quantized decode step is
-    still exactly one launch.
+    paged KV POOL (num_pages, page_size, KVH, D), and the table lookups ride
+    in SMEM via scalar prefetch.  A launch of one query a slot (the decode
+    step) takes the decode path: each grid step reads P pages of a slot and
+    attends every head at once, and steps past a slot's live pages move
+    nothing.  More queries a slot (verify windows) walk the table row one
+    page-sized key block at a time.  Either way a ragged, paged, quantized
+    decode step is exactly one launch, and the launch keeps this function's
+    name (`flash_attention`), which the compiled custom call takes.
 
     This is the ONE attention engine: every mask variant (causal, prefix-LM,
     non-causal), both cache dtypes, GQA, and the paged pool layout route

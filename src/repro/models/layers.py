@@ -436,9 +436,9 @@ def _flash_cache_attention(q, kv, vv, pos, t: int, groups: int, *,
     else out to the dequant fallback).
 
     With `page_table` (B, max_pages) the kv/vv (and ks/vs) operands are the
-    paged POOL (num_pages, page_size, KVH, ...) and the kernel's KV index
-    map does the one table lookup — ragged + paged + quantized is still ONE
-    launch.
+    paged POOL (num_pages, page_size, KVH, ...), looked up through the table
+    in-kernel (a one-token step takes the kernel's decode path) — ragged +
+    paged + quantized is still ONE launch.
     """
     lens = _slot_kv_lens(pos, t, q.shape[0])
     from repro.kernels import ops

@@ -2,10 +2,12 @@
 shared-prefix reuse and copy-on-write.
 
 The acceptance contract:
-  - the paged flash kernel is BIT-identical to the dense flash kernel run at
-    block_k = page_size (same blocks, same accumulation order) for every
-    ragged-length x page-size x GQA x dense/int8 cell, and matches the exact
-    paged dequant oracle (kernels.ref.attention_paged*) numerically;
+  - a one-query paged launch (the decode step) takes the decode path, P
+    pages a grid step with every head at once, and matches the exact paged
+    (dequant) oracle (kernels.ref.attention_paged*) numerically for every
+    length x page-size x GQA x dense/int8 cell; a launch of more queries
+    (verify windows) keeps the flash kernel, BIT-identical to the dense
+    flash kernel run at block_k = page_size (same blocks, same order);
   - the host allocator (launch.paging) enforces refcounts, exact-tail
     partial-page matching, first-writer-wins registration and CoW
     bookkeeping, and can never hand out the trash page;
@@ -28,7 +30,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import blas, quant
-from repro.kernels import ops, ref
+from repro.kernels import attention, ops, ref
 from repro.launch import paging
 from repro.launch.serve import serve
 from repro.models import transformer as tf
@@ -134,19 +136,24 @@ def test_allocator_capacity_multiplier_counts_logical_pages():
 # Paged flash kernel: page-table index math vs dense flash vs exact oracle
 # --------------------------------------------------------------------------
 
-def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0):
-    """Build one ragged paged-decode cell and return (paged, dense, oracle)
-    outputs.  The dense kernel runs at block_k=page_size on the contiguous
-    gather of the same pages, so it visits identical key blocks in identical
-    order — the paged kernel must be BIT-identical, not just close."""
+def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0,
+                       q_len=1):
+    """Build one ragged paged cell of `q_len` queries a slot and return
+    (paged, dense, oracle) outputs.  The dense kernel runs at
+    block_k=page_size on the contiguous gather of the same pages, so with
+    q_len > 1, where both launches run the flash kernel, it visits identical
+    key blocks in identical order: the paged launch must then be
+    BIT-identical, not just close.  A one-query paged launch takes the
+    decode path, which blocks the keys its own way."""
     rng = np.random.default_rng(seed)
     b, kvh, hd = 2, 2, 8
     h = kvh * groups
-    lens = np.array([seq_len, max(1, seq_len // 2)], np.int32)
+    seq_len = max(seq_len, q_len)
+    lens = np.array([seq_len, max(q_len, seq_len // 2)], np.int32)
     n_pages = -(-seq_len // page_size)
     tk = n_pages * page_size
 
-    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, q_len, h, hd)), jnp.float32)
     kd = jnp.asarray(rng.standard_normal((b, tk, kvh, hd)), jnp.float32)
     vd = jnp.asarray(rng.standard_normal((b, tk, kvh, hd)), jnp.float32)
     # shuffled table: logical page j of slot s lives at a random pool page
@@ -196,14 +203,114 @@ def _paged_kernel_case(seq_len, page_size, groups, quantized, seed=0):
        quantized=st.integers(min_value=0, max_value=1))
 def test_paged_flash_matches_dense_flash_and_oracle(seq_len, page_size,
                                                     groups, quantized):
+    """One query a slot: the paged launch takes the decode path, whose key
+    blocking differs from the dense kernel's, so both are held to the exact
+    oracle (bit identity with the dense kernel is the shared-kernel case
+    below)."""
     with blas.use_backend("pallas"):
         paged, dense, oracle = _paged_kernel_case(seq_len, page_size, groups,
                                                   bool(quantized))
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(dense), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
+
+
+@settings(deadline=None, max_examples=6)
+@given(seq_len=st.integers(min_value=1, max_value=21),
+       page_size=st.integers(min_value=1, max_value=8),
+       groups=st.integers(min_value=1, max_value=3),
+       quantized=st.integers(min_value=0, max_value=1),
+       q_len=st.integers(min_value=2, max_value=5))
+def test_paged_flash_window_is_dense_flash_bitwise(seq_len, page_size, groups,
+                                                   quantized, q_len):
+    """More than one query a slot (a verify window): paged and dense launches
+    share the flash kernel, so the page table may change where keys live and
+    nothing else: bit for bit the dense kernel at block_k = page_size."""
+    with blas.use_backend("pallas"):
+        paged, dense, oracle = _paged_kernel_case(seq_len, page_size, groups,
+                                                  bool(quantized), q_len=q_len)
     assert jnp.array_equal(paged, dense), (
         "paged flash must be bit-identical to dense flash at "
         f"block_k=page_size (seq={seq_len} ps={page_size} g={groups} "
-        f"int8={quantized})")
+        f"int8={quantized} q_len={q_len})")
     np.testing.assert_allclose(np.asarray(paged), np.asarray(oracle),
+                               rtol=2e-5, atol=2e-5)
+
+
+# the decode path's own cases: a slot length of one key, of exactly P pages,
+# of P pages and one key, and off a page boundary
+_DECODE_LENGTHS = {
+    "one": lambda p, ps: 1,
+    "p_pages": lambda p, ps: p * ps,
+    "p_pages_plus_1": lambda p, ps: p * ps + 1,
+    "ragged": lambda p, ps: p * ps + ps // 2 + (ps == 1) * 3 + 1,
+}
+
+
+def _decode_case(kind, page_size, groups, quantized, seed):
+    """Three slots through a shuffled page table: slot 0 of the `kind`
+    length, slot 1 more than one group of P pages longer, slot 2 of a few
+    keys; every table column past a slot's last page is dead (trash page 0).
+    Returns (decode path, oracle)."""
+    rng = np.random.default_rng(seed)
+    b, kvh, hd = 3, 2, 8
+    h = kvh * groups
+    dtype = jnp.int8 if quantized else jnp.bfloat16
+    p = attention.decode_group_pages(page_size, kvh, hd, dtype, quantized,
+                                     max_pages=1 << 20)
+    first = _DECODE_LENGTHS[kind](p, page_size)
+    lens = np.array([first,
+                     first + 2 * p * page_size + int(rng.integers(0, 5)),
+                     int(rng.integers(1, 4))], np.int32)
+    n_pages = -(-lens // page_size)
+    cols = int(n_pages.max()) + 2
+    assert attention.decode_group_pages(page_size, kvh, hd, dtype, quantized,
+                                        cols) == p
+    num_pages = 1 + int(n_pages.sum()) + 3           # spare pages, unread
+    perm = rng.permutation(np.arange(1, num_pages))
+    table = np.zeros((b, cols), np.int32)
+    at = 0
+    for s, n in enumerate(n_pages):
+        table[s, :n] = perm[at:at + n]
+        at += n
+    shape = (num_pages, page_size, kvh, hd)
+    k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, 1, h, hd)), jnp.float32)
+    table, kv_lens = jnp.asarray(table), jnp.asarray(lens)
+    row_lens = jnp.asarray(np.repeat(lens, h))
+    kw = dict(kv_lens=kv_lens, page_table=table, kv_groups=groups)
+    if quantized:
+        kq, vq = quant.quantize_kv(k), quant.quantize_kv(v)
+        got = ops.flash_attention(q, kq.values, vq.values, k_scales=kq.scales,
+                                  v_scales=vq.scales, **kw)
+        want = ref.attention_paged_kv_dequant(
+            q, kq.values, kq.scales, vq.values, vq.scales, table, row_lens)
+    else:
+        # bf16 pages, as served; f32 queries, so the output is not rounded
+        k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+        got = ops.flash_attention(q, k, v, **kw)
+        want = ref.attention_paged(q, k, v, table, row_lens)
+    return got, want
+
+
+@pytest.mark.parametrize("kind", list(_DECODE_LENGTHS))
+@settings(deadline=None, max_examples=4)
+@given(page_size=st.integers(min_value=1, max_value=16),
+       groups=st.integers(min_value=1, max_value=3),
+       quantized=st.integers(min_value=0, max_value=1),
+       seed=st.integers(min_value=0, max_value=2 ** 31))
+def test_paged_decode_path_matches_oracle(kind, page_size, groups, quantized,
+                                          seed):
+    """The decode path against the exact paged oracles: bf16-class and int8
+    pools, GQA, page sizes 1-16, slot lengths on and off the group and page
+    boundaries, slots whose live pages differ by more than one group, and
+    dead table columns."""
+    with blas.use_backend("pallas"):
+        got, want = _decode_case(kind, page_size, groups, bool(quantized),
+                                 seed)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -415,6 +522,100 @@ def test_paged_decode_is_one_flash_launch(monkeypatch):
 
 def _boom(*a, **k):  # the dense fallback must be unreachable under pallas
     raise AssertionError("paged pallas serve fell back to attention_core")
+
+
+def _launch(kind):
+    """Operands of one launch of each kind the serving stack makes:
+    (q, k, v, keyword arguments)."""
+    rng = np.random.default_rng(0)
+    b, h, hd, ps = 2, 4, 8, 4
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    table = jnp.asarray([[3, 1, 0], [2, 4, 5]], jnp.int32)
+    lens = jnp.asarray([6, 11], jnp.int32)
+    pool = (6, ps, h, hd)
+    return {
+        "paged_decode": (arr(b, 1, h, hd), arr(*pool), arr(*pool),
+                         dict(kv_lens=lens, page_table=table)),
+        "paged_verify": (arr(b, 3, h, hd), arr(*pool), arr(*pool),
+                         dict(kv_lens=lens, page_table=table)),
+        "dense_decode": (arr(b, 1, h, hd), arr(b, 12, h, hd),
+                         arr(b, 12, h, hd), dict(kv_lens=lens)),
+        "prefill": (arr(b, 8, h, hd), arr(b, 8, h, hd), arr(b, 8, h, hd),
+                    {}),
+        "chunked_admission": (arr(b, 4, h, hd), arr(b, 12, h, hd),
+                              arr(b, 12, h, hd),
+                              dict(kv_lens=jnp.asarray([8, 8], jnp.int32))),
+        "cross": (arr(b, 3, h, hd), arr(b, 7, h, hd), arr(b, 7, h, hd),
+                  dict(causal=False)),
+        "prefix_lm": (arr(b, 8, h, hd), arr(b, 8, h, hd), arr(b, 8, h, hd),
+                      dict(prefix_len=3)),
+    }[kind]
+
+
+@pytest.mark.parametrize("kind", ["paged_decode", "paged_verify",
+                                  "dense_decode", "prefill",
+                                  "chunked_admission", "cross", "prefix_lm"])
+def test_only_one_query_paged_launches_take_the_decode_path(kind,
+                                                            monkeypatch):
+    """The decode path is chosen by the operands alone: a page table and one
+    query a slot.  Every other launch runs the flash kernel as before."""
+    routes = []
+    real_decode, real_flash = attention._paged_decode, attention._flash_kernel
+
+    def decode(*a, **kw):
+        routes.append("decode")
+        return real_decode(*a, **kw)
+
+    def flash(*a, **kw):
+        routes.append("flash")
+        return real_flash(*a, **kw)
+
+    monkeypatch.setattr(attention, "_paged_decode", decode)
+    monkeypatch.setattr(attention, "_flash_kernel", flash)
+    q, k, v, kw = _launch(kind)
+    out = attention.attention(q, k, v, interpret=True, **kw)
+    assert out.shape == q.shape
+    assert routes and set(routes) == {
+        "decode" if kind == "paged_decode" else "flash"}, routes
+
+
+def test_paged_serve_routes_decode_and_windows(monkeypatch):
+    """A paged serve with speculation and chunked admission: each one-query
+    launch on the page table takes the decode path, and verify windows,
+    admission chunks and prefills keep the flash kernel."""
+    calls = []
+    real_attention, real_decode = attention.attention, attention._paged_decode
+
+    def spy_attention(q, k, v, **kw):
+        calls.append({"q_len": q.shape[1],
+                      "paged": kw.get("page_table") is not None,
+                      "decode": False})
+        return real_attention(q, k, v, **kw)
+
+    def spy_decode(*a, **kw):
+        calls[-1]["decode"] = True
+        return real_decode(*a, **kw)
+
+    monkeypatch.setattr(attention, "attention", spy_attention)
+    monkeypatch.setattr(attention, "_paged_decode", spy_decode)
+    jax.clear_caches()   # trace every launch afresh, so the spies see it
+    cfg = get_config(ARCH, "smoke")
+    prompts = _prompts_uniform(cfg.vocab, n=3)
+    for speculate in (None, 2):
+        stats = serve(ARCH, "smoke", batch=2, eos=NO_EOS, verbose=False,
+                      backend="pallas", scheduler="continuous",
+                      prompts=prompts, gen_lens=[4, 6, 3], kv_page_size=4,
+                      prefill_chunk=4, speculate=speculate)
+        assert stats["completed"] == 3
+    for c in calls:
+        assert c["decode"] == (c["paged"] and c["q_len"] == 1), c
+    kinds = {(c["paged"], c["q_len"] > 1) for c in calls}
+    assert (True, False) in kinds, "no paged decode step seen"
+    assert (True, True) in kinds, "no verify window seen"
+    assert (False, True) in kinds, "no admission prefill seen"
 
 
 # --------------------------------------------------------------------------

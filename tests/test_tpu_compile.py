@@ -117,25 +117,38 @@ def test_flash_cache_layout_decode_compiles(one_chip):
              _spec(one_chip, (B,), jnp.int32))
 
 
-@pytest.mark.parametrize("page_size", [1, 16])
+@pytest.mark.parametrize("shape", [
+    # (slots, query heads, KV heads, head size, page size, pool pages,
+    #  table columns)
+    pytest.param((B, H, H, HD, 1, 1 + B * CACHE, CACHE), id="1"),
+    pytest.param((B, H, H, HD, 16, 1 + B * CACHE // 16, CACHE // 16),
+                 id="16"),
+    # the benchmark cell's pool: 777 pages of 16 x 32 x 64, 96 columns
+    pytest.param((B, H, H, HD, 16, 777, 96), id="cell"),
+    # GQA at internlm2-20b's widths: 48 query heads on 8 KV heads of 128
+    pytest.param((B, 48, 8, 128, 16, 777, 96), id="gqa"),
+])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_flash_paged_decode_compiles(one_chip, page_size, kv):
-    """Paged decode through the page table, bf16 and packed int8 KV.  Page
-    size 1 is the smallest a pool can have: whole-head blocks keep the
+def test_flash_paged_decode_compiles(one_chip, shape, kv):
+    """One-query paged decode through the page table, bf16 and packed int8
+    KV: the decode path, P page-sized blocks of the pool a grid step.  Page
+    size 1 is the smallest a pool can have: whole-head blocks keep each
     block's last two dims at (KVH, D) whatever the page size."""
-    max_pages = CACHE // page_size
-    pool = (1 + B * max_pages, page_size, H, HD)
-    q = _spec(one_chip, (B, 1, H, HD))
-    lens = _spec(one_chip, (B,), jnp.int32)
-    table = _spec(one_chip, (B, max_pages), jnp.int32)
+    b, h, kvh, hd, page_size, pages, cols = shape
+    pool = (pages, page_size, kvh, hd)
+    q = _spec(one_chip, (b, 1, h, hd))
+    lens = _spec(one_chip, (b,), jnp.int32)
+    table = _spec(one_chip, (b, cols), jnp.int32)
+    groups = h // kvh
     if kv == "int8":
         kq = _spec(one_chip, pool, jnp.int8)
         ks = _spec(one_chip, pool[:-1] + (1,), jnp.float32)
         _compile(lambda q, k, v, ks, vs, lens, pt: attention.attention(
-            q, k, v, k_scales=ks, v_scales=vs, kv_lens=lens, page_table=pt),
+            q, k, v, k_scales=ks, v_scales=vs, kv_lens=lens, page_table=pt,
+            kv_groups=groups),
             q, kq, kq, ks, ks, lens, table)
     else:
         kb = _spec(one_chip, pool)
         _compile(lambda q, k, v, lens, pt: attention.attention(
-            q, k, v, kv_lens=lens, page_table=pt),
+            q, k, v, kv_lens=lens, page_table=pt, kv_groups=groups),
             q, kb, kb, lens, table)
