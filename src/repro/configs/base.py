@@ -65,6 +65,7 @@ class ModelConfig:
     head_dim: Optional[int] = None   # default d_model // n_heads
     act: str = "swiglu"              # swiglu | geglu | gelu
     norm: str = "rms"                # rms | ln
+    norm_eps: Optional[float] = None  # None: 1e-6 for rms, 1e-5 for ln
     use_bias: bool = False
     qk_norm: bool = False
     parallel_block: bool = False     # command-r style parallel attn+mlp

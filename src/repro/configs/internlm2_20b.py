@@ -5,7 +5,8 @@ from repro.configs.base import ModelConfig
 FULL = ModelConfig(
     arch_id="internlm2-20b", family="dense",
     d_model=6144, n_layers=48, n_heads=48, n_kv=8, d_ff=16384, vocab=92544,
-    head_dim=128, act="swiglu", norm="rms", rope_theta=1e6, tie_embeddings=False,
+    head_dim=128, act="swiglu", norm="rms", norm_eps=1e-5, rope_theta=1e6,
+    tie_embeddings=False,
 )
 
 SMOKE = ModelConfig(

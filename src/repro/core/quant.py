@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -159,6 +160,11 @@ def _expand_scales(scales: jnp.ndarray, block: Tuple[int, int], shape: tuple) ->
     return s.reshape(shape)
 
 
+@jax.jit
+def _all_finite(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.isfinite(x).all()
+
+
 def quantize(x: jnp.ndarray, spec: QuantSpec = QuantSpec(),
              validate: bool = False) -> QuantizedTensor:
     """Symmetric per-block int8 quantization over the last two dims.
@@ -180,14 +186,15 @@ def quantize(x: jnp.ndarray, spec: QuantSpec = QuantSpec(),
       unspecified), so a downstream scale-finiteness check — the serving
       invariant in `launch.faults` — always detects the corruption; nothing
       silently launders a non-finite weight into a plausible scale.  With
-      ``validate=True`` (concrete inputs only, e.g. weight packing at serve
-      startup) non-finite inputs raise ``ValueError`` up front instead;
+      ``validate=True`` (concrete inputs only; `pack`, which packs the
+      serving weights at start-up, always validates) non-finite inputs
+      raise ``ValueError`` up front instead;
       traced inputs cannot be validated and always use the propagate path.
     """
     if x.ndim < 2:
         raise ValueError(f"quantize needs a matrix, got shape {x.shape}")
     if validate and not isinstance(x, jax.core.Tracer):
-        if not bool(jnp.isfinite(x).all()):
+        if not bool(_all_finite(x)):
             raise ValueError(
                 "quantize(validate=True): input contains NaN/Inf — refusing "
                 "to pack a corrupt tensor (the scale would be non-finite)")
@@ -204,6 +211,42 @@ def quantize(x: jnp.ndarray, spec: QuantSpec = QuantSpec(),
     values = jnp.clip(q, -INT8_MAX, INT8_MAX).astype(jnp.int8).reshape(x.shape)
     return QuantizedTensor(values=values, scales=scales, block=(qm, qn),
                            transposed=spec.transpose)
+
+
+@functools.partial(jax.jit, static_argnums=3, donate_argnums=0)
+def _pack_into(packed: QuantizedTensor, x: jnp.ndarray, i: jnp.ndarray,
+               spec: QuantSpec) -> QuantizedTensor:
+    """`packed` with matrix `i` of the stack `x` (leading dims flattened)
+    quantized into its place."""
+    flat = lambda a: a.reshape((-1,) + a.shape[-2:])
+    qt = quantize(flat(x)[i], spec)
+    return QuantizedTensor(
+        values=flat(packed.values).at[i].set(qt.values).reshape(
+            packed.values.shape),
+        scales=flat(packed.scales).at[i].set(qt.scales).reshape(
+            packed.scales.shape),
+        block=qt.block, transposed=qt.transposed)
+
+
+def pack(x: jnp.ndarray, spec: QuantSpec = QuantSpec()) -> QuantizedTensor:
+    """`quantize(x, spec, validate=True)` for a weight, one matrix of its
+    leading (layer or expert) dims at a time, under one compiled call per
+    shape: the float32 temporaries span one matrix, not the stack, so a
+    stack that fills most of the device packs beside itself.  Values and
+    scales are bitwise those of `quantize`."""
+    if not isinstance(x, jax.core.Tracer) and not bool(_all_finite(x)):
+        raise ValueError(
+            "pack: input contains NaN/Inf — refusing to pack a corrupt "
+            "tensor (the scale would be non-finite)")
+    if x.ndim <= 2:
+        return quantize(x, spec)
+    # a loop inside one program would not do: the compiler hoists the
+    # float32 cast of the whole stack out of it
+    packed = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                          jax.eval_shape(lambda a: quantize(a, spec), x))
+    for i in range(math.prod(x.shape[:-2])):
+        packed = _pack_into(packed, x, jnp.int32(i), spec)
+    return packed
 
 
 def scales_finite(qt: QuantizedTensor) -> bool:
@@ -517,9 +560,8 @@ def align_blocks_for_sharding(qt: QuantizedTensor, shards: int,
     if size % shards:
         raise ValueError(
             f"stored dim {dim} of size {size} not divisible by {shards}")
-    import math as _math
     old = qt.block[dim]
-    new = _math.gcd(old, size // shards)
+    new = math.gcd(old, size // shards)
     if new == old:
         return qt
     scales = jnp.repeat(qt.scales, old // new, axis=ax)

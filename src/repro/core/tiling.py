@@ -108,6 +108,20 @@ def epilogue_vmem_bytes(blk: BlockShape, dtype_bytes: int, *,
     return extra
 
 
+#: width of a packed B tile once the kernel dequantizes it: to the f32
+#: accumulator's dtype (`kernels.gemv.dequant_tile`)
+DEQUANT_BYTES = 4
+
+
+def dequant_vmem_bytes(blk: BlockShape, *, gate: bool = False) -> int:
+    """VMEM a packed-weight (mixed-width) grid step claims besides its
+    pipelined tiles: the dequantized B tile at f32, and the gate operand's
+    beside it.  Mosaic places these on the kernel's stack; on a v5e the
+    int8 dual-B SwiGLU at (bm, bn, bk) = (1024, 2048, 2048) asks 72.77 MiB
+    in all, which this charge keeps out of a 64 MiB plan."""
+    return (2 if gate else 1) * blk.bk * blk.bn * DEQUANT_BYTES
+
+
 def rank_block_shapes(
     m: int,
     n: int,
@@ -166,7 +180,8 @@ def rank_block_shapes(
                     used = cand.vmem_bytes(dtype_bytes)
                 else:
                     used = (2 * (bm * bk * dtype_bytes + bk * bn * b_bytes)
-                            + bm * bn * 4 + bm * bn * dtype_bytes)
+                            + bm * bn * 4 + bm * bn * dtype_bytes
+                            + dequant_vmem_bytes(cand, gate=gate))
                 # the gate operand is a second B stream (packed width when
                 # quantized); the residual tile is activation-width
                 used += epilogue_vmem_bytes(cand, b_bytes, gate=gate)

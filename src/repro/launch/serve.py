@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import blas
+from repro.core import blas, quant
 from repro.launch import compile_cache
 from repro.launch import draft as draft_lib
 from repro.launch import faults as faults_lib
@@ -172,7 +172,9 @@ def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, bat
     generated token), `outputs` (greedy token ids per request, in submission
     order) and per-request admit/finish decode-step indices.  The
     continuous scheduler also returns its program spans, `spans` (one per
-    decode round, token read-back and admission group; `launch/spans.py`).
+    decode round, token read-back and admission group, and in set-up, before
+    t0, the weight packing `serve.pack`, whose length is `pack_s`;
+    `launch/spans.py`).
     """
     cfg = get_config(arch, variant)
     rng = np.random.default_rng(seed)
@@ -431,11 +433,24 @@ def _admit_step(cache, mini, slots, tok, tok0):
     return cache, tok
 
 
-def _quantize_params(params, quantize: str):
-    if quantize == "int8":
-        from repro.models import layers
-        return layers.quantize_weights(params)
-    return params
+def _quantize_params(params, quantize: str, spans):
+    """The serving weights: with `quantize="int8"` every projection packed
+    (`layers.quantize_weights`), as one `serve.pack` span in `spans` with
+    the leaves packed and their bytes before and after."""
+    if quantize != "int8":
+        return params
+    from repro.models import layers
+    with spans.span("serve.pack") as pack:
+        packed = jax.block_until_ready(layers.quantize_weights(params))
+        pairs = [(w, q) for w, q in zip(
+            jax.tree.leaves(params),
+            jax.tree.leaves(packed, is_leaf=quant.is_quantized))
+            if quant.is_quantized(q)]
+        pack.set(leaves=len(pairs),
+                 bytes_in=sum(w.nbytes for w, _ in pairs),
+                 bytes_out=sum(q.values.nbytes + q.scales.nbytes
+                               for _, q in pairs))
+    return packed
 
 
 def _make_tp_context(cfg, params, tp: int):
@@ -505,7 +520,11 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     cache_len = _cache_len(cfg, prompts, gen_lens) + spec
     rng = np.random.default_rng(seed + 1)
 
-    params = _quantize_params(tf.init_params(jax.random.PRNGKey(seed), cfg), quantize)
+    # the recorder starts before set-up: its records move onto the clock
+    # of t0 below, so a set-up span (`serve.pack`) ends before 0
+    spans = spans_lib.Spans(time.time())
+    params = _quantize_params(tf.init_params(jax.random.PRNGKey(seed), cfg),
+                              quantize, spans)
     tp_ctx = _make_tp_context(cfg, params, tp)
     if tp_ctx is not None:
         params = tp_ctx["params"]
@@ -654,6 +673,8 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     # next decode round refreshes it once instead of per event
     dirty = [False]
     stats = _new_stats(nreq)
+    pack = spans.last("serve.pack")
+    stats["pack_s"] = pack["end"] - pack["start"] if pack else 0.0
     if paged:
         stats.update({"kv_page_size": page_size, "pages_live": 0,
                       "pages_shared": 0, "paged_capacity_multiplier": 0.0,
@@ -676,7 +697,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
 
     occ = []
     t0 = time.time()
-    spans = spans_lib.Spans(t0)
+    spans.rebase(t0)
     # inter-token stall trackers for LIVE slots: the first record of the
     # current busy period (a stall is measured between the ends of two
     # `serve.round.wait`s inside one), and admission-prefill tokens
@@ -1247,7 +1268,8 @@ def _serve_batch(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     n_prefix = cfg.n_prefix if cfg.family == "vlm" else 0
     rng = np.random.default_rng(seed + 1)
 
-    params = _quantize_params(tf.init_params(jax.random.PRNGKey(seed), cfg), quantize)
+    params = _quantize_params(tf.init_params(jax.random.PRNGKey(seed), cfg),
+                              quantize, spans_lib.Spans(time.time()))
     tp_ctx = _make_tp_context(cfg, params, tp)
     if tp_ctx is not None:
         params = tp_ctx["params"]

@@ -70,6 +70,15 @@ class Spans:
                 assert self.records[-1] is record, name
                 self.records.pop()
 
+    def rebase(self, t0: float) -> None:
+        """Move the clock's zero to `t0`: the records made so far keep their
+        times on `time.time()`, so those made before `t0` end before 0."""
+        shift = t0 - self.t0
+        for record in self.records:
+            record["start"] -= shift
+            record["end"] -= shift
+        self.t0 = t0
+
     def last(self, name: str, since: int = 0) -> dict | None:
         """The newest closed record called `name` among records `since`
         onward."""

@@ -64,16 +64,17 @@ def quantize_weights(params: dict, spec: "quant.QuantSpec" = None) -> dict:
 def _quantize_leaf(key, leaf, spec: "quant.QuantSpec", in_expert: bool):
     if key not in QUANT_WEIGHT_KEYS or not hasattr(leaf, "ndim"):
         return leaf
-    # weight packing happens once, on concrete arrays, at serve startup:
-    # validate so a NaN/Inf weight fails loudly HERE, not as a non-finite
-    # scale corrupting every decode step (the quantize degenerate contract)
+    # weight packing happens once, on concrete arrays, at serve startup,
+    # one matrix of a stack at a time: validate so a NaN/Inf weight fails
+    # loudly HERE, not as a non-finite scale corrupting every decode step
+    # (the quantize degenerate contract)
     if in_expert and leaf.ndim >= 3:
         # expert-stacked (.., E, d, f): consumed as a batched GEMM right-hand
         # side — keep the (k, n) orientation, per-expert block scales
         espec = quant.QuantSpec(block_m=spec.block_m, block_n=spec.block_n,
                                 transpose=False)
-        return quant.quantize(leaf, espec, validate=True)
-    return quant.quantize(leaf, spec, validate=True)
+        return quant.pack(leaf, espec)
+    return quant.pack(leaf, spec)
 
 
 # --------------------------------------------------------------------------
@@ -100,10 +101,16 @@ def init_norm(d: int, kind: str = "rms", dtype=jnp.bfloat16):
     return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
 
 
-def apply_norm(params: dict, x: jnp.ndarray, kind: str = "rms") -> jnp.ndarray:
+#: each norm's epsilon where the config names none (`ModelConfig.norm_eps`)
+NORM_EPS = {"rms": 1e-6, "ln": 1e-5}
+
+
+def apply_norm(params: dict, x: jnp.ndarray, kind: str = "rms",
+               eps: Optional[float] = None) -> jnp.ndarray:
+    eps = NORM_EPS[kind] if eps is None else eps
     if kind == "rms":
-        return rms_norm(x, params["scale"])
-    return layer_norm(x, params["scale"], params["bias"])
+        return rms_norm(x, params["scale"], eps)
+    return layer_norm(x, params["scale"], params["bias"], eps)
 
 
 # --------------------------------------------------------------------------

@@ -74,7 +74,7 @@ def dense_block(params, x, cfg: ModelConfig, *, positions, cache=None, prefix_le
     acfg = _attn_cfg(cfg)
     aux = jnp.zeros((), jnp.float32)
     if cfg.parallel_block:
-        h = layers.apply_norm(params["ln1"], x, cfg.norm)
+        h = layers.apply_norm(params["ln1"], x, cfg.norm, cfg.norm_eps)
         a, new_cache = layers.attention_layer(
             params["attn"], h, acfg, positions=positions, cache=cache,
             prefix_len=prefix_len, residual=x,
@@ -84,10 +84,10 @@ def dense_block(params, x, cfg: ModelConfig, *, positions, cache=None, prefix_le
             return a + m, new_cache, aux
         return layers.mlp(params["ffn"], h, cfg.act, residual=a), new_cache, aux
     a, new_cache = layers.attention_layer(
-        params["attn"], layers.apply_norm(params["ln1"], x, cfg.norm), acfg,
+        params["attn"], layers.apply_norm(params["ln1"], x, cfg.norm, cfg.norm_eps), acfg,
         positions=positions, cache=cache, prefix_len=prefix_len, residual=x,
     )  # a = x + attn(...)
-    h = layers.apply_norm(params["ln2"], a, cfg.norm)
+    h = layers.apply_norm(params["ln2"], a, cfg.norm, cfg.norm_eps)
     if cfg.family == "moe":
         m, aux = moe.moe_layer(params["ffn"], h, cfg.moe, cfg.act)
         return a + m, new_cache, aux
@@ -291,7 +291,7 @@ def forward(params, batch, cfg: ModelConfig, cache=None, act_fault=None):
     else:
         raise ValueError(cfg.family)
 
-    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
+    x = layers.apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     return x, aux, new_cache
 
 
@@ -350,10 +350,10 @@ def _shared_attn_block(params, x, cfg: ModelConfig, positions, occ: int, cache=N
         attn_params["wk"] = lora(attn_params["wk"], lo["ka"], lo["kb"])
         attn_params["wv"] = lora(attn_params["wv"], lo["va"], lo["vb"])
     a, new_cache = layers.attention_layer(
-        attn_params, layers.apply_norm(sp["ln1"], x, cfg.norm), acfg,
+        attn_params, layers.apply_norm(sp["ln1"], x, cfg.norm, cfg.norm_eps), acfg,
         positions=positions, cache=cache, residual=x,
     )
-    x = layers.mlp(sp["ffn"], layers.apply_norm(sp["ln2"], a, cfg.norm), "gelu",
+    x = layers.mlp(sp["ffn"], layers.apply_norm(sp["ln2"], a, cfg.norm, cfg.norm_eps), "gelu",
                    residual=a)
     return x, new_cache
 
@@ -375,16 +375,16 @@ def _audio_forward(params, x_dec, batch, cfg: ModelConfig, positions, cache=None
 
         def enc_body(lp, x, lc):
             h, _ = layers.attention_layer(
-                lp["attn"], layers.apply_norm(lp["ln1"], x, cfg.norm),
+                lp["attn"], layers.apply_norm(lp["ln1"], x, cfg.norm, cfg.norm_eps),
                 _attn_cfg(cfg, causal=False, use_rope=False), positions=enc_pos,
                 residual=x,
             )
-            x = layers.mlp(lp["ffn"], layers.apply_norm(lp["ln2"], h, cfg.norm),
+            x = layers.mlp(lp["ffn"], layers.apply_norm(lp["ln2"], h, cfg.norm, cfg.norm_eps),
                            cfg.act, residual=h)
             return x, lc, jnp.zeros((), jnp.float32)
 
         enc, _, _ = _scan_blocks(params["enc_layers"], enc, enc_body, cfg, None)
-        enc = layers.apply_norm(params["enc_final_norm"], enc, cfg.norm)
+        enc = layers.apply_norm(params["enc_final_norm"], enc, cfg.norm, cfg.norm_eps)
 
     # decoder: sinusoidal positions (simplification of whisper's learned
     # embedding, DESIGN.md); with a cache the table covers max_len and is
@@ -400,13 +400,13 @@ def _audio_forward(params, x_dec, batch, cfg: ModelConfig, positions, cache=None
     def dec_body(lp, x, lc):
         self_cache = None if lc is None else {"k": lc["k"], "v": lc["v"], "pos": cache["pos"]}
         h, new_sc = layers.attention_layer(
-            lp["attn"], layers.apply_norm(lp["ln1"], x, cfg.norm), acfg_self,
+            lp["attn"], layers.apply_norm(lp["ln1"], x, cfg.norm, cfg.norm_eps), acfg_self,
             positions=positions, cache=self_cache, residual=x,
         )
         x = h
         # cross attention: q from decoder, k/v from encoder output (biases
         # fused into the projection flush when present)
-        hx = layers.apply_norm(lp["ln_x"], x, cfg.norm)
+        hx = layers.apply_norm(lp["ln_x"], x, cfg.norm, cfg.norm_eps)
         if cfg.use_bias:
             q = blas.matmul_fused(hx, lp["xattn"]["wq"], bias=lp["xattn"]["bq"])
             k = blas.matmul_fused(enc, lp["xattn"]["wk"], bias=lp["xattn"]["bk"])
@@ -431,7 +431,7 @@ def _audio_forward(params, x_dec, batch, cfg: ModelConfig, positions, cache=None
             ho.reshape(bq_, tq_, cfg.n_heads * cfg.hd), lp["xattn"]["wo"],
             residual=x,
         )
-        x = layers.mlp(lp["ffn"], layers.apply_norm(lp["ln2"], x, cfg.norm),
+        x = layers.mlp(lp["ffn"], layers.apply_norm(lp["ln2"], x, cfg.norm, cfg.norm_eps),
                        cfg.act, residual=x)
         new_lc = None if lc is None else new_sc
         return x, new_lc, jnp.zeros((), jnp.float32)

@@ -126,3 +126,38 @@ def test_pad_dim_roundtrip():
     y, orig = tiling.pad_dim_to(x, 1, 4)
     assert y.shape == (5, 8) and orig == 7
     assert float(y[:, 7:].sum()) == 0.0
+
+
+# the prefill projections of stablelm-1.6b's cell (d=2048, d_ff=5632; 1024
+# rows a slot): bf16 plans, which the packed-weight charge leaves alone
+@pytest.mark.parametrize("n,k,kw", [
+    pytest.param(2048, 2048, {}, id="qkv"),
+    pytest.param(2048, 2048, {"residual": True}, id="wo"),
+    pytest.param(5632, 2048, {"gate": True}, id="gate-up"),
+    pytest.param(2048, 5632, {"residual": True}, id="down"),
+])
+def test_bf16_plans_at_stablelm_widths_are_unchanged(n, k, kw):
+    assert tiling.rank_block_shapes(1024, n, k, **kw)[0] == BlockShape(
+        1024, 2048, 2048)
+
+
+def test_packed_plan_charges_the_dequantized_tiles():
+    """A packed-weight plan charges the B tile the kernel dequantizes to
+    f32, and the gate's beside it.  At internlm2-20b's SwiGLU (d=6144,
+    d_ff=16384, 1024 rows a slot) that keeps out the block a v5e refuses
+    (72.77 MiB of scoped VMEM against 64) and plans the next one."""
+    big = BlockShape(1024, 2048, 2048)
+    assert tiling.dequant_vmem_bytes(big) == 2048 * 2048 * 4
+    assert tiling.dequant_vmem_bytes(big, gate=True) == 2 * 2048 * 2048 * 4
+    ranked = tiling.rank_block_shapes(1024, 16384, 6144, gate=True,
+                                      b_dtype_bytes=1)
+    assert big not in ranked
+    assert ranked[0] == BlockShape(1024, 2048, 1024)
+    for b in ranked:
+        used = (2 * (b.bm * b.bk * 2 + b.bk * b.bn) + b.bm * b.bn * 4
+                + b.bm * b.bn * 2 + tiling.dequant_vmem_bytes(b, gate=True)
+                + tiling.epilogue_vmem_bytes(b, 1, gate=True))
+        assert used <= tiling.DEFAULT_VMEM_BUDGET
+    # one packed stream charges one dequantized tile
+    plain = tiling.rank_block_shapes(1024, 6144, 16384, b_dtype_bytes=1)
+    assert plain[0] == big

@@ -213,3 +213,35 @@ def test_attention_core_prime_lengths_chunked(causal):
     np.testing.assert_allclose(
         np.asarray(out_chunked), np.asarray(out_full), rtol=2e-4, atol=2e-4
     )
+
+
+@pytest.mark.parametrize("arch", ["internlm2-20b", "stablelm-1.6b"],
+                         ids=["rms", "ln"])
+def test_norm_eps_reaches_the_norms(arch):
+    """`ModelConfig.norm_eps` is the epsilon of every RMSNorm and LayerNorm
+    of the forward; left unset it is the norm's own (1e-6 rms, 1e-5 ln)."""
+    from repro.models import layers
+    cfg = dataclasses.replace(get_config(arch, "smoke"), dtype="float32")
+    params = tf.init_params(KEY, cfg)
+    batch = _batch(cfg)
+
+    def hidden(c):
+        return np.asarray(tf.forward(params, batch, c)[0])
+
+    base = hidden(cfg)
+    np.testing.assert_array_equal(base, hidden(dataclasses.replace(
+        cfg, norm_eps=layers.NORM_EPS[cfg.norm])))
+    assert not np.allclose(base, hidden(dataclasses.replace(cfg, norm_eps=1.0)),
+                           atol=1e-3)
+
+
+def test_norm_eps_of_the_registry():
+    """internlm2-20b publishes rms_norm_eps 1e-5; every other entry, and
+    internlm2's smoke size, keep the norm's default."""
+    from repro.configs import internlm2_20b
+    assert internlm2_20b.FULL.norm_eps == 1e-5
+    for arch in ARCH_IDS:
+        for variant in ("full", "smoke"):
+            cfg = get_config(arch, variant)
+            if cfg is not internlm2_20b.FULL:
+                assert cfg.norm_eps is None, (arch, variant)

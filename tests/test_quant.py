@@ -72,6 +72,50 @@ def test_block_fits_awkward_dims():
     assert 66 % qm == 0 and 130 % qn == 0
 
 
+@pytest.mark.parametrize("shape,spec", [
+    ((2, 64, 128), quant.QuantSpec(64, None, transpose=True)),
+    ((3, 66, 130), quant.QuantSpec(64, 64)),             # awkward dims
+    ((3, 96, 80), quant.QuantSpec(48, 40, transpose=True)),
+    ((2, 3, 40, 72), quant.QuantSpec(16, None)),         # two leading dims
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_pack_matches_whole_stack_quantize_bitwise(shape, spec, dtype):
+    """Packing one matrix of a stack at a time gives the values, scales and
+    block that quantizing the whole stack at once gives, bit for bit."""
+    x = _rand(shape, dtype, scale=3.0)
+    want = quant.quantize(x, spec)
+    got = quant.pack(x, spec)
+    assert (got.block, got.transposed) == (want.block, want.transposed)
+    assert got.values.dtype == want.values.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got.values),
+                                  np.asarray(want.values))
+    np.testing.assert_array_equal(np.asarray(got.scales).view(np.uint32),
+                                  np.asarray(want.scales).view(np.uint32))
+
+
+def test_pack_refuses_non_finite_input():
+    bad = _rand((2, 16, 32)).at[1, 3, 4].set(jnp.nan)
+    with pytest.raises(ValueError, match="NaN/Inf"):
+        quant.pack(bad)
+    assert quant.is_quantized(quant.pack(_rand((2, 16, 32))))
+
+
+def test_pack_temporaries_span_one_matrix():
+    """Compiled for the CPU only (nothing is allocated): packing an
+    internlm2-20b stage's SwiGLU leaf, (8, 6144, 16384) bf16, keeps its
+    float32 temporaries to about one layer's matrix (0.4 GB), where the
+    whole stack at once would hold several of 3.2 GB."""
+    spec = quant.QuantSpec(64, None, transpose=True)
+    leaf = jax.ShapeDtypeStruct((8, 6144, 16384), jnp.bfloat16)
+    packed = jax.eval_shape(lambda x: quant.quantize(x, spec), leaf)
+    per_layer = quant._pack_into.lower(
+        packed, leaf, jax.ShapeDtypeStruct((), jnp.int32), spec).compile()
+    whole = jax.jit(lambda x: quant.quantize(x, spec)).lower(leaf).compile()
+    temp = per_layer.memory_analysis().temp_size_in_bytes
+    assert temp < 2e9
+    assert whole.memory_analysis().temp_size_in_bytes > 2 * temp
+
+
 def test_quantized_tensor_is_a_pytree():
     x = _rand((32, 64))
     qt = quant.quantize(x, quant.QuantSpec(block_m=16, block_n=None))

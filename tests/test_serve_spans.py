@@ -170,3 +170,35 @@ def test_profiler_trace_holds_every_round(tmp_path):
                 if e.name == "serve.round")
     assert kv == sorted(r["attrs"]["kv_tokens"]
                         for _, r in _named(stats, "serve.round"))
+
+
+@pytest.mark.parametrize("quantize", ["int8", "none"])
+def test_weight_packing_is_one_span_of_set_up(quantize):
+    """Packing the weights (quantize="int8") is one `serve.pack` span per
+    call, recorded in set-up, so it ends before the scheduler's t0, with
+    the projections it packed and their bytes before and after; `pack_s`
+    is its length.  Unpacked weights leave no such span."""
+    import jax
+    from repro.core import quant
+    from repro.models import layers, transformer as tf
+
+    stats = _serve(_prompts(PLENS[:3]), GENS[:3], quantize=quantize)
+    packs = _named(stats, "serve.pack")
+    if quantize == "none":
+        assert packs == [] and stats["pack_s"] == 0.0
+        return
+    ((_, pack),) = packs
+    assert pack["parent"] is None and pack["start"] <= pack["end"] < 0.0
+    assert stats["pack_s"] == pytest.approx(pack["end"] - pack["start"])
+    params = jax.eval_shape(lambda: tf.init_params(
+        jax.random.PRNGKey(0), get_config(ARCH, "smoke")))
+    packed = jax.eval_shape(layers.quantize_weights, params)
+    qts = [q for q in jax.tree.leaves(packed, is_leaf=quant.is_quantized)
+           if quant.is_quantized(q)]
+    assert pack["attrs"]["leaves"] == len(qts) == 7
+    layer = {**params["layers"]["attn"], **params["layers"]["ffn"]}
+    assert pack["attrs"]["bytes_in"] == sum(
+        np.prod(layer[k].shape) * layer[k].dtype.itemsize
+        for k in layers.QUANT_WEIGHT_KEYS)
+    assert pack["attrs"]["bytes_out"] == sum(
+        np.prod(q.values.shape) + 4 * np.prod(q.scales.shape) for q in qts)

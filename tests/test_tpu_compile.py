@@ -86,6 +86,22 @@ def test_prefill_bgemm_swiglu_dual_b_compiles(one_chip):
         _spec(one_chip, (D, FF)))
 
 
+def test_prefill_bgemm_swiglu_int8_at_internlm2_widths_compiles(one_chip):
+    """The int8 prefill SwiGLU of internlm2-20b (d=6144, d_ff=16384), an
+    admission of 8 slots of 1024 rows: packed gate and up weights, each
+    dequantized in-kernel, at the planner's first block, which has to fit
+    the 64 MiB scoped-VMEM budget once the dequantized tiles are charged."""
+    b, t, d, ff = 8, 1024, 6144, 16384
+    blk = tiling.rank_block_shapes(t, ff, d, gate=True, b_dtype_bytes=1)[0]
+    epi = epilogue.make("silu", gate=True)
+    w, ws, q_block = _packed(one_chip, d, ff)
+    _compile(lambda a, w, ws, w2, w2s: bgemm.bgemm(
+        a, w, b2=w2, scales=ws, b2_scales=w2s, q_block=q_block,
+        b_layout="nk", epilogue=epi, block_m=blk.bm, block_n=blk.bn,
+        block_k=blk.bk),
+        _spec(one_chip, (b, t, d)), w, ws, w, ws)
+
+
 def test_decode_bgemv_transpose_a_compiles(one_chip):
     """The bf16 decode projection: broadcast weight streamed in its HBM
     (d, f) layout under transpose_a, with the SwiGLU gate fused."""
