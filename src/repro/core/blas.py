@@ -22,8 +22,9 @@ but are functional: they return the result instead of mutating.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import threading
-from typing import Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -448,6 +449,53 @@ def batched_gemv(
     return out
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class LayerSlice:
+    """Layer `index` of a layer-stacked projection weight, left in its stack.
+
+    The decode step's layer scan (`transformer._scan_blocks`) hands these to
+    the projections in place of the per-layer slices `lax.scan` would cut:
+    a pallas decode-shaped projection passes the stack and the index to
+    `bgemv`, which reads the layer's tiles straight out of the stack in
+    HBM, so no copy of the layer's weights is made each step.  Every other
+    consumer takes `get()`, the slice the scan would have cut.
+    """
+
+    stack: Any   # (L, d, f) array, or a QuantizedTensor stacked the same way
+    index: Any   # () int32
+
+    def tree_flatten(self):
+        return (self.stack, self.index), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    @property
+    def shape(self) -> tuple:
+        return self.stack.shape[1:]
+
+    def get(self):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self.index, 0, False),
+            self.stack)
+
+
+def _layer_operands(x, w, w2=None):
+    """(w, w2, layer) for a projection of x.  A `LayerSlice` stays in its
+    stack (layer = its index) only where the pallas decode `bgemv` reads it
+    in place: one row per member, the weight in the layout it streams.
+    Elsewhere it is sliced out, as `lax.scan` would have cut it."""
+    if not isinstance(w, LayerSlice):
+        return w, w2, None
+    in_place = (get_backend() == "pallas" and x.ndim > 2 and x.shape[-2] == 1
+                and (not _quant.is_quantized(w.stack) or w.stack.transposed))
+    if in_place:
+        return w.stack, None if w2 is None else w2.stack, w.index
+    return w.get(), None if w2 is None else w2.get(), None
+
+
 def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """Model-layer entry point: x (..., d) @ w (d, f) -> (..., f).
 
@@ -464,7 +512,10 @@ def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     launch, now at int8 bandwidth); the xla host backend uses per-member
     contiguous int8 matvecs for small decode batches and exact
     dequantization elsewhere.
+
+    A `LayerSlice` w is read in place by the decode bgemv, else sliced.
     """
+    w, _, layer = _layer_operands(x, w)
     quantized = _quant.is_quantized(w)
     backend = get_backend()
     if backend == "pallas":
@@ -487,7 +538,8 @@ def matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
             # Quantized weights are stored output-major (QuantSpec.transpose)
             # so the same call streams packed int8 in HBM layout.
             wq = w if not quantized or w.transposed else _deq(w, x.dtype)
-            out = ops.bgemv(wq, xb[:, 0, :], transpose_a=True).astype(x.dtype)
+            out = ops.bgemv(wq, xb[:, 0, :], transpose_a=True,
+                            layer=layer).astype(x.dtype)
             return out.reshape(*lead, w.shape[-1])
         out = ops.bgemm(xb, w)
         return out.reshape(*lead, w.shape[-1]).astype(x.dtype)
@@ -533,8 +585,10 @@ def matmul_fused(
     ONE kernel launch and ONE HBM output write (gemm / bgemm / decode-shaped
     bgemv with transpose_a, mirroring `matmul`'s routing); xla/ref apply the
     identical epilogue semantic to the f32 accumulator before the single
-    output cast, so all backends agree to dtype tolerance.
+    output cast, so all backends agree to dtype tolerance.  `LayerSlice`
+    weights route as in `matmul`.
     """
+    w, w2, layer = _layer_operands(x, w, w2)
     epi = _epi_spec(activation, w2, bias, residual)
     quantized = _quant.is_quantized(w)
     lead = x.shape[:-1]
@@ -561,8 +615,8 @@ def matmul_fused(
             if quantized and not w.transposed:
                 wq, wq2 = _deq(w, x.dtype), _deq(w2, x.dtype)
             out = ops.bgemv(wq, xb[:, 0, :], a2=wq2, bias=bias, residual=rb,
-                            transpose_a=True,
-                            activation=epi.activation).astype(x.dtype)
+                            transpose_a=True, activation=epi.activation,
+                            layer=layer).astype(x.dtype)
             return out.reshape(*lead, f)
         rb = None if res is None else res.reshape(-1, rows, f)
         out = ops.bgemm(xb, w, b2=w2, bias=bias, residual=rb,

@@ -24,6 +24,7 @@ best — identical to `choose_block_shape` — is served from the same cache.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -120,6 +121,108 @@ def dequant_vmem_bytes(blk: BlockShape, *, gate: bool = False) -> int:
     int8 dual-B SwiGLU at (bm, bn, bk) = (1024, 2048, 2048) asks 72.77 MiB
     in all, which this charge keeps out of a 64 MiB plan."""
     return (2 if gate else 1) * blk.bk * blk.bn * DEQUANT_BYTES
+
+
+# --------------------------------------------------------------------------
+# Decode bgemv: one weight tile a grid step against the whole batch
+# --------------------------------------------------------------------------
+
+#: weight bytes one `bgemv` grid step streams per operand: ~2.6 us of DMA at
+#: the v5e's 819 GB/s, so the ~0.35 us a grid step costs stays small
+BGEMV_TILE_BYTES = 2 * 1024 * 1024
+#: widest contraction extent of one `bgemv` tile
+BGEMV_MAX_BN = 2048
+#: batch-block granularity when a batch is split (a bf16 tile's 16 rows)
+BGEMV_BATCH_ALIGN = 16
+
+
+def _tile_extent(dim: int, cap: int) -> int:
+    """One tile's extent along `dim`: the whole dim when it fits in `cap`,
+    else the largest multiple of 128 in [cap/2, cap] that divides dim (no
+    ragged fringe), else cap rounded to 128 (a fringe the kernel masks on
+    the contraction axis, or Pallas clips on the output axis)."""
+    if dim <= cap:
+        return dim
+    cap = max(MXU_DIM, cap - cap % MXU_DIM)
+    for t in range(cap, cap // 2 - 1, -MXU_DIM):
+        if dim % t == 0:
+            return t
+    return cap
+
+
+def bgemv_vmem_bytes(bb: int, bm: int, bn: int, a_dtype, x_dtype, *,
+                     gate: bool = False, residual: bool = False) -> int:
+    """VMEM one `bgemv` grid step claims for a weight of `a_dtype` against
+    activations of `x_dtype`.  Products run at the wider float of the two;
+    an integer (packed) weight converts to the activations' dtype.
+
+    Per weight operand (two under the dual gate): its double-buffered
+    (bm, bn) tile, plus the tile converted to the activations' width where
+    the weight is stored narrower (packed int8 to bf16).  Then the
+    double-buffered (bb, bn) x block, the (bb, bm) accumulator per operand,
+    the double-buffered (bb, bm) output and residual blocks and the bias
+    row.  Rows pad to whole sublanes."""
+    a, x = jnp.dtype(a_dtype), jnp.dtype(x_dtype)
+    act = jnp.promote_types(a, x) if jnp.issubdtype(a, jnp.floating) else x
+    act_bytes, acc_bytes = act.itemsize, max(4, act.itemsize)
+    rows = round_up(bb, SUBLANE)
+    ops = 2 if gate else 1
+    weight = 2 * bm * bn * a.itemsize + (bm * bn * act_bytes if act != a else 0)
+    return (ops * weight
+            + 2 * rows * bn * act_bytes
+            + ops * rows * bm * acc_bytes
+            + (4 if residual else 2) * rows * bm * act_bytes
+            + 2 * SUBLANE * bm * act_bytes)
+
+
+def bgemv_batch_cap(bm: int, bn: int, a_dtype, x_dtype, *,
+                    gate: bool = False, residual: bool = False) -> int:
+    """Most batch rows one block may hold beside (bm, bn) weight tiles under
+    DEFAULT_VMEM_BUDGET: a multiple of BGEMV_BATCH_ALIGN, 0 when not even
+    that many fit."""
+    charge = functools.partial(bgemv_vmem_bytes, bm=bm, bn=bn,
+                               a_dtype=a_dtype, x_dtype=x_dtype, gate=gate,
+                               residual=residual)
+    fixed = charge(0)
+    per_block = charge(BGEMV_BATCH_ALIGN) - fixed
+    return (max(0, (DEFAULT_VMEM_BUDGET - fixed) // per_block)
+            * BGEMV_BATCH_ALIGN)
+
+
+def plan_bgemv(batch: int, m: int, n: int, a_dtype, x_dtype, *,
+               gate: bool = False, residual: bool = False,
+               broadcast: bool = True, block_m: int = None,
+               block_n: int = None) -> tuple[int, int, int]:
+    """(bb, bm, bn) for a `bgemv` of `batch` vectors of `x_dtype` by an
+    (m, n) weight of `a_dtype` (output extent m, contraction n, whichever
+    way the weight is stored).
+
+    The weight tile is about BGEMV_TILE_BYTES: bn at most BGEMV_MAX_BN along
+    the contraction, bm the rest, each a whole extent, a divisor of it or a
+    multiple of 128; a caller's `block_m`/`block_n` take their place.  A
+    broadcast weight puts the whole batch in one block (bb = batch), so
+    each tile is read once per call; a batch that does not fit beside the
+    tiles under DEFAULT_VMEM_BUDGET splits into the fewest blocks that do,
+    evened out.  A per-member weight (`broadcast` False) takes one member a
+    block."""
+    bn = min(block_n, n) if block_n else _tile_extent(n, BGEMV_MAX_BN)
+    a_bytes = jnp.dtype(a_dtype).itemsize
+    bm = (min(block_m, m) if block_m else _tile_extent(
+        m, max(MXU_DIM, BGEMV_TILE_BYTES // (bn * a_bytes))))
+    cap_kw = dict(gate=gate, residual=residual)
+    cap = bgemv_batch_cap(bm, bn, a_dtype, x_dtype, **cap_kw)
+    while not cap and bm > MXU_DIM and not block_m:
+        bm = _tile_extent(m, bm // 2)
+        cap = bgemv_batch_cap(bm, bn, a_dtype, x_dtype, **cap_kw)
+    if not broadcast:
+        return 1, bm, bn
+    if batch <= cap:
+        return batch, bm, bn
+    if not cap:
+        raise ValueError(f"no bgemv batch block fits {DEFAULT_VMEM_BUDGET} B "
+                         f"of VMEM beside ({bm}, {bn}) weight tiles")
+    blocks = cdiv(batch, cap)
+    return round_up(cdiv(batch, blocks), BGEMV_BATCH_ALIGN), bm, bn
 
 
 def rank_block_shapes(

@@ -7,7 +7,8 @@ Responsibilities:
     gemm still pads here;
   - block-shape selection via core.tiling — `tiling.autotune_block_shape`,
     the AE4 analytic ranking plus (REPRO_AUTOTUNE=1) empirical measurement
-    of the top-K candidates, cached per (op, shape, dtype, backend);
+    of the top-K candidates, cached per (op, shape, dtype, backend); the
+    decode bgemv's tiles and batch block from `tiling.plan_bgemv`;
   - fused-epilogue plumbing (core.epilogue): bias/activation/residual and
     the dual-GEMM gate operand travel alongside the GEMM operands into the
     kernels' last-k-step flush;
@@ -364,8 +365,8 @@ def bgemm(a: jnp.ndarray, b: jnp.ndarray, *, b2=None, bias=None, residual=None,
 @functools.partial(
     jax.jit, static_argnames=("block_m", "block_n", "activation", "transpose_a")
 )
-def _bgemv_call(a, x, a2, bias, residual, *, block_m, block_n, activation,
-                transpose_a):
+def _bgemv_call(a, x, a2, bias, residual, layer, *, block_m, block_n,
+                activation, transpose_a):
     quantized = _quant.is_quantized(a)
     if quantized:
         # the packed weight streams in its STORED layout: logical transposes
@@ -384,43 +385,57 @@ def _bgemv_call(a, x, a2, bias, residual, *, block_m, block_n, activation,
         m, n = a.shape[-2:]
     batch = x.shape[0]
     epi = _epi_spec(activation, a2, bias, residual)
-    # no padding: the kernel runs a cdiv grid, masks the ragged contraction
-    # fringe in-VMEM and Pallas clips the ragged output rows on the write.
-    # Under transpose_a the output dim m lives on the lane axis and the
-    # contraction n on sublanes, so the alignment constraints swap too.
-    bm = min(block_m, tiling.round_up(m, 128 if transpose_a else 8))
-    bn = min(block_n, tiling.round_up(n, 8 if transpose_a else 128))
     q_kw = {}
     if quantized:
-        qm, qn = a.block
-        bm, bn = _align_block(bm, qm), _align_block(bn, qn)
         q_kw = {"scales": a.scales, "q_block": a.block}
         if a2 is not None:
             q_kw["a2_scales"] = a2.scales
             a2 = a2.values
         a = a.values
+    # the tiles and the batch block follow from the shapes and dtypes: a
+    # broadcast weight (the decode step; with `layer`, one layer of a stack)
+    # puts the whole batch in one block, so each weight tile is read once
+    # per call; a per-member (3-D) weight takes one member a block.  No
+    # padding: the kernel runs a cdiv grid, masks the ragged contraction
+    # fringe in-VMEM and Pallas clips the ragged output fringes on the write.
+    if quantized:
+        qm, qn = q_kw["q_block"]
+        block_m = block_m and _align_block(block_m, qm)
+        block_n = block_n and _align_block(block_n, qn)
+    bb, bm, bn = tiling.plan_bgemv(batch, m, n, a.dtype, x.dtype,
+                                   gate=a2 is not None,
+                                   residual=residual is not None,
+                                   broadcast=a.ndim == 2 or layer is not None,
+                                   block_m=block_m, block_n=block_n)
+    if quantized:
+        bm, bn = _align_block(bm, qm), _align_block(bn, qn)
     if bias is not None:
-        bias = bias.reshape((1, m) if transpose_a else (m, 1))
-    if residual is not None:
-        residual = residual.reshape(
-            (batch, 1, m) if transpose_a else (batch, m, 1)
-        )
+        bias = bias.reshape(1, m)
     return _bgemv.bgemv(a, x, a2=a2, bias=bias, residual=residual,
-                        epilogue=epi, transpose_a=transpose_a, block_m=bm,
-                        block_n=bn, interpret=_interpret(), **q_kw)
+                        epilogue=epi, transpose_a=transpose_a, block_b=bb,
+                        block_m=bm, block_n=bn, layer=layer,
+                        interpret=_interpret(), **q_kw)
 
 
 def bgemv(a: jnp.ndarray, x: jnp.ndarray, *, a2=None, bias=None, residual=None,
-          activation=None, transpose_a=False, block_m=512, block_n=512):
+          activation=None, transpose_a=False, layer=None, block_m=None,
+          block_n=None):
     """epilogue(op(a) @ x[b] [, op(a2) @ x[b]]) -> (batch, m).
 
     a is ((batch,) m, n) — or ((batch,) n, m) under transpose_a, which
     streams the weight in its HBM layout (op = A^T) instead of requiring a
     materialized transpose; 2-D a broadcasts across the batch (the serving
-    decode case).  bias is (m,), residual (batch, m).
+    decode case: the whole batch in one block, each weight tile read once).
+    bias is (m,), residual (batch, m).  Blocks default to
+    `tiling.plan_bgemv`'s.
+
+    With `layer` (a traced () int), a (and a2) is a layer stack, one such
+    broadcast weight per layer on a leading axis, and the kernel reads
+    layer `layer` out of it in place: the decode step's layer scan passes
+    its stacks so, instead of copying each layer's weights out first.
 
     A `QuantizedTensor` a (and a2) is the packed serving weight: int8 tiles
-    stream at 1 byte/element and dequantize in-kernel against the f32
+    stream at 1 byte/element, converted in-kernel and scaled on the f32
     accumulator.  Its stored layout already encodes the op (transpose folded
     in at quantization time), so transpose_a must match `a.transposed`.
     """
@@ -435,10 +450,12 @@ def bgemv(a: jnp.ndarray, x: jnp.ndarray, *, a2=None, bias=None, residual=None,
         n, m = a.shape[-2:]
     else:
         m, n = a.shape[-2:]
-    if x.shape[-1] != n or (a.ndim == 3 and a.shape[0] != x.shape[0]):
+    per_member = a.ndim == 3 and layer is None
+    if (x.shape[-1] != n or (per_member and a.shape[0] != x.shape[0])
+            or (layer is not None and a.ndim != 3)):
         raise ValueError(f"bgemv shape mismatch: {a.shape} @ {x.shape}")
     _check_epilogue_shapes(a2, bias, residual, a.shape, (m,), (x.shape[0], m))
-    return _bgemv_call(a, x, a2, bias, residual, block_m=block_m,
+    return _bgemv_call(a, x, a2, bias, residual, layer, block_m=block_m,
                        block_n=block_n, activation=activation,
                        transpose_a=transpose_a)
 

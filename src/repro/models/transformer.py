@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core import blas
+from repro.core import blas, distributed
 from repro.core.act_sharding import constrain
 from repro.models import layers, mamba, moe, rwkv
 from repro.models.layers import AttnConfig
@@ -192,14 +192,52 @@ def sinusoidal(t: int, d: int, dtype) -> jnp.ndarray:
 # Forward (train / prefill path, full sequences)
 # --------------------------------------------------------------------------
 
-def _scan_blocks(params_stacked, x, body, cfg: ModelConfig, cache=None):
+#: the block projections a decode step reads in place from their stacks
+IN_PLACE_PROJECTIONS = {"attn": ("wq", "wk", "wv", "wo"),
+                        "ffn": ("w_gate", "w_up", "w_down")}
+
+
+def _in_place(params_stacked, x, cache) -> dict:
+    """{sub-tree: keys} of the layer-stacked 2-D projections that the decode
+    step's bgemv reads out of their stacks: a pallas one-token step with a
+    cache (no gradient flows through it).  MoE expert stacks (4-D) and
+    anything else keep the scan's slices."""
+    if (cache is None or x.shape[1] != 1 or blas.get_backend() != "pallas"
+            or distributed.tp_active()):
+        return {}
+    return {sub: tuple(k for k in keys if k in params_stacked.get(sub, {})
+                       and params_stacked[sub][k].ndim == 3)
+            for sub, keys in IN_PLACE_PROJECTIONS.items()}
+
+
+def _scan_blocks(params_stacked, x, body, cfg: ModelConfig, cache=None,
+                 in_place=None):
     """lax.scan over layer-stacked params (+ optional stacked cache).
 
     body(layer_params, x, layer_cache) -> (x, new_layer_cache, aux)
+
+    in_place ({sub-tree: keys}, see `_in_place`): those leaves reach the
+    body as `blas.LayerSlice`s of their whole stack at the layer's index,
+    so the projection kernel reads the layer in place; the scan slices the
+    rest.
     """
+    kept = {sub: {k: params_stacked[sub][k] for k in keys}
+            for sub, keys in (in_place or {}).items() if keys}
+    scanned, idx = params_stacked, None
+    if kept:
+        scanned = {key: ({k: v for k, v in sub.items() if k not in kept[key]}
+                         if key in kept else sub)
+                   for key, sub in params_stacked.items()}
+        idx = jnp.arange(jax.tree.leaves(params_stacked)[0].shape[0],
+                         dtype=jnp.int32)
+
     def step(carry, xs):
         x, aux = carry
-        lp, lc = xs
+        lp, lc, i = xs
+        if kept:
+            lp = {**lp, **{sub: {**lp[sub], **{
+                k: blas.LayerSlice(w, i) for k, w in ws.items()}}
+                for sub, ws in kept.items()}}
         # Megatron-SP analog: the residual stream between blocks is sharded
         # over ("dp", seqres) — the scan's saved carries shrink by the model
         # axis, which is what lets 100B+ train cells fit 16 GiB/chip.
@@ -209,7 +247,7 @@ def _scan_blocks(params_stacked, x, body, cfg: ModelConfig, cache=None):
 
     fn = jax.checkpoint(step) if cfg.remat == "full" else step
     (x, aux), new_cache = jax.lax.scan(
-        fn, (x, jnp.zeros((), jnp.float32)), (params_stacked, cache),
+        fn, (x, jnp.zeros((), jnp.float32)), (scanned, cache, idx),
         unroll=True if cfg.scan_unroll else 1,
     )
     return x, aux, new_cache
@@ -268,7 +306,9 @@ def forward(params, batch, cfg: ModelConfig, cache=None, act_fault=None):
             nc = None if nc is None else {k_: nc[k_] for k_ in ckeys}
             return x, nc, aux
 
-        x, aux, new_scan = _scan_blocks(params["layers"], x, body, cfg, scan_cache)
+        x, aux, new_scan = _scan_blocks(
+            params["layers"], x, body, cfg, scan_cache,
+            in_place=_in_place(params["layers"], x, cache))
         new_cache = None if cache is None else {**new_scan, "pos": pos + t}
         if new_cache is not None and page_table is not None:
             # table updates are host-side page-pointer writes (admission /

@@ -161,3 +161,67 @@ def test_packed_plan_charges_the_dequantized_tiles():
     # one packed stream charges one dequantized tile
     plain = tiling.rank_block_shapes(1024, 6144, 16384, b_dtype_bytes=1)
     assert plain[0] == big
+
+
+@pytest.mark.parametrize("a_dtype,m,n,kw,want", [
+    # internlm2-20b int8 decode projections (output m, contraction n)
+    ("int8", 16384, 6144, {"gate": True}, (8, 1024, 2048)),
+    ("int8", 6144, 16384, {"residual": True}, (8, 1024, 2048)),
+    ("int8", 1024, 6144, {}, (8, 1024, 2048)),
+    # stablelm-1.6b bf16: d_ff 5632 = 4 x 1408 along the contraction
+    ("bfloat16", 5632, 2048, {"gate": True}, (8, 512, 2048)),
+    ("bfloat16", 2048, 5632, {"residual": True}, (8, 512, 1408)),
+])
+def test_bgemv_plans_at_serving_widths(a_dtype, m, n, kw, want):
+    """The decode plan: 8 slots in one block beside ~2 MiB weight tiles
+    whose extents divide the weight (no ragged fringe), under the budget
+    once the converted int8 tile, the gate and the residual are charged."""
+    import jax.numpy as jnp
+    bb, bm, bn = tiling.plan_bgemv(8, m, n, a_dtype, jnp.bfloat16, **kw)
+    assert (bb, bm, bn) == want
+    assert m % bm == 0 and n % bn == 0
+    used = tiling.bgemv_vmem_bytes(bb, bm, bn, a_dtype, jnp.bfloat16, **kw)
+    assert used <= tiling.DEFAULT_VMEM_BUDGET
+    if a_dtype == "int8" and kw.get("gate"):
+        # two double-buffered int8 tiles and their bf16 copies; x, the two
+        # f32 accumulators, the output and the bias row, 8 rows each
+        assert used == (2 * (2 * bm * bn + bm * bn * 2) + 2 * 8 * bn * 2
+                        + 2 * 8 * bm * 4 + 2 * 8 * bm * 2 + 2 * 8 * bm * 2)
+
+
+@pytest.mark.parametrize("a_dtype,x_dtype,m,n", [
+    ("float32", "float32", 512, 256),
+    ("bfloat16", "bfloat16", 5632, 2048),
+    ("int8", "bfloat16", 16384, 6144),
+])
+def test_bgemv_batch_cap_is_what_fits(a_dtype, x_dtype, m, n):
+    """The batch cap is the most aligned rows whose charge fits the budget;
+    a batch past it splits into the fewest even blocks within the cap."""
+    budget = tiling.DEFAULT_VMEM_BUDGET
+    _, bm, bn = tiling.plan_bgemv(1, m, n, a_dtype, x_dtype, gate=True)
+    cap = tiling.bgemv_batch_cap(bm, bn, a_dtype, x_dtype, gate=True)
+    assert cap % tiling.BGEMV_BATCH_ALIGN == 0 and cap > 0
+    charge = lambda bb: tiling.bgemv_vmem_bytes(bb, bm, bn, a_dtype,
+                                                x_dtype, gate=True)
+    assert charge(cap) <= budget
+    assert charge(cap + tiling.BGEMV_BATCH_ALIGN) > budget
+    for batch in (cap, cap + 1, 3 * cap + 5):
+        bb, _, _ = tiling.plan_bgemv(batch, m, n, a_dtype, x_dtype,
+                                     gate=True)
+        assert bb <= cap
+        assert tiling.cdiv(batch, bb) == tiling.cdiv(batch, cap)
+
+
+def test_bgemv_caller_tiles_are_charged():
+    """Tiles a caller passes replace the planned ones, and the batch block
+    is charged against them: wider tiles leave room for fewer rows."""
+    m, n = 16384, 6144
+    _, bm, bn = tiling.plan_bgemv(8, m, n, "int8", "bfloat16", gate=True)
+    wide = tiling.plan_bgemv(10 ** 6, m, n, "int8", "bfloat16", gate=True,
+                             block_m=2 * bm, block_n=bn)
+    assert wide[1:] == (2 * bm, bn)
+    cap = tiling.bgemv_batch_cap(2 * bm, bn, "int8", "bfloat16", gate=True)
+    assert 0 < wide[0] <= cap < tiling.bgemv_batch_cap(bm, bn, "int8",
+                                                       "bfloat16", gate=True)
+    assert tiling.plan_bgemv(8, m, n, "int8", "bfloat16", gate=True,
+                             block_m=128, block_n=256) == (8, 128, 256)

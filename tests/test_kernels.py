@@ -120,6 +120,188 @@ def test_bgemm_block_shape_invariance():
 
 
 # --------------------------------------------------------------------------
+# Broadcast-A bgemv: the decode kernel, the whole batch in one block
+# --------------------------------------------------------------------------
+
+#: (activation, bias, gate, residual) of the broadcast-A sweep
+BGEMV_EPILOGUES = [
+    (None, False, False, False),
+    ("silu", False, True, False),       # the dual-GEMV SwiGLU
+    (None, True, False, False),
+    (None, False, False, True),
+]
+
+
+def _bgemv_case(layout, m, n, batch, act, use_bias, use_gate, use_res, seed=0):
+    """Operands of one broadcast-A bgemv and its f32 numpy oracle.
+
+    `layout` "bf16_t" is the bf16 weight stored (n, m) under transpose_a;
+    "int8" the packed output-major weight with one scale per 64 output rows
+    across the contraction (layers.quantize_weights' spec), bf16 vectors."""
+    from repro.core import quant
+    from repro.core.epilogue import make
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    w = jax.random.normal(ks[0], (n, m), F32) * 0.1
+    w2 = jax.random.normal(ks[1], (n, m), F32) * 0.1
+    x = jax.random.normal(ks[2], (batch, n), F32).astype(BF16)
+    bias = jax.random.normal(ks[3], (m,), F32).astype(BF16) if use_bias else None
+    res = (jax.random.normal(ks[4], (batch, m), F32).astype(BF16)
+           if use_res else None)
+    if layout == "int8":
+        spec = quant.QuantSpec(block_m=64, block_n=None, transpose=True)
+        a, a2 = quant.quantize(w, spec), quant.quantize(w2, spec)
+        wf, w2f = np.asarray(a.dequantize()), np.asarray(a2.dequantize())
+    else:
+        a, a2 = w.astype(BF16), w2.astype(BF16)
+        wf, w2f = np.asarray(a, np.float32), np.asarray(a2, np.float32)
+    f = lambda z: None if z is None else np.asarray(z, np.float32)
+    xf = f(x)
+    epi = make(act, bias=bias, gate=use_gate or None, residual=res)
+    want = np.asarray(epi.apply(
+        jnp.asarray(xf @ wf), acc2=jnp.asarray(xf @ w2f) if use_gate else None,
+        bias=f(bias), residual=f(res)))
+    call = dict(a2=a2 if use_gate else None, bias=bias, residual=res,
+                activation=act, transpose_a=True)
+    return a, x, call, want
+
+
+def _pallas_grids(fn, *args):
+    """Grids of every pallas_call in fn's jaxpr."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for v in eqn.params.values():
+                if hasattr(v, "jaxpr"):
+                    walk(v.jaxpr)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+@pytest.mark.parametrize("layout", ["bf16_t", "int8"])
+@pytest.mark.parametrize("act,use_bias,use_gate,use_res", BGEMV_EPILOGUES)
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("m,n,blocks", [
+    (256, 384, {}),                                    # one tile, planned
+    (320, 300, {"block_m": 128, "block_n": 128}),      # ragged m and n fringes
+])
+def test_bgemv_broadcast_a_decode(layout, act, use_bias, use_gate, use_res,
+                                  batch, m, n, blocks):
+    """The decode projection (one weight for every slot, the whole batch in
+    one block, one MXU product a step) against the f32 oracle: bf16 under
+    transpose_a and packed int8 output-major, each epilogue, ragged m and n
+    fringes (int8 tiles align to the 64-row scale groups, so its m fringe
+    reads past the scale grid and its n tiles divide n)."""
+    a, x, call, want = _bgemv_case(layout, m, n, batch, act, use_bias,
+                                   use_gate, use_res)
+    got = ops.bgemv(a, x, **call, **blocks)
+    assert got.shape == (batch, m) and got.dtype == BF16
+    _cmp(got, want, BF16)
+
+
+@pytest.mark.parametrize("layout", ["bf16_t", "int8"])
+def test_bgemv_batch_above_block_cap_splits(layout):
+    """A batch one row past what one block holds beside the weight tiles
+    splits into two batch blocks, each reading the weight once, and still
+    matches the oracle (its ragged last block clipped on the write)."""
+    from repro.core import tiling
+    m, n = 128, 256
+    a_dtype = jnp.int8 if layout == "int8" else BF16
+    _, bm, bn = tiling.plan_bgemv(1, m, n, a_dtype, BF16, gate=True)
+    cap = tiling.bgemv_batch_cap(bm, bn, a_dtype, BF16, gate=True)
+    assert cap >= 8
+    a, x, call, want = _bgemv_case(layout, m, n, cap + 1, "silu", False,
+                                   True, False)
+    bb, _, _ = tiling.plan_bgemv(cap + 1, m, n, a_dtype, BF16, gate=True)
+    assert bb < cap + 1 <= 2 * bb
+    fn = lambda a, x: ops.bgemv(a, x, **call)
+    assert _pallas_grids(fn, a, x) == [
+        (tiling.cdiv(m, bm), 2, tiling.cdiv(n, bn))]
+    _cmp(fn(a, x), want, BF16)
+
+
+@pytest.mark.parametrize("layout", ["bf16_t", "int8"])
+@pytest.mark.parametrize("batch", [1, 8])
+@pytest.mark.parametrize("layers", [None, 4])
+def test_bgemv_broadcast_grid_reads_each_tile_once(layout, batch, layers):
+    """A broadcast weight's grid is (m/bm, n/bn), no batch axis: every
+    weight tile is one grid step, so it is read once for the whole batch.
+    The same holds for one layer of a layer stack (`layer`), read in place.
+    Shapes wide enough for several tiles along both axes."""
+    from repro.core import tiling
+    m, n = 2 * 1024, 2 * tiling.BGEMV_MAX_BN
+    a_dtype = jnp.int8 if layout == "int8" else BF16
+    bb, bm, bn = tiling.plan_bgemv(batch, m, n, a_dtype, BF16, gate=True)
+    grid = (tiling.cdiv(m, bm), tiling.cdiv(n, bn))
+    assert bb == batch and min(grid) >= 2
+    shape = jax.ShapeDtypeStruct
+    lead = () if layers is None else (layers,)
+    if layout == "int8":
+        from repro.core import quant
+        spec = quant.QuantSpec(block_m=64, block_n=None, transpose=True)
+        a = jax.eval_shape(lambda w: quant.quantize(w, spec),
+                           shape(lead + (n, m), F32))
+    else:
+        a = shape(lead + (n, m), BF16)
+    layer = None if layers is None else shape((), jnp.int32)
+    fn = lambda a, x, i: ops.bgemv(a, x, a2=a, activation="silu",
+                                   transpose_a=True, layer=i)
+    assert _pallas_grids(fn, a, shape((batch, n), BF16), layer) == [grid]
+
+
+@pytest.mark.parametrize("layout", ["bf16_t", "int8"])
+@pytest.mark.parametrize("act,use_bias,use_gate,use_res", BGEMV_EPILOGUES)
+def test_bgemv_reads_layer_from_stack(layout, act, use_bias, use_gate,
+                                      use_res):
+    """A layer stack and a traced `layer` give exactly the call on that
+    layer's weight sliced out (the index maps pick the layer, nothing else
+    changes), for bf16 under transpose_a and packed int8 with its scales,
+    each epilogue; and the f32 oracle."""
+    m, n, batch, layer = 256, 384, 8, 1
+    cases = [_bgemv_case(layout, m, n, batch, act, use_bias, use_gate,
+                         use_res, seed=s) for s in range(3)]
+    stack = lambda ws: jax.tree.map(lambda *w: jnp.stack(w), *ws)
+    a = stack([c[0] for c in cases])
+    a2 = stack([c[2]["a2"] for c in cases]) if use_gate else None
+    _, x, call, want = cases[layer]
+    got = jax.jit(lambda a, a2, i: ops.bgemv(a, x, **{**call, "a2": a2},
+                                             layer=i))(a, a2, jnp.int32(layer))
+    sliced = ops.bgemv(cases[layer][0], x, **call)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(sliced, np.float32))
+    _cmp(got, want, BF16)
+
+
+def test_bgemv_per_member_a_keeps_member_grid():
+    """A 3-D A (one matrix per member) has no weight to share: its grid
+    keeps one member a step, (m/bm, batch, n/bn)."""
+    batch, m, n = 5, 33, 200
+    a = jax.ShapeDtypeStruct((batch, m, n), F32)
+    x = jax.ShapeDtypeStruct((batch, n), F32)
+    assert _pallas_grids(lambda a, x: ops.bgemv(a, x), a, x) == [(1, batch, 1)]
+
+
+@pytest.mark.parametrize("trans", [False, True])
+def test_bgemv_broadcast_a_f64(trans):
+    """DGEMV proper: broadcast f64 weights contract at HIGHEST precision and
+    accumulate in f64 (interpret mode), ragged fringes included."""
+    with jax.enable_x64(True):
+        rng = np.random.default_rng(3)
+        m, n, batch = 137, 261, 3
+        a = rng.standard_normal((n, m) if trans else (m, n))
+        x = rng.standard_normal((batch, n))
+        got = ops.bgemv(jnp.asarray(a), jnp.asarray(x), transpose_a=trans,
+                        block_m=128, block_n=128)
+        assert got.dtype == jnp.float64
+        want = x @ (a if trans else a.T)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-12,
+                                   atol=1e-12)
+
+
+# --------------------------------------------------------------------------
 # Flash attention
 # --------------------------------------------------------------------------
 

@@ -245,3 +245,68 @@ def test_norm_eps_of_the_registry():
             cfg = get_config(arch, variant)
             if cfg is not internlm2_20b.FULL:
                 assert cfg.norm_eps is None, (arch, variant)
+
+
+def _bgemv_launches(fn, *args):
+    """Scalar-prefetch operand counts of every bgemv pallas_call in fn's
+    jaxpr, nested jaxprs (jit, scan, checkpoint) included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "pallas_call" and "_bgemv_kernel" in
+                    eqn.params["jaxpr"].debug_info.func_src_info):
+                found.append(eqn.params["grid_mapping"].num_index_operands)
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if isinstance(inner, jax.extend.core.Jaxpr):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("quantize", [False, True])
+def test_pallas_decode_reads_projections_in_place(quantize, monkeypatch):
+    """Under the pallas backend a one-token decode step leaves the block
+    projections in their layer stacks: each of a layer's six bgemv launches
+    (q, k, v, o, the dual gate/up, down) takes the layer index as its one
+    scalar-prefetch operand and reads the layer in place.  The logits are
+    bitwise those of the same step with every layer sliced out first."""
+    from repro.core import blas
+    from repro.models import layers
+    cfg = get_config("stablelm-1.6b", "smoke")
+    params = tf.init_params(KEY, cfg)
+    if quantize:
+        params = layers.quantize_weights(params)
+    cache = tf.init_cache(cfg, B, 16, per_slot=True)
+    tok = jax.random.randint(jax.random.PRNGKey(5), (B, 1), 0, cfg.vocab)
+    step = jax.jit(lambda p, t, c: tf.decode_step(p, t, c, cfg)[0])
+    with blas.use_backend("pallas"):
+        assert _bgemv_launches(step, params, tok, cache) == [1] * 6
+        got = step(params, tok, cache)
+        monkeypatch.setattr(tf, "_in_place", lambda *a: {})
+        step = jax.jit(lambda p, t, c: tf.decode_step(p, t, c, cfg)[0])
+        assert _bgemv_launches(step, params, tok, cache) == [0] * 6
+        want = step(params, tok, cache)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_in_place_only_for_the_pallas_decode_step():
+    """The stacks are read in place only by a one-token step with a cache
+    under pallas; prefill, verify windows, training, the xla backend and
+    MoE expert stacks (4-D) keep the scan's per-layer slices."""
+    from repro.core import blas
+    cfg = get_config("stablelm-1.6b", "smoke")
+    stacked = jax.eval_shape(lambda: tf.init_params(KEY, cfg))["layers"]
+    one, many = jnp.zeros((B, 1, cfg.d_model)), jnp.zeros((B, 3, cfg.d_model))
+    cache = {"pos": jnp.zeros((B,), jnp.int32)}
+    with blas.use_backend("pallas"):
+        assert tf._in_place(stacked, one, cache) == tf.IN_PLACE_PROJECTIONS
+        assert tf._in_place(stacked, many, cache) == {}
+        assert tf._in_place(stacked, one, None) == {}
+        moe_like = {**stacked, "ffn": {
+            k: jax.ShapeDtypeStruct((2, 4) + w.shape[1:], w.dtype)
+            for k, w in stacked["ffn"].items()}}
+        assert tf._in_place(moe_like, one, cache)["ffn"] == ()
+    assert tf._in_place(stacked, one, cache) == {}

@@ -248,7 +248,9 @@ def test_combined_quantized_gqa_arch_matches_oracle():
 def test_quantized_decode_routes_through_packed_bgemv(monkeypatch):
     """Under the pallas backend the quantized decode projections stay ONE
     broadcast bgemv launch per weight — now with a packed QuantizedTensor
-    operand (in-kernel dequant), not a dequantized array."""
+    operand (in-kernel dequant), not a dequantized array.  A broadcast
+    weight is one 2-D weight, or one layer of a layer stack (`layer`) that
+    the kernel reads in place."""
     from repro.core import quant
     from repro.kernels import ops
 
@@ -256,7 +258,8 @@ def test_quantized_decode_routes_through_packed_bgemv(monkeypatch):
     real_bgemv = ops.bgemv
 
     def spy(a, x, **kw):
-        calls.append((quant.is_quantized(a), a.ndim, x.shape[0]))
+        ndim = a.ndim - (kw.get("layer") is not None)
+        calls.append((quant.is_quantized(a), ndim, x.shape[0]))
         return real_bgemv(a, x, **kw)
 
     monkeypatch.setattr(ops, "bgemv", spy)
@@ -380,7 +383,8 @@ def test_partial_occupancy_decode_routes_through_bgemv(monkeypatch):
     real_bgemv = ops.bgemv
 
     def spy(a, x, **kw):
-        calls.append((a.ndim, x.shape[0]))
+        # one layer of a layer stack read in place is a broadcast weight too
+        calls.append((a.ndim - (kw.get("layer") is not None), x.shape[0]))
         return real_bgemv(a, x, **kw)
 
     monkeypatch.setattr(ops, "bgemv", spy)

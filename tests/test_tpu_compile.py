@@ -1,7 +1,8 @@
 """Compile-only checks of the serving path's Pallas kernels for a TPU v5e.
 
 Each test lowers one kernel at stablelm-1.6b's published widths (d=2048,
-32 heads of 64, d_ff=5632) with `interpret=False` and compiles it for a
+32 heads of 64, d_ff=5632), or at internlm2-20b's where it says so (the
+int8 projections), with `interpret=False` and compiles it for a
 DESCRIBED v5e chip: the TPU compiler is installed here even where no chip
 is attached, so it refuses what the chip would refuse (block shapes off the
 (8, 128) tiling, scoped VMEM overruns).  Nothing runs, so these tests say
@@ -52,12 +53,13 @@ def _spec(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _packed(sharding, k, n):
+def _packed(sharding, k, n, lead=()):
     """Shapes of a serving weight packed as layers.quantize_weights packs it:
-    output-major int8 values with one f32 scale per 64 output rows."""
+    output-major int8 values with one f32 scale per 64 output rows (`lead`:
+    a layer stack's leading axis)."""
     qt = jax.eval_shape(
         lambda w: quant.quantize(w, quant.QuantSpec(64, None, transpose=True)),
-        jax.ShapeDtypeStruct((k, n), jnp.bfloat16))
+        jax.ShapeDtypeStruct(lead + (k, n), jnp.bfloat16))
     return (_spec(sharding, qt.values.shape, qt.values.dtype),
             _spec(sharding, qt.scales.shape, qt.scales.dtype), qt.block)
 
@@ -102,25 +104,100 @@ def test_prefill_bgemm_swiglu_int8_at_internlm2_widths_compiles(one_chip):
         _spec(one_chip, (b, t, d)), w, ws, w, ws)
 
 
+def _planned_bgemv(a_dtype, m, n, *, gate=False, residual=False):
+    """The (block_b, block_m, block_n) keywords `tiling.plan_bgemv` gives a
+    broadcast-weight decode projection of B slots."""
+    bb, bm, bn = tiling.plan_bgemv(B, m, n, a_dtype, jnp.bfloat16, gate=gate,
+                                   residual=residual)
+    assert bb == B  # the whole batch in one block: each tile read once
+    return dict(block_b=bb, block_m=bm, block_n=bn)
+
+
 def test_decode_bgemv_transpose_a_compiles(one_chip):
     """The bf16 decode projection: broadcast weight streamed in its HBM
-    (d, f) layout under transpose_a, with the SwiGLU gate fused."""
+    (d, f) layout under transpose_a, with the SwiGLU gate fused, at the
+    planner's tiles."""
     epi = epilogue.make("silu", gate=True)
+    blocks = _planned_bgemv(jnp.bfloat16, FF, D, gate=True)
     _compile(lambda w, w2, x: bgemv.bgemv(w, x, a2=w2, epilogue=epi,
-                                          transpose_a=True),
+                                          transpose_a=True, **blocks),
              _spec(one_chip, (D, FF)), _spec(one_chip, (D, FF)),
              _spec(one_chip, (B, D)))
 
 
 def test_decode_bgemv_int8_packed_compiles(one_chip):
     """The int8 decode projection: packed output-major weight and its scale
-    grid streamed with in-kernel dequantization, SwiGLU gate fused."""
+    grid, the tile converted in-kernel and scaled in the flush, SwiGLU gate
+    fused, at the planner's tiles."""
     epi = epilogue.make("silu", gate=True)
     w, ws, q_block = _packed(one_chip, D, FF)
+    blocks = _planned_bgemv(jnp.int8, FF, D, gate=True)
     _compile(lambda w, ws, w2, w2s, x: bgemv.bgemv(
         w, x, a2=w2, scales=ws, a2_scales=w2s, q_block=q_block,
-        epilogue=epi),
+        epilogue=epi, **blocks),
         w, ws, w, ws, _spec(one_chip, (B, D)))
+
+
+@pytest.mark.parametrize("proj", [
+    # internlm2-20b (d=6144, d_ff=16384), int8, one scale per 64 rows
+    pytest.param(("int8", 6144, 16384, "gate"), id="int8-gate-up"),
+    pytest.param(("int8", 16384, 6144, "residual"), id="int8-down"),
+    pytest.param(("int8", 6144, 6144, None), id="int8-wq"),
+    # stablelm-1.6b, bf16 under transpose_a
+    pytest.param(("bf16", D, FF, "gate"), id="bf16-gate-up"),
+    pytest.param(("bf16", D, D, "bias"), id="bf16-qkv-bias"),
+])
+@pytest.mark.parametrize("layers", [None, 8], ids=["one", "stack"])
+def test_decode_bgemv_compiles_at_serving_widths(one_chip, proj, layers):
+    """Each decode projection of both benchmark models, 8 slots in one
+    block, at the tiles the planner gives: the VMEM charge and the tiling
+    of every block (weight tile, (B, bn) x, lane-dense (B, bm) output) are
+    what the chip's compiler checks.  "stack" is how the decode step runs
+    it: one layer read in place out of an 8-layer stack, the layer index
+    in SMEM."""
+    kind, k, n, extra = proj
+    gate, residual, bias = extra == "gate", extra == "residual", extra == "bias"
+    epi = epilogue.make("silu" if gate else None, gate=gate or None,
+                        residual=residual or None, bias=bias or None)
+    x = _spec(one_chip, (B, k))
+    lead = () if layers is None else (layers,)
+    layer = [] if layers is None else [_spec(one_chip, (), jnp.int32)]
+    res = [_spec(one_chip, (B, n))] if residual else []
+    if kind == "int8":
+        w, ws, q_block = _packed(one_chip, k, n, lead)
+        blocks = _planned_bgemv(jnp.int8, n, k, gate=gate, residual=residual)
+
+        def fn(x, w, ws, *rest):
+            rest = list(rest)
+            kw = dict(a2=rest.pop(0), a2_scales=rest.pop(0)) if gate else {}
+            if residual:
+                kw["residual"] = rest.pop(0)
+            if layers:
+                kw["layer"] = rest.pop(0)
+            return bgemv.bgemv(w, x, scales=ws, q_block=q_block, epilogue=epi,
+                               **kw, **blocks)
+
+        _compile(fn, x, w, ws, *([w, ws] if gate else []), *res, *layer)
+        return
+    w = _spec(one_chip, lead + (k, n))
+    blocks = _planned_bgemv(jnp.bfloat16, n, k, gate=gate, residual=residual)
+
+    def fn(x, w, *rest):
+        rest = list(rest)
+        kw = {}
+        if gate:
+            kw["a2"] = rest.pop(0)
+        if bias:
+            kw["bias"] = rest.pop(0)
+        if residual:
+            kw["residual"] = rest.pop(0)
+        if layers:
+            kw["layer"] = rest.pop(0)
+        return bgemv.bgemv(w, x, epilogue=epi, transpose_a=True, **kw,
+                           **blocks)
+
+    _compile(fn, x, w, *([w] if gate else []),
+             *([_spec(one_chip, (1, n))] if bias else []), *res, *layer)
 
 
 def test_flash_cache_layout_decode_compiles(one_chip):
