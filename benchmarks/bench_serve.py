@@ -276,14 +276,15 @@ def shared_prefix_rows(arch: str = "stablelm-1.6b", variant: str = "smoke",
                        tail: int = 4, gen: int = 12, page_size: int = 8,
                        seed: int = 0, backend: str = "xla"):
     """Shared-prefix serving (ISSUE 7): every request opens with the same
-    `sys_len`-token system prompt, with a short unique tail.  The dense
-    per-slot cache stores the prefix once PER SLOT; the paged cache hashes
-    it page by page at admission and backs all concurrent slots with the
-    same physical pages, so the pool holds the prefix ONCE.  Both runs serve
-    identical work and greedy tokens are asserted identical — the paged
-    row's capacity multiplier (per-slot logical pages / distinct physical
-    pages, peak over the run) is the effective-capacity win CI gates
-    (> 1.5x at batch 4 with a prefix this long)."""
+    `sys_len`-token system prompt, with a short unique tail.  The control
+    is the same paged run with prefix reuse off, which stores the prefix
+    once PER SLOT; with reuse on the scheduler hashes it page by page at
+    admission and backs all concurrent slots with the same physical pages,
+    so the pool holds the prefix ONCE.  Both runs serve identical work and
+    greedy tokens are asserted identical — the shared run's capacity
+    multiplier (per-slot logical pages / distinct physical pages, peak over
+    the run) is the effective-capacity win CI gates (> 1.5x at batch 4 with
+    a prefix this long)."""
     rng = np.random.default_rng(seed)
     sysp = rng.integers(3, 256, size=(sys_len,), dtype=np.int32)
     prompts = [
@@ -292,12 +293,13 @@ def shared_prefix_rows(arch: str = "stablelm-1.6b", variant: str = "smoke",
     ]
     gen_lens = [gen] * requests
     kw = dict(batch=batch, prompts=prompts, gen_lens=gen_lens, seed=seed,
-              eos=-1, verbose=False, backend=backend, scheduler="continuous")
-    dense = serve(arch, variant, **kw)
-    paged = serve(arch, variant, kv_page_size=page_size, **kw)
-    assert paged["outputs"] == dense["outputs"], \
-        "paged serving must be greedy-token identical to the dense cache"
-    assert paged["completed"] == dense["completed"] == requests
+              eos=-1, verbose=False, backend=backend, scheduler="continuous",
+              kv_page_size=page_size)
+    unshared = serve(arch, variant, prefix_reuse=False, **kw)
+    paged = serve(arch, variant, **kw)
+    assert paged["outputs"] == unshared["outputs"], \
+        "prefix reuse must be greedy-token identical to unshared pages"
+    assert paged["completed"] == unshared["completed"] == requests
     return [(
         "serve_paged_shared_prefix",
         round(paged["tok_s"], 1),
@@ -308,7 +310,7 @@ def shared_prefix_rows(arch: str = "stablelm-1.6b", variant: str = "smoke",
         f"cow_copies={float(paged['cow_copies'])};"
         f"kv_page_size={float(page_size)};"
         f"token_parity=1.0;"
-        f"tok_s_dense={dense['tok_s']:.1f};"
+        f"tok_s_unshared={unshared['tok_s']:.1f};"
         f"tok_s_paged={paged['tok_s']:.1f}",
     )]
 

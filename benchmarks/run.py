@@ -39,7 +39,7 @@ JSON schema (schema_version 1):
                   "ttft_p95": float,            # chunked-admission TTFT p95 (s)
                   "paged_capacity_multiplier": float,  # logical/physical pages
                                                        # under a shared prefix
-                  "paged_token_parity": float,  # 1.0 iff paged == dense tokens
+                  "paged_token_parity": float,  # 1.0 iff shared == unshared tokens
                   "paged_pages_live": float,    # peak distinct physical pages
                   "paged_pages_shared": float,  # peak pages with refcount > 1
                   "preempt_recompute_parity": float,  # 1.0 iff preempted
@@ -140,7 +140,7 @@ def _summarize(rows: list[dict]) -> dict:
                       if isinstance(m.get(k), float)}
         if row["name"] == "serve_paged_shared_prefix":
             # paged KV cache + shared-prefix reuse (ISSUE 7): effective-
-            # capacity multiplier and dense-path token parity, for the CI gate
+            # capacity multiplier and token parity with reuse off, for the CI gate
             paged = {k: m[k] for k in ("paged_capacity_multiplier",
                                        "pages_live", "pages_shared",
                                        "token_parity")
@@ -196,7 +196,7 @@ def _summarize(rows: list[dict]) -> dict:
         "ttft_p95": stall.get("ttft_p95", 0.0),
         # paged KV cache with shared-prefix reuse (ISSUE 7): per-slot logical
         # pages / distinct physical pages (peak) under a shared system
-        # prompt, plus greedy-token parity of the paged run vs the dense one
+        # prompt, plus greedy-token parity of the shared run vs reuse off
         "paged_capacity_multiplier": paged.get("paged_capacity_multiplier", 0.0),
         "paged_token_parity": paged.get("token_parity", 0.0),
         "paged_pages_live": paged.get("pages_live", 0.0),

@@ -8,7 +8,8 @@ One chip, two phases, each through `serve()` on the continuous scheduler
 with `backend="pallas"` (8 requests at batch 8, 128-token prompts, 32
 generated tokens each, random weights from a seed):
 
-  bf16     bf16 weights and a dense bf16 KV cache;
+  bf16     bf16 weights and a bf16 KV cache in 16-token pages (the
+           continuous scheduler's default);
   int8     int8 weights, an int8 KV cache and a paged pool (the composed
            path users run).
 

@@ -1,4 +1,5 @@
-"""Host-side page allocator for the paged KV cache (ISSUE 7).
+"""Host-side page policy for the paged KV cache: the allocator and the
+slot manager.
 
 The device side is dumb on purpose: a global page pool
 `(num_pages, page_size, KVH, hd)` per layer plus one `(B, max_pages)` int32
@@ -20,15 +21,23 @@ Page 0 is reserved as the TRASH page: dead page-table entries point at it,
 so the masked decode writes of inactive slots and the culled key blocks of
 short slots always index in-bounds without any device-side branching.
 
-The allocator never touches jax — it is deliberately unit-testable with no
-device in sight, and the serve scheduler mirrors every decision into the
+`SlotPages` puts the continuous scheduler's page decisions on top of the
+allocator: each slot's run of pages, the admission watermark, group
+placement with prefix matching and copy-on-write, growth of a run as a
+slot decodes, victim choice for preemption, and the occupancy peaks.
+
+Nothing here touches jax — the module is unit-testable with no device in
+sight, and the serve scheduler mirrors every decision into the
 device-side page table with tiny `.at[].set` writes.
 """
 
 from __future__ import annotations
 
+import dataclasses
 from collections import deque
-from typing import Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, Iterable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 #: reserved physical page every dead/unmapped table entry points at
 TRASH_PAGE = 0
@@ -265,3 +274,233 @@ class PageAllocator:
 
     def free_pages(self) -> int:
         return len(self._free)
+
+
+@dataclasses.dataclass
+class Placement:
+    """What `SlotPages.place` decided for one admission group, in member
+    order: `placed[i]` says whether member i got its pages (the others go
+    back to the queue); `rows` holds each member's page-table row (trash
+    where unplaced); `graft` the (rows, toks, pages, offs) coordinates of
+    every unshared token to copy out of the mini cache, padded with
+    (0, 0) -> trash writes to `slots * total` entries, so that each prompt
+    length grafts at one shape; `cow` the (src, dst) page copies to make
+    after the graft (a source may be a page the same group grafts); and
+    `victims` the live slots evicted to make room."""
+
+    placed: List[bool]
+    rows: np.ndarray
+    graft: Tuple[List[int], ...]
+    cow: List[Tuple[int, int]]
+    victims: List[int]
+
+
+class SlotPages:
+    """The page policy of a slot grid over one pool: the `PageAllocator`,
+    each slot's run of pages, prefix sharing and copy-on-write, admission
+    control, on-demand growth, victim choice and the occupancy peaks.
+
+    Host-only: it decides and the scheduler writes.  Every method returns
+    what the device has to mirror (table rows, graft coordinates, CoW
+    pairs, growth pages, victims) and touches no device state itself.  A
+    victim's pages are released here; the scheduler still repoints its
+    table row at trash and requeues its request.
+
+    `capacity` is a slot's KV capacity in tokens (its table row holds
+    ceil(capacity / page_size) pages).  The pool defaults to the size at
+    which growth can never exhaust it: every slot's full row plus one page
+    of slack, and the trash page.  `share` turns prefix reuse on;
+    `n_prefix` counts the positions in front of each prompt's tokens (the
+    vlm patch prefix) that every admission writes."""
+
+    def __init__(self, page_size: int, slots: int, capacity: int,
+                 num_pages: Optional[int] = None, share: bool = True,
+                 n_prefix: int = 0):
+        self.page_size = int(page_size)
+        self.max_pages = -(-int(capacity) // self.page_size)
+        if num_pages is None:
+            num_pages = 1 + slots * (self.max_pages + 1)
+        self.alloc = PageAllocator(num_pages, page_size)
+        self.num_pages = self.alloc.num_pages
+        self.share = share
+        self.n_prefix = n_prefix
+        self.slots: List[List[int]] = [[] for _ in range(slots)]
+        self._seq = [0] * slots     # placement order, for victim choice
+        self._next = 0
+        self.peaks = {"pages_live": 0, "pages_shared": 0,
+                      "paged_capacity_multiplier": 0.0, "cow_copies": 0}
+
+    # -- admission -----------------------------------------------------------
+
+    def _match(self, tokens) -> Tuple[List[int], int]:
+        return self.alloc.match_prefix(tokens) if self.share else ([], 0)
+
+    def need(self, tokens: Sequence[int]) -> Optional[int]:
+        """Fresh pages an admission of `tokens` takes through its first
+        decode write (later writes grow on demand), net of a matched shared
+        prefix and plus the CoW of a matched partial tail; None when the
+        request could not fit even a fully-free pool."""
+        total = len(tokens) + self.n_prefix
+        need = total // self.page_size + 1
+        if need > self.num_pages - 1:
+            return None
+        matched, covered = self._match(tokens)
+        new = need - len(matched)
+        if covered == total and total % self.page_size:
+            new += 1
+        return new
+
+    def fits(self, n_pages: int, active: Sequence[bool]) -> bool:
+        """The watermark: do `n_pages` fresh pages fit in the free pages
+        plus what preempting live slots could reclaim?"""
+        return self.alloc.can_admit(self.page_size * n_pages,
+                                    reclaimable=self.reclaimable(active))
+
+    def reclaimable(self, active: Sequence[bool]) -> int:
+        """Pages preemption could free now: the unshared pages of the live
+        slots (the ones `victim` may choose)."""
+        return sum(1 for s, run in enumerate(self.slots) if active[s]
+                   for p in run if not self.alloc.shared(p))
+
+    def place(self, members, active: Sequence[bool]) -> Placement:
+        """Give each member of one admission group (a list of (slot,
+        tokens, decodes), all of one prompt length) its run of pages.
+
+        The first pass matches each prompt against the registered prefixes,
+        retains the matched pages, allocates the rest (evicting live slots
+        if the pool moved since the watermark let the group in; a member
+        that still does not fit is left unplaced) and registers the prompt.
+        The second pass runs after every member has registered, so that
+        identical prompts in one group share their partial tail before
+        anyone writes it: a member that will decode copies a shared write
+        page (the page of position `total`) or unpublishes an owned one."""
+        ps = self.page_size
+        total = len(members[0][1]) + self.n_prefix
+        need = total // ps + 1
+        placed = [False] * len(members)
+        rows = np.zeros((len(members), self.max_pages), np.int64)
+        graft = ([], [], [], [])
+        cow, victims = [], []
+        cow_reserve = 0     # pages the second pass's CoWs will take
+        for i, (s, toks, decodes) in enumerate(members):
+            while True:
+                matched, covered = self._match(toks)
+                # partial-page keys are exact-tail, so a matched partial
+                # page covers the whole prompt: the graft never appends
+                # into a shared page
+                assert covered == total or covered % ps == 0, (covered, total)
+                cow_tail = decodes and covered == total and total % ps != 0
+                new = need - len(matched) + cow_tail
+                if new + cow_reserve <= self.alloc.free_pages():
+                    break
+                if not self._free_up(new + cow_reserve, active, victims):
+                    matched = None
+                    break
+                # the victims may have freed registered pages: match again
+            if matched is None:
+                continue
+            self.alloc.retain(matched)
+            run = matched + self.alloc.alloc(need - len(matched))
+            cow_reserve += cow_tail
+            if self.share:
+                self.alloc.register_prefix(toks, run[:-(-len(toks) // ps)])
+            self.slots[s] = run
+            self._seq[s], self._next = self._next, self._next + 1
+            placed[i] = True
+            rows[i, :len(run)] = run
+            for p in range(covered, total):
+                for coord, v in zip(graft, (i, p, run[p // ps], p % ps)):
+                    coord.append(v)
+        widx = total // ps
+        for i, (s, _, decodes) in enumerate(members):
+            if not placed[i] or not decodes:
+                continue
+            p = self.slots[s][widx]
+            if self.alloc.shared(p):
+                new = self.alloc.cow(p)
+                cow.append((p, new))
+                self.slots[s][widx] = rows[i, widx] = new
+            else:
+                self.alloc.invalidate(p)
+        pad = len(self.slots) * total - len(graft[0])
+        graft = tuple(c + [0] * pad for c in graft)
+        return Placement(placed, rows, graft, cow, victims)
+
+    # -- growth and preemption -----------------------------------------------
+
+    def victim(self, active: Sequence[bool], spared=()) -> Optional[int]:
+        """The live slot to preempt: the newest placement (the least sunk
+        prefill lost, and FIFO keeps older requests moving), skipping slots
+        whose pages are all shared, since releasing them reclaims nothing,
+        and the slots in `spared` (already evicted)."""
+        best = None
+        for s, run in enumerate(self.slots):
+            if (active[s] and s not in spared
+                    and any(not self.alloc.shared(p) for p in run)
+                    and (best is None or self._seq[s] > self._seq[best])):
+                best = s
+        return best
+
+    def _free_up(self, n_pages: int, active, victims: List[int]) -> bool:
+        """Evict victims until `n_pages` pages are free; False when no
+        victim is left."""
+        while self.alloc.free_pages() < n_pages:
+            v = self.victim(active, victims)
+            if v is None:
+                return False
+            self.release(v)
+            victims.append(v)
+        return True
+
+    def grow(self, s: int, last_pos: int, active: Sequence[bool],
+             exhausted: Callable[[], bool] = lambda: False):
+        """Grow slot s's run to cover every write up to position
+        `last_pos`.  `exhausted()` is asked once for each page to allocate;
+        True (an injected exhaustion) evicts a victim first, and so does a
+        pool with no free page.  Returns ([(run index, page)] to write into
+        s's table row, [victims evicted, in order]); it stops early when s
+        itself was evicted, and then the caller skips s this round."""
+        last = last_pos // self.page_size
+        assert last < self.max_pages, (last, self.max_pages)
+        writes, victims = [], []
+        while len(self.slots[s]) <= last:
+            forced = exhausted()
+            while forced or not self.alloc.free_pages():
+                v = self.victim(active, victims)
+                if v is None and not forced:
+                    # unreachable while s is live (a decoding slot always
+                    # owns its unshared write page): the honest failure
+                    # rather than a silent hang
+                    raise PoolExhausted(
+                        f"growth for slot {s}: no free page and no victim")
+                forced = False
+                if v is not None:
+                    self.release(v)
+                    victims.append(v)
+                    if v == s:
+                        return writes, victims
+            p = self.alloc.alloc(1)[0]
+            writes.append((len(self.slots[s]), p))
+            self.slots[s].append(p)
+        return writes, victims
+
+    # -- release and accounting ----------------------------------------------
+
+    def release(self, s: int) -> None:
+        """Drop slot s's references; a no-op on a slot that holds none."""
+        self.alloc.release(self.slots[s])
+        self.slots[s] = []
+
+    def sample(self) -> None:
+        """Fold the pool's current occupancy into `peaks`."""
+        a, pk = self.alloc, self.peaks
+        pk["pages_live"] = max(pk["pages_live"], a.pages_live())
+        pk["pages_shared"] = max(pk["pages_shared"], a.pages_shared())
+        pk["paged_capacity_multiplier"] = max(
+            pk["paged_capacity_multiplier"], a.capacity_multiplier())
+        pk["cow_copies"] = a.cow_copies
+
+    def leak_check(self) -> None:
+        """Conservation: every page is free, live or the trash page,
+        exactly once (`PageAllocator.leak_check`)."""
+        self.alloc.leak_check()

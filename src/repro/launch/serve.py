@@ -3,14 +3,15 @@
 Schedulers
 ----------
 - "continuous" (default): real continuous batching over a fixed slot grid
-  (batch x max_len KV cache).  The moment a sequence finishes (EOS or its
-  generation budget) its slot is freed and the next pending request is
-  admitted at the next step boundary — an admission prefill on the fixed
-  grid shape whose rows are grafted into the freed slots, no waiting for the
-  rest of the batch to drain.  Per-slot position state lives in the jit'd decode step
-  (cache["pos"] is a (batch,) vector; the masked step freezes finished
-  slots), so the donated KV cache keeps updating in place while occupancy
-  stays high.  The decode batch shape never changes, so under
+  whose KV cache is a pool of pages with one page table per slot
+  (`paging.SlotPages` keeps the page policy).  The moment a sequence
+  finishes (EOS or its generation budget) its slot is freed and the next
+  pending request is admitted at the next step boundary — an admission
+  prefill on the fixed grid shape whose rows are grafted into the freed
+  slots' pages, no waiting for the rest of the batch to drain.  Per-slot
+  position state lives in the jit'd decode step (cache["pos"] is a
+  (batch,) vector; the masked step freezes finished slots), so the donated
+  KV cache keeps updating in place while occupancy stays high.  The decode batch shape never changes, so under
   --backend pallas every projection stays one fused broadcast-A `bgemv`
   launch at any occupancy — the bandwidth amortization the batch exists for
   (KBLAS, arXiv:1410.1726: throughput scales with live batch members, not
@@ -51,6 +52,9 @@ from repro.launch import spans as spans_lib
 from repro.launch import steps as steps_lib
 from repro.models import transformer as tf
 from repro.models.registry import get_config
+
+#: KV page size of the continuous scheduler when none is given
+PAGE_SIZE = 16
 
 
 def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, batch: int = 4,
@@ -99,34 +103,38 @@ def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, bat
     projections through the fused batched kernels: every (B, 1, d) matmul is
     one bgemv launch over the request batch with broadcast weights.
 
-    kv_page_size: store the KV cache PAGED — a global pool of
-    `kv_page_size`-token pages plus a per-slot page table — instead of the
-    dense (batch, cache_len) buffers.  Under the continuous scheduler,
-    admission becomes page-pointer writes: the prompt is hashed page by page
-    against previously admitted prompts (prefix_reuse, default on), a
-    matched prefix is backed by the SAME physical pages with a refcount
-    bump, only the unshared suffix is grafted into the pool, and the first
-    divergent write copies-on-write exactly one page.  Freed slots return
-    their pages to a free list.  Greedy tokens are bit-identical to the
-    dense cache under both schedulers; stats gain `pages_live`,
+    kv_page_size: the size in tokens of the KV cache's pages.  The
+    continuous scheduler always stores the KV cache PAGED — a global pool of
+    pages plus a per-slot page table — in pages of `kv_page_size` tokens,
+    or PAGE_SIZE (16) when it is None.  Admission there is page-pointer
+    writes: the prompt is hashed page by page against previously admitted
+    prompts (prefix_reuse, default on), a matched prefix is backed by the
+    SAME physical pages with a refcount bump, only the unshared suffix is
+    grafted into the pool, and the first divergent write copies-on-write
+    exactly one page.  Freed slots return their pages to a free list.  The
+    batch scheduler keeps dense (batch, cache_len) buffers when it is None.
+    Under the xla backend greedy tokens are bit-identical to a dense cache;
+    under pallas the one-query paged decode is its own kernel, which sums
+    in another order than dense flash, so tokens can differ from a dense
+    run where logits nearly tie.  Stats gain `kv_page_size`, `pages_live`,
     `pages_shared`, `cow_copies` and `paged_capacity_multiplier` (logical /
     physical pages — >1 exactly when prefixes are shared).
 
     Operational robustness (ISSUE 8):
 
     pool_pages: override the paged pool size (default: sized so exhaustion
-    cannot happen).  A small pool turns page pressure into real scheduling:
-    admission BLOCKS at the allocator watermark (free + reclaimable pages,
-    FIFO head first — backpressure, not a crash), and an allocation failure
-    during decode-time page growth PREEMPTS a victim slot (newest admission
-    first) whose request is re-queued at the head and later recomputed —
-    continuous scheduler: re-prefill of prompt + already-emitted tokens,
-    continuing the greedy stream bit-identically; batch scheduler: full
-    recompute from the original prompt, same final tokens under greedy
-    decoding.  (Caveat: the vlm family redraws its random patch embeds per
-    admission, so a preempted vlm request's recompute is NOT bit-exact.)
-    A request that cannot fit even a fully-free pool is terminally
-    "rejected".
+    cannot happen; the batch scheduler needs kv_page_size with it).  A small
+    pool turns page pressure into real scheduling: admission BLOCKS at the
+    allocator watermark (free + reclaimable pages, FIFO head first —
+    backpressure, not a crash), and an allocation failure during decode-time
+    page growth PREEMPTS a victim slot (newest admission first) whose
+    request is re-queued at the head and later recomputed — continuous
+    scheduler: re-prefill of prompt + already-emitted tokens, continuing the
+    greedy stream bit-identically; batch scheduler: full recompute from the
+    original prompt, same final tokens under greedy decoding.  (Caveat: the
+    vlm family redraws its random patch embeds per admission, so a preempted
+    vlm request's recompute is NOT bit-exact.) A request that cannot fit
+    even a fully-free pool is terminally "rejected".
 
     speculate=k runs greedy SPECULATIVE decoding (ISSUE 9): every decode
     round, each live slot verifies k self-drafted candidate tokens (n-gram
@@ -233,7 +241,7 @@ def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, bat
         raise ValueError("qscale faults corrupt KV quantization scales and "
                          "need kv_cache='int8'")
     if pool_pages is not None:
-        if kv_page_size is None:
+        if kv_page_size is None and scheduler != "continuous":
             raise ValueError("pool_pages sizes the paged pool and needs "
                              "kv_page_size")
         if pool_pages < 2:
@@ -275,7 +283,7 @@ def serve(arch: str, variant: str = "smoke", requests: Optional[int] = None, bat
                 )
             stats = _serve_continuous(cfg, prompts, list(gen_lens), batch, seed,
                                       eos, quantize, prefill_chunk,
-                                      page_size=kv_page_size,
+                                      page_size=kv_page_size or PAGE_SIZE,
                                       prefix_reuse=prefix_reuse,
                                       deadline_ms=deadline_ms,
                                       pool_pages=pool_pages,
@@ -422,17 +430,6 @@ def _prefill_extras(cfg, rng, n: int, enc: int) -> dict:
     return extras
 
 
-def _admit_step(cache, mini, slots, tok, tok0):
-    """jit target for one admission round: graft the prefilled rows into
-    their slots AND splice their first generated tokens into the device
-    token block (one scatter instead of per-slot eager dispatches).
-    Padding rows (slots[i] < 0) drop out of both scatters."""
-    cache = tf.insert_slots_cache(cache, mini, slots)
-    safe = jnp.where(slots < 0, tok.shape[0], slots)
-    tok = tok.at[safe].set(tok0, mode="drop")
-    return cache, tok
-
-
 def _quantize_params(params, quantize: str, spans):
     """The serving weights: with `quantize="int8"` every projection packed
     (`layers.quantize_weights`), as one `serve.pack` span in `spans` with
@@ -470,8 +467,57 @@ def _make_tp_context(cfg, params, tp: int):
     return {"mesh": mesh, "pspecs": pspecs, "params": params}
 
 
+def _slot_programs(cfg, spec, fault_kinds, tp_ctx, slot_cache, mini_zero):
+    """The continuous scheduler's jitted programs: the admission prefill
+    (its zero template is reused every round: no donation), the slot decode
+    step — the verify step under speculation, one (B, k+1) window launch
+    per round — and its poisoned variants for the NaN/Inf faults in
+    `fault_kinds`.  Also returns `put_slot`, which places a slot cache at
+    the sharding the steps take (so the donated buffers never reshard per
+    step), and the mini cache template placed likewise.  Under --tp the
+    steps are the sharded ones, with cache specs taken from a real slot
+    cache, page pool included (`slot_cache()`)."""
+    if tp_ctx is None:
+        prefill = steps_lib.make_prefill_step(cfg)
+
+        def mk_step(act_fault=None):
+            if spec:
+                return steps_lib.make_verify_step_slots(cfg, spec,
+                                                        act_fault=act_fault)
+            return steps_lib.make_decode_step_slots(cfg, act_fault=act_fault)
+
+        def put_slot(c):
+            return c
+    else:
+        mesh, pspecs = tp_ctx["mesh"], tp_ctx["pspecs"]
+        mini_specs = sharding_lib.tp_cache_specs(mini_zero)
+        slot_specs = sharding_lib.tp_cache_specs(slot_cache())
+        slot_shardings = sharding_lib.to_shardings(slot_specs, mesh)
+        prefill = steps_lib.make_tp_prefill_step(cfg, mesh, pspecs, mini_specs)
+
+        def mk_step(act_fault=None):
+            if spec:
+                return steps_lib.make_tp_verify_step_slots(
+                    cfg, mesh, spec, pspecs, slot_specs, act_fault=act_fault)
+            return steps_lib.make_tp_decode_step_slots(
+                cfg, mesh, pspecs, slot_specs, act_fault=act_fault)
+
+        def put_slot(c):
+            return jax.device_put(c, slot_shardings)
+
+        mini_zero = jax.device_put(
+            mini_zero, sharding_lib.to_shardings(mini_specs, mesh))
+    decode = jax.jit(mk_step(), donate_argnums=(2,))
+    faulted = {
+        kind: jax.jit(mk_step(act_fault=val), donate_argnums=(2,))
+        for kind, val in (("nan", float("nan")), ("inf", float("inf")))
+        if kind in fault_kinds
+    }
+    return jax.jit(prefill), decode, faulted, put_slot, mini_zero
+
+
 def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
-                      prefill_chunk=None, page_size=None, prefix_reuse=True,
+                      prefill_chunk=None, page_size=PAGE_SIZE, prefix_reuse=True,
                       deadline_ms=None, pool_pages=None,
                       check_invariants=False, plan=None, speculate=None,
                       tp=1):
@@ -485,31 +531,30 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     admission costs each live slot at most one chunk of prefill work between
     its tokens instead of the whole prompt.
 
-    With `page_size`, the slot cache is the PAGED pool: admission writes the
-    slot's page-table row (matched shared prefix pages + fresh pages) and
-    grafts only the unshared suffix tokens; a finished slot's row is
-    repointed at the trash page and its pages go back to the free list.  The
-    decode step itself is unchanged — still one masked launch over the slot
-    grid, reading and writing straight through the page table.
+    The slot cache is a pool of `page_size`-token pages plus a page table
+    per slot.  Every page decision — the admission watermark, placement
+    with prefix sharing and copy-on-write, growth, victim choice, release —
+    is `paging.SlotPages`'s; this function makes the device writes that
+    mirror them: admission writes the slot's page-table row (matched shared
+    prefix pages + fresh pages) and grafts only the unshared suffix tokens;
+    a finished slot's row is repointed at the trash page.  The decode step
+    itself is one masked launch over the slot grid, reading and writing
+    straight through the page table.
 
-    Robustness layer (ISSUE 8).  Admission reserves only the pages the
-    prompt plus the first decode write need; decode GROWS the slot's page
-    run on demand at page boundaries.  Admission is gated by the
-    allocator's watermark (`can_admit` against free + reclaimable pages):
-    the FIFO head blocks — backpressure — instead of crashing the pool.  A
-    growth (or injected) allocation failure preempts a victim slot —
-    newest admission first, slots whose pages are all prefix-shared are
-    skipped because releasing them reclaims nothing — frees its non-shared
-    pages, and re-queues its request at the queue head; the re-admission
-    prefills the ORIGINAL PROMPT + ALREADY-EMITTED TOKENS, which by the
-    chunked-prefill parity property continues the greedy stream
-    bit-identically (already-emitted tokens are never re-recorded).
-    Per-request deadlines are enforced at decode-round boundaries
-    (terminal status "timeout"); a request whose prompt can never fit the
-    whole pool is terminally "rejected".  `plan` (a faults.FaultPlan)
-    injects deterministic exhaustion/graft/NaN/Inf/scale faults, and
-    `check_invariants` sweeps the allocator/page-table/finiteness
-    invariants every round."""
+    Robustness layer.  Admission reserves only the pages the prompt plus the
+    first decode write need; decode GROWS the slot's page run on demand at
+    page boundaries.  The FIFO head blocks at the watermark (free +
+    reclaimable pages) — backpressure — instead of crashing the pool.  A
+    growth (or injected) allocation failure preempts a victim slot and
+    re-queues its request at the queue head; the re-admission prefills the
+    ORIGINAL PROMPT + ALREADY-EMITTED TOKENS, which by the chunked-prefill
+    parity property continues the greedy stream bit-identically
+    (already-emitted tokens are never re-recorded).  Per-request deadlines
+    are enforced at decode-round boundaries (terminal status "timeout"); a
+    request whose prompt can never fit the whole pool is terminally
+    "rejected".  `plan` (a faults.FaultPlan) injects deterministic
+    exhaustion/graft/NaN/Inf/scale faults, and `check_invariants` sweeps
+    the allocator/page-table/finiteness invariants every round."""
     plan = plan if plan is not None else faults_lib.FaultPlan({})
     nreq = len(prompts)
     spec = int(speculate or 0)
@@ -530,90 +575,23 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         params = tp_ctx["params"]
     mini_zero = tf.init_cache(cfg, batch, cache_len)
 
-    paged = page_size is not None
-    if paged:
-        max_pages = -(-cache_len // page_size)
-        # the pool still defaults to the no-exhaustion worst case (each
-        # slot's full capacity + slack); on-demand growth means live pages
-        # track ACTUAL tokens, and pool_pages can shrink the pool to create
-        # real backpressure/preemption traffic
-        num_pages = pool_pages if pool_pages is not None else 1 + batch * (max_pages + 1)
-        alloc = paging.PageAllocator(num_pages, page_size)
-        slot_pages = [[] for _ in range(batch)]
-        graft_fn = jax.jit(tf.graft_pages, donate_argnums=(0,))
-        copy_fn = jax.jit(tf.copy_pages, donate_argnums=(0,))
-        # vlm prompts carry per-admission random patch embeds in front of
-        # the tokens, so equal token ids do NOT mean equal KV: never share
-        share = prefix_reuse and cfg.family != "vlm"
-        n_prefix = cfg.n_prefix if cfg.family == "vlm" else 0
-    else:
-        admit_fn = jax.jit(_admit_step, donate_argnums=(0, 3))
+    # vlm prompts carry per-admission random patch embeds in front of the
+    # tokens, so equal token ids do NOT mean equal KV: never share
+    n_prefix = cfg.n_prefix if cfg.family == "vlm" else 0
+    pages = paging.SlotPages(page_size, batch, cache_len, num_pages=pool_pages,
+                             share=prefix_reuse and cfg.family != "vlm",
+                             n_prefix=n_prefix)
+    graft_fn = jax.jit(tf.graft_pages, donate_argnums=(0,))
+    copy_fn = jax.jit(tf.copy_pages, donate_argnums=(0,))
 
-    # step builders (after the paged-pool sizing: the TP cache specs come
-    # from a REAL slot-cache template, page pool included)
-    if tp_ctx is None:
-        def mk_prefill():
-            return steps_lib.make_prefill_step(cfg)
+    def slot_cache():
+        return tf.init_cache(cfg, batch, cache_len, per_slot=True,
+                             page_size=page_size, num_pages=pages.num_pages)
 
-        def mk_decode(act_fault=None):
-            return steps_lib.make_decode_step_slots(cfg, act_fault=act_fault)
-
-        def mk_verify(act_fault=None):
-            return steps_lib.make_verify_step_slots(cfg, spec,
-                                                    act_fault=act_fault)
-
-        def put_slot(c):
-            return c
-    else:
-        mesh, pspecs = tp_ctx["mesh"], tp_ctx["pspecs"]
-        mini_specs = sharding_lib.tp_cache_specs(mini_zero)
-        slot_kwargs = dict(per_slot=True)
-        if paged:
-            slot_kwargs.update(page_size=page_size, num_pages=num_pages)
-        slot_specs = sharding_lib.tp_cache_specs(
-            tf.init_cache(cfg, batch, cache_len, **slot_kwargs))
-        slot_shardings = sharding_lib.to_shardings(slot_specs, mesh)
-
-        def mk_prefill():
-            return steps_lib.make_tp_prefill_step(cfg, mesh, pspecs,
-                                                  mini_specs)
-
-        def mk_decode(act_fault=None):
-            return steps_lib.make_tp_decode_step_slots(
-                cfg, mesh, pspecs, slot_specs, act_fault=act_fault)
-
-        def mk_verify(act_fault=None):
-            return steps_lib.make_tp_verify_step_slots(
-                cfg, mesh, spec, pspecs, slot_specs, act_fault=act_fault)
-
-        def put_slot(c):
-            # place a freshly-built slot cache at the shard_map's required
-            # sharding once, so the donated buffers never reshard per step
-            return jax.device_put(c, slot_shardings)
-
-        mini_zero = jax.device_put(
-            mini_zero, sharding_lib.to_shardings(mini_specs, mesh))
-
-    # the admission prefill's zero template is reused every round: no donation
-    prefill_fn = jax.jit(mk_prefill())
+    prefill_fn, decode_fn, decode_faulted, put_slot, mini_zero = \
+        _slot_programs(cfg, spec, plan.events, tp_ctx, slot_cache, mini_zero)
     if spec:
-        # speculative: the decode step IS the verify step — one (B, k+1)
-        # window launch per round; the plain step is never traced
-        decode_fn = jax.jit(mk_verify(), donate_argnums=(2,))
-        decode_faulted = {
-            kind: jax.jit(mk_verify(act_fault=val), donate_argnums=(2,))
-            for kind, val in (("nan", float("nan")), ("inf", float("inf")))
-            if kind in plan.events
-        }
         drafter = draft_lib.make_drafter("ngram")
-    else:
-        decode_fn = jax.jit(mk_decode(), donate_argnums=(2,))
-        # poisoned step variants, traced only when a NaN/Inf fault is scheduled
-        decode_faulted = {
-            kind: jax.jit(mk_decode(act_fault=val), donate_argnums=(2,))
-            for kind, val in (("nan", float("nan")), ("inf", float("inf")))
-            if kind in plan.events
-        }
 
     # compile outside the timed region (throwaway buffers), so the stats
     # measure scheduling, not jit.  Ragged prompts still trace one extra
@@ -621,41 +599,22 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     warm_in = {"tokens": jnp.zeros((batch, len(prompts[0])), jnp.int32)}
     warm_in.update(_prefill_extras(cfg, rng, batch, 0))
     warm_tok0, warm_mini = prefill_fn(params, warm_in, mini_zero)
-    if paged:
-        warm_cache = put_slot(tf.init_cache(cfg, batch, cache_len,
-                                            per_slot=True,
-                                            page_size=page_size,
-                                            num_pages=num_pages))
-        zc = jnp.zeros((batch * (len(prompts[0]) + n_prefix),), jnp.int32)
-        warm_cache = graft_fn(warm_cache, warm_mini, zc, zc, zc, zc)
-        warm_cache = copy_fn(warm_cache, jnp.zeros((1,), jnp.int32),
-                             jnp.zeros((1,), jnp.int32))
-        warm_tok = jnp.zeros((batch, 1), jnp.int32)
-    else:
-        warm_cache, warm_tok = admit_fn(
-            put_slot(tf.init_cache(cfg, batch, cache_len, per_slot=True)),
-            warm_mini,
-            jnp.zeros(batch, jnp.int32) - 1, jnp.zeros((batch, 1), jnp.int32), warm_tok0)
+    zc = jnp.zeros((batch * (len(prompts[0]) + n_prefix),), jnp.int32)
+    warm_cache = graft_fn(put_slot(slot_cache()), warm_mini, zc, zc, zc, zc)
+    warm_cache = copy_fn(warm_cache, jnp.zeros((1,), jnp.int32),
+                         jnp.zeros((1,), jnp.int32))
     if spec:
-        warm_p, warm_a, warm_cache = decode_fn(
+        warm_tok, _, warm_cache = decode_fn(
             params, jnp.zeros((batch, spec + 1), jnp.int32), warm_cache,
             jnp.zeros(batch, bool))
-        jax.block_until_ready(warm_p)
-        del warm_p, warm_a
     else:
-        warm_tok, warm_cache = decode_fn(params, warm_tok, warm_cache,
-                                         jnp.zeros(batch, bool))
-        jax.block_until_ready(warm_tok)
+        warm_tok, warm_cache = decode_fn(params, jnp.zeros((batch, 1), jnp.int32),
+                                         warm_cache, jnp.zeros(batch, bool))
+    jax.block_until_ready(warm_tok)
     del warm_mini, warm_cache, warm_tok, warm_tok0
 
     pending = collections.deque(enumerate(prompts))  # FIFO: popleft serves arrival order
-    if paged:
-        cache = put_slot(tf.init_cache(cfg, batch, cache_len, per_slot=True,
-                                       page_size=page_size,
-                                       num_pages=num_pages))
-        max_pages_row = cache["page_table"].shape[1]
-    else:
-        cache = put_slot(tf.init_cache(cfg, batch, cache_len, per_slot=True))
+    cache = put_slot(slot_cache())
     # the token block and active mask live on device; the host only touches
     # rows on admission/finish events, so a steady decode step has no H2D
     # transfer (same as the batch-at-a-time loop)
@@ -665,9 +624,7 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     slot_left = np.zeros(batch, np.int64)
     slot_pos = np.zeros(batch, np.int64)        # next decode write position
     slot_last = np.zeros(batch, np.int64)       # last COMMITTED token (spec
-    slot_admit_seq = np.zeros(batch, np.int64)  # window pos 0); admit order
-    admit_seq = [0]
-    preempted_ever = [False] * nreq
+    preempted_ever = [False] * nreq             # window pos 0)
     active = np.zeros(batch, bool)
     # the device mask went stale via a free/preempt outside admission; the
     # next decode round refreshes it once instead of per event
@@ -675,10 +632,8 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     stats = _new_stats(nreq)
     pack = spans.last("serve.pack")
     stats["pack_s"] = pack["end"] - pack["start"] if pack else 0.0
-    if paged:
-        stats.update({"kv_page_size": page_size, "pages_live": 0,
-                      "pages_shared": 0, "paged_capacity_multiplier": 0.0,
-                      "cow_copies": 0})
+    stats["kv_page_size"] = page_size
+    stats.update(pages.peaks)
     if spec:
         stats.update({"speculate": spec, "spec_slot_steps": 0,
                       "spec_emitted": 0, "spec_drafts_proposed": 0,
@@ -686,14 +641,6 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                       # spec_accept_hist[a] = verify rounds (per slot) that
                       # accepted exactly a of the k drafts
                       "spec_accept_hist": [0] * (spec + 1)})
-
-    def sample_pages():
-        """Fold the allocator's current occupancy into the run peaks."""
-        stats["pages_live"] = max(stats["pages_live"], alloc.pages_live())
-        stats["pages_shared"] = max(stats["pages_shared"], alloc.pages_shared())
-        stats["paged_capacity_multiplier"] = max(
-            stats["paged_capacity_multiplier"], alloc.capacity_multiplier())
-        stats["cow_copies"] = alloc.cow_copies
 
     occ = []
     t0 = time.time()
@@ -707,94 +654,38 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
     # an admission group was grafted since the last decode round
     grafted = [False]
 
-    def free_slot(s):
-        """Release slot s's pages and repoint its table row at trash so the
-        frozen slot's masked decode writes can never land in a recycled
-        page.  Shared by finish, timeout and preemption."""
+    def exhausted():
+        return plan.take("exhaust")
+
+    def trash_row(s):
+        """Repoint slot s's table row at trash, so the frozen slot's masked
+        decode writes can never land in a recycled page."""
         nonlocal cache
+        cache["page_table"] = cache["page_table"].at[s].set(paging.TRASH_PAGE)
+
+    def free_slot(s):
+        """Release slot s's pages and trash its row.  Shared by finish,
+        timeout and preemption."""
         if spec and slot_req[s] >= 0:
             # preempted requests get a fresh begin() at re-admission
             drafter.forget(int(slot_req[s]))
         active[s] = False
         slot_req[s] = -1
         dirty[0] = True
-        if paged:
-            alloc.release(slot_pages[s])
-            slot_pages[s] = []
-            cache["page_table"] = cache["page_table"].at[s].set(
-                paging.TRASH_PAGE)
-
-    def pick_victim():
-        """Preemption victim: the NEWEST admission (least sunk prefill work
-        lost, and strict FIFO keeps older requests making progress).  Paged
-        slots whose pages are ALL prefix-shared are skipped — releasing them
-        reclaims nothing."""
-        best, best_seq = None, -1
-        for s in range(batch):
-            if not active[s]:
-                continue
-            if paged and not any(not alloc.shared(p) for p in slot_pages[s]):
-                continue
-            if slot_admit_seq[s] > best_seq:
-                best, best_seq = s, slot_admit_seq[s]
-        return best
+        pages.release(s)
+        trash_row(s)
 
     def preempt(s):
-        """Evict slot s: free its (non-shared) pages and put its request
-        back at the HEAD of the queue.  The re-admission prefills the
-        original prompt + every token already emitted, so the greedy stream
-        continues bit-identically; emitted tokens are never re-recorded."""
+        """Evict slot s (its pages may already be released by `pages`) and
+        put its request back at the HEAD of the queue.  The re-admission
+        prefills the original prompt + every token already emitted, so the
+        greedy stream continues bit-identically; emitted tokens are never
+        re-recorded."""
         vid = slot_req[s]
         stats["preemptions"] += 1
         preempted_ever[vid] = True
         free_slot(s)
         pending.appendleft((vid, prompts[vid]))
-
-    def free_up(n_pages):
-        """Preempt victims until `n_pages` pages are free; False if no
-        preemptible victim remains (every live page is shared)."""
-        while alloc.free_pages() < n_pages:
-            v = pick_victim()
-            if v is None:
-                return False
-            preempt(v)
-        return True
-
-    def ensure_page(s, horizon=0):
-        """Grow slot s's page run to cover every write of the coming round:
-        positions slot_pos[s] .. slot_pos[s]+horizon (horizon=k under
-        speculation — the verify round writes all k+1 candidates before
-        acceptance).  An injected (`exhaust@K`) or real allocation failure
-        preempts a victim; returns False iff s itself was the victim (skip
-        its step).  The plain-decode case grows at most one page per call,
-        exactly the pre-speculation behavior."""
-        nonlocal cache
-        last_idx = (int(slot_pos[s]) + horizon) // page_size
-        assert last_idx < max_pages_row, (last_idx, max_pages_row)
-        while len(slot_pages[s]) <= last_idx:
-            pidx = len(slot_pages[s])
-            if plan.take("exhaust"):
-                v = pick_victim()
-                if v is not None:
-                    preempt(v)
-                    if v == s:
-                        return False
-            while not alloc.free_pages():
-                v = pick_victim()
-                if v is None:
-                    # unreachable while s itself is active (an active
-                    # decoding slot always owns its non-shared write page) —
-                    # kept as the honest failure mode rather than a silent
-                    # hang
-                    raise paging.PoolExhausted(
-                        f"growth for slot {s}: no free page and no victim")
-                preempt(v)
-                if v == s:
-                    return False
-            newp = alloc.alloc(1)[0]
-            slot_pages[s].append(newp)
-            cache["page_table"] = cache["page_table"].at[s, pidx].set(newp)
-        return True
 
     def poison_scale():
         """qscale fault: write Inf into a live KV quantization scale — the
@@ -803,12 +694,8 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         if "k_scale" not in cache:
             return
         arr = cache["k_scale"]
-        if paged:
-            live = [s for s in range(batch) if active[s] and slot_pages[s]]
-            loc = slot_pages[live[0]][0] if live else paging.TRASH_PAGE
-        else:
-            live = [s for s in range(batch) if active[s]]
-            loc = live[0] if live else 0
+        live = [s for s in range(batch) if active[s] and pages.slots[s]]
+        loc = pages.slots[live[0]][0] if live else paging.TRASH_PAGE
         idx = (0, loc) + (0,) * (arr.ndim - 2)
         cache["k_scale"] = arr.at[idx].set(jnp.inf)
 
@@ -838,24 +725,30 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
             active_dev = jnp.asarray(active)
             dirty[0] = False
             return False
-        # injected faults for THIS round.  preempt@K is the only way a
-        # dense-cache slot is ever preempted (no pool to pressure).
+        # injected faults for THIS round
         if plan.at_step("preempt", step_idx):
-            v = pick_victim()
+            v = pages.victim(active)
             if v is not None:
                 preempt(v)
-        if paged:
-            for s in range(batch):
-                if active[s]:
-                    ensure_page(s, horizon=spec)
-            if spec:
-                # CoW hazard gate: every page a verify round may write must
-                # be exclusively owned — a rejected-draft write into a page
-                # with refcount > 1 would corrupt another slot's committed
-                # prefix.  Structural (admission CoWs/unpublishes the write
-                # page, growth pages are fresh), enforced every round.
-                faults_lib.check_write_window(alloc, active, slot_pages,
-                                              slot_pos, page_size, spec)
+        for s in range(batch):
+            if active[s]:
+                # grow the run over this round's writes: positions
+                # slot_pos[s] .. slot_pos[s]+spec (a verify round writes all
+                # k+1 candidates before acceptance)
+                writes, victims = pages.grow(s, int(slot_pos[s]) + spec, active,
+                                             exhausted)
+                for pidx, p in writes:
+                    cache["page_table"] = cache["page_table"].at[s, pidx].set(p)
+                for v in victims:
+                    preempt(v)
+        if spec:
+            # CoW hazard gate: every page a verify round may write must be
+            # exclusively owned — a rejected-draft write into a page with
+            # refcount > 1 would corrupt another slot's committed prefix.
+            # Structural (admission CoWs/unpublishes the write page, growth
+            # pages are fresh), enforced every round.
+            faults_lib.check_write_window(pages.alloc, active, pages.slots,
+                                          slot_pos, page_size, spec)
         if plan.at_step("qscale", step_idx):
             poison_scale()
         fn = decode_fn
@@ -945,23 +838,12 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         if dirty[0]:
             active_dev = jnp.asarray(active)
             dirty[0] = False
-        if paged:
-            sample_pages()
+        pages.sample()
         if check_invariants:
             faults_lib.check_serve_invariants(
-                alloc=alloc if paged else None,
-                table=cache.get("page_table"), active=active,
-                slot_pages=slot_pages if paged else None, cache=cache)
+                alloc=pages.alloc, table=cache["page_table"], active=active,
+                slot_pages=pages.slots, cache=cache)
         return True
-
-    def _reclaimable():
-        """Pages preemption could free RIGHT NOW: the non-shared pages of
-        active slots (the same slots pick_victim may evict)."""
-        n = 0
-        for s in range(batch):
-            if active[s]:
-                n += sum(1 for p in slot_pages[s] if not alloc.shared(p))
-        return n
 
     while pending or active.any():
         if not active.any():
@@ -973,12 +855,12 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         # the admission prefill runs on the fixed grid shape (one launch per
         # distinct prompt length this round; padding rows are dropped at the
         # graft), so a lone admission is not a degenerate batch-1 launch.
-        # Under pool pressure the FIFO head BLOCKS at the allocator's
-        # watermark (free + reclaimable pages) — backpressure, never
-        # skip-ahead — and a request that could not fit even a fully-free
-        # pool is terminally "rejected".  A re-queued (preempted) request's
-        # admission prompt is its original prompt + every token it already
-        # emitted, so the greedy continuation is bit-identical.
+        # Under pool pressure the FIFO head BLOCKS at the watermark —
+        # backpressure, never skip-ahead — and a request that could not fit
+        # even a fully-free pool is terminally "rejected".  A re-queued
+        # (preempted) request's admission prompt is its original prompt +
+        # every token it already emitted, so the greedy continuation is
+        # bit-identical.
         admits = []       # (slot, rid, admission_prompt, n_already_emitted)
         reserved = 0      # pages this round's earlier picks will allocate
         blocked = False
@@ -990,27 +872,16 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                 em = stats["outputs"][rid]
                 adm = (np.concatenate([base, np.asarray(em, np.int32)])
                        if em else base)
-                if paged:
-                    total = len(adm) + n_prefix
-                    # pages through the FIRST decode write (pos == total);
-                    # later writes grow on demand at round boundaries
-                    need = total // page_size + 1
-                    if need > num_pages - 1:
-                        pending.popleft()
-                        stats["status"][rid] = "rejected"
-                        stats["rejections"] += 1
-                        continue  # same slot, next request
-                    matched, covered = (alloc.match_prefix(adm) if share
-                                        else ([], 0))
-                    need_new = need - len(matched)
-                    if covered == total and total % page_size:
-                        # the first decode write will CoW the matched tail
-                        need_new += 1
-                    if not alloc.can_admit(page_size * (need_new + reserved),
-                                           reclaimable=_reclaimable()):
-                        blocked = True  # FIFO head blocks; no skip-ahead
-                        break
-                    reserved += need_new
+                need = pages.need(adm)
+                if need is None:
+                    pending.popleft()
+                    stats["status"][rid] = "rejected"
+                    stats["rejections"] += 1
+                    continue  # same slot, next request
+                if not pages.fits(need + reserved, active):
+                    blocked = True  # FIFO head blocks; no skip-ahead
+                    break
+                reserved += need
                 pending.popleft()
                 admits.append((s, rid, adm, len(em)))
                 break
@@ -1022,10 +893,8 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
             with spans.span("serve.admit", plen=plen, rows=len(group),
                             rids=[rid for _, rid, _, _ in group]):
                 block = np.zeros((batch, plen), np.int32)
-                slots = np.full(batch, -1, np.int32)
-                for i, (s, _, adm, _) in enumerate(group):
+                for i, (_, _, adm, _) in enumerate(group):
                     block[i] = adm
-                    slots[i] = s
                 csize = plen if prefill_chunk is None else min(prefill_chunk, plen)
                 mini = mini_zero
                 tok0 = None
@@ -1043,137 +912,54 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                     stats["prefills"] += 1
                     if active.any():
                         prefill_gap[0] += min(csize, plen - start)
-                placed = [True] * len(group)
-                requeue = []
-                if paged:
-                    # page-pointer admission: match the prompt against registered
-                    # prefixes, take fresh pages for the rest, and graft ONLY the
-                    # unshared suffix tokens out of the mini cache — matched
-                    # pages are already resident in the pool.
-                    total = plen + n_prefix
-                    rows_l, toks_l, pages_l, offs_l = [], [], [], []
-                    cow_src, cow_dst = [], []
-                    table_rows = np.zeros((len(group), max_pages_row), np.int64)
-                    cow_reserve = 0  # pages earlier members' pass-2 CoWs will take
-                    for i, (s, rid, adm, n_em) in enumerate(group):
-                        need = total // page_size + 1
-                        will_decode = gen_lens[rid] - n_em - 1 > 0
-                        while True:
-                            matched, covered = (alloc.match_prefix(adm) if share
-                                                else ([], 0))
-                            # partial-page keys are exact-tail, so a matched
-                            # partial page always covers the whole prompt: the
-                            # graft below never appends into a shared page
-                            assert covered == total or covered % page_size == 0, \
-                                (covered, total)
-                            cow_tail = (will_decode and covered == total
-                                        and total % page_size != 0)
-                            need_new = need - len(matched) + (1 if cow_tail else 0)
-                            if need_new + cow_reserve <= alloc.free_pages():
-                                break
-                            if not free_up(need_new + cow_reserve):
-                                matched = None
-                                break
-                            # free_up's victims may have freed registered pages:
-                            # re-match before trusting the matched list
-                        if matched is None:
-                            # the watermark admitted optimistically but the pool
-                            # moved under us: back out, requeue at the head
-                            slots[i] = -1
-                            placed[i] = False
-                            requeue.append(rid)
-                            continue
-                        alloc.retain(matched)
-                        plist = matched + alloc.alloc(need - len(matched))
-                        if cow_tail:
-                            # the +1 in need_new is NOT allocated here — the CoW
-                            # happens in the second pass, after every member has
-                            # matched; carry the reservation so later members'
-                            # fresh allocations can't eat the page out from under
-                            # it (group CoWs never exceed group reservations: a
-                            # shared write page is always a matched partial tail)
-                            cow_reserve += 1
-                        if share:
-                            alloc.register_prefix(adm, plist[:-(-plen // page_size)])
-                        slot_pages[s] = plist
-                        table_rows[i, :len(plist)] = plist
-                        for p in range(covered, total):
-                            rows_l.append(i)
-                            toks_l.append(p)
-                            pages_l.append(plist[p // page_size])
-                            offs_l.append(p % page_size)
-                    # second placement pass — AFTER every member has matched and
-                    # registered, so identical same-group prompts share their
-                    # partial tail before anyone mutates it: resolve each
-                    # member's first-decode-write hazard (pos == total) inside
-                    # the reservation cow_tail sized — CoW a shared write page,
-                    # unpublish an owned registered tail.  The graft never
-                    # touches page widx when a CoW happens (covered == total
-                    # means nothing is grafted), so coords stay valid.
-                    widx = total // page_size
-                    for i, (s, rid, adm, n_em) in enumerate(group):
-                        if not placed[i] or gen_lens[rid] - n_em - 1 <= 0:
-                            continue
-                        plist = slot_pages[s]
-                        p = plist[widx]
-                        if alloc.shared(p):
-                            newp = alloc.cow(p)
-                            cow_src.append(p)
-                            cow_dst.append(newp)
-                            plist[widx] = newp
-                            table_rows[i, widx] = newp
-                        else:
-                            alloc.invalidate(p)
-                    if plan.take("graft"):
-                        # simulated graft failure, injected BEFORE the donating
-                        # graft call: the device cache is untouched, so recovery
-                        # is pure bookkeeping — back out every placement and
-                        # requeue the whole group at the queue head
-                        for i, (s, rid, adm, n_em) in enumerate(group):
-                            if placed[i]:
-                                alloc.release(slot_pages[s])
-                                slot_pages[s] = []
-                                placed[i] = False
-                        for rid in reversed([r for _, r, _, _ in group]):
-                            pending.appendleft((rid, prompts[rid]))
-                        continue
-                    srows = jnp.asarray([s for s, _, _, _ in group])
-                    cache["page_table"] = cache["page_table"].at[srows].set(
-                        jnp.asarray(table_rows, jnp.int32))
-                    cache["pos"] = cache["pos"].at[srows].set(total)
-                    for src, dst in zip(cow_src, cow_dst):
-                        # matched pages are already resident, so the CoW copy
-                        # can run before the graft (which only writes fresh
-                        # pages)
-                        cache = copy_fn(cache, jnp.asarray([src]),
-                                        jnp.asarray([dst]))
-                    # pad the graft to one fixed bucket per prompt length (the
-                    # padding re-writes mini token (0, 0) into the trash page)
-                    # so ragged admission counts don't retrace the jit
-                    pad = batch * total - len(rows_l)
-                    coords = [jnp.asarray(c + [0] * pad, jnp.int32)
-                              for c in (rows_l, toks_l, pages_l, offs_l)]
-                    cache = graft_fn(cache, mini, *coords)
-                    grafted[0] = True
-                    safe = jnp.asarray(np.where(slots < 0, batch, slots))
-                    tok_dev = tok_dev.at[safe].set(tok0, mode="drop")
-                    sample_pages()
-                else:
-                    if plan.take("graft"):
-                        for rid in reversed([r for _, r, _, _ in group]):
-                            pending.appendleft((rid, prompts[rid]))
-                        continue
-                    cache, tok_dev = admit_fn(cache, mini, jnp.asarray(slots),
-                                              tok_dev, tok0)
-                    grafted[0] = True
-                for rid in reversed(requeue):
-                    # placement-failed members go back to the queue head in
-                    # their original order
-                    pending.appendleft((rid, prompts[rid]))
+                # page-pointer admission: a matched prefix is already
+                # resident in the pool, so only the unshared suffix tokens
+                # are grafted out of the mini cache
+                pl = pages.place(
+                    [(s, adm, gen_lens[rid] - n_em - 1 > 0)
+                     for s, rid, adm, n_em in group], active)
+                for v in pl.victims:
+                    preempt(v)
+                if plan.take("graft"):
+                    # simulated graft failure, injected BEFORE the donating
+                    # graft call: the device cache is untouched, so recovery
+                    # is pure bookkeeping — back out every placement and
+                    # requeue the whole group at the queue head
+                    for (s, _, _, _), ok in zip(group, pl.placed):
+                        if ok:
+                            pages.release(s)
+                    for rid in reversed([r for _, r, _, _ in group]):
+                        pending.appendleft((rid, prompts[rid]))
+                    continue
+                total = plen + n_prefix
+                srows = jnp.asarray([s for s, _, _, _ in group])
+                cache["page_table"] = cache["page_table"].at[srows].set(
+                    jnp.asarray(pl.rows, jnp.int32))
+                cache["pos"] = cache["pos"].at[srows].set(total)
+                cache = graft_fn(cache, mini,
+                                 *(jnp.asarray(c, jnp.int32) for c in pl.graft))
+                for src, dst in pl.cow:
+                    # after the graft: a CoW source may be a page this very
+                    # group grafts (identical prompts share it), and the
+                    # graft never writes a CoW destination
+                    cache = copy_fn(cache, jnp.asarray([src]),
+                                    jnp.asarray([dst]))
+                grafted[0] = True
+                safe = np.full(batch, batch, np.int32)
+                for i, (s, _, _, _) in enumerate(group):
+                    if pl.placed[i]:
+                        safe[i] = s
+                tok_dev = tok_dev.at[jnp.asarray(safe)].set(tok0, mode="drop")
+                pages.sample()
+                for (_, rid, _, _), ok in reversed(list(zip(group, pl.placed))):
+                    if not ok:
+                        # placement-failed members go back to the queue head
+                        # in their original order
+                        pending.appendleft((rid, prompts[rid]))
                 tok0_np = np.asarray(tok0)[:, 0]  # sync BEFORE stamping TTFT
                 t_first = time.time() - t0
                 for i, (s, rid, adm, n_em) in enumerate(group):
-                    if not placed[i]:
+                    if not pl.placed[i]:
                         continue
                     if stats["ttft"][rid] is None:
                         # a resumed request keeps its FIRST admission's TTFT and
@@ -1195,21 +981,15 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
                         slot_req[s] = rid
                         slot_left[s] = rem
                         slot_last[s] = int(tok0_np[i])
-                        slot_admit_seq[s] = admit_seq[0]
-                        admit_seq[0] += 1
-                        slot_pos[s] = plen + (n_prefix if paged else 0)
-                    elif spec:
-                        drafter.forget(rid)
-                if paged:
-                    for i, (s, rid, _, _) in enumerate(group):
-                        if placed[i] and not active[s]:
-                            # finished on its prefill token: nothing will ever be
-                            # decoded into these pages
-                            alloc.release(slot_pages[s])
-                            slot_pages[s] = []
-                            cache["page_table"] = cache["page_table"].at[s].set(
-                                paging.TRASH_PAGE)
-                    sample_pages()
+                        slot_pos[s] = total
+                    else:
+                        if spec:
+                            drafter.forget(rid)
+                        # finished on its prefill token: nothing will ever
+                        # be decoded into these pages
+                        pages.release(s)
+                        trash_row(s)
+                pages.sample()
                 # refresh the device mask per GROUP (not per round): a later
                 # group's chunk-boundary decode must advance this group's slots
                 active_dev = jnp.asarray(active)
@@ -1217,12 +997,11 @@ def _serve_continuous(cfg, prompts, gen_lens, batch, seed, eos, quantize="none",
         if not active.any():
             continue  # remaining pending requests all finished at prefill
         decode_round()
-    if paged:
-        sample_pages()
-        # conservation at end-of-serve ALWAYS (cheap): every page must be
-        # back on the free list — a leak here is a real production bug even
-        # when nothing was injected
-        alloc.leak_check()
+    pages.sample()
+    stats.update(pages.peaks)
+    # conservation at end-of-serve ALWAYS (cheap): a leak here is a real
+    # production bug even when nothing was injected
+    pages.leak_check()
     stats["faults_fired"] = list(plan.fired)
     stats["faults_unfired"] = plan.pending()
     stats["spans"] = spans.records
@@ -1630,13 +1409,16 @@ def main():
                          "bounds the inter-token stall a long admission "
                          "inflicts on live slots")
     ap.add_argument("--kv-page-size", type=int, default=0,
-                    help="store the KV cache paged: a global pool of pages "
-                         "of this many tokens + a per-slot page table "
-                         "(0 = dense per-slot cache).  Freed slots return "
-                         "their pages; under --scheduler continuous a "
-                         "repeated prompt prefix is stored once")
+                    help="KV page size in tokens: the cache is a global pool "
+                         "of pages + a per-slot page table.  --scheduler "
+                         "continuous always pages (0 = 16-token pages) and "
+                         "stores a repeated prompt prefix once; --scheduler "
+                         "batch pages only when this is set (0 = dense).  "
+                         "Freed slots return their pages.  Under --backend "
+                         "pallas paged decode is its own kernel and is not "
+                         "bitwise equal to a dense cache")
     ap.add_argument("--prefix-reuse", default="on", choices=("on", "off"),
-                    help="paged continuous scheduler: hash admitted prompts "
+                    help="continuous scheduler: hash admitted prompts "
                          "page by page and back a matched prefix with the "
                          "SAME physical pages (copy-on-write on divergence)")
     ap.add_argument("--pool-pages", type=int, default=0,

@@ -648,49 +648,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, enc_frames: int = 0,
     raise ValueError(cfg.family)
 
 
-def insert_slots_cache(cache: dict, mini: dict, slots: jnp.ndarray) -> dict:
-    """Graft rows of a freshly prefilled cache into serving slots.
-
-    `cache` is a per-slot cache (init_cache(..., per_slot=True), pos (B,));
-    `mini` is a scalar-pos cache with the same batch and max_len that just
-    ran a (padded) prompt block through `prefill` — admission runs on the
-    fixed grid shape, like decode, so there is one jit trace per prompt
-    length instead of a per-request batch-1 launch.  Row i of `mini`
-    replaces slot slots[i] wholesale (clearing the previous occupant's
-    residue) and sets that slot's position entry to the mini cache's scalar
-    pos; slots[i] < 0 marks a padding row and is dropped, so the admitted
-    requests continue in place while every other slot keeps decoding
-    untouched.
-    """
-    # negative indices WRAP under jnp indexing (mode="drop" only drops
-    # out-of-range), so rewrite padding markers to B before the scatter
-    nslots = cache["pos"].shape[0]
-    slots = jnp.where(slots < 0, nslots, slots)
-    new = dict(cache)
-    for key in ("k", "v", "k_scale", "v_scale"):
-        if key in cache:
-            new[key] = cache[key].at[:, slots].set(
-                mini[key].astype(cache[key].dtype), mode="drop"
-            )
-    new["pos"] = cache["pos"].at[slots].set(
-        jnp.full(slots.shape, mini["pos"], cache["pos"].dtype), mode="drop"
-    )
-    return new
-
-
 def graft_pages(cache: dict, mini: dict, rows: jnp.ndarray, toks: jnp.ndarray,
                 pages: jnp.ndarray, offs: jnp.ndarray) -> dict:
     """Graft admission-prefill tokens into the paged pool, token by token.
 
     `cache` is a paged cache (init_cache(..., page_size=...)); `mini` is the
-    dense scalar-pos mini cache admission prefilled into (insert_slots_cache's
-    source).  Token i copies mini row `rows[i]`, position `toks[i]` — every
-    layer at once, values and scale pages in lockstep — into pool page
-    `pages[i]` at row `offs[i]`.  The host only enumerates the NON-SHARED
-    suffix of each admitted prompt here: tokens covered by a matched prefix
-    are pure page-table pointer writes and never touch the pool — that is
-    the structural difference from the dense `insert_slots_cache` scatter,
-    which re-copied the whole capacity row per admission.
+    dense scalar-pos mini cache admission prefilled into.  Token i copies
+    mini row `rows[i]`, position `toks[i]` — every layer at once, values and
+    scale pages in lockstep — into pool page `pages[i]` at row `offs[i]`.
+    The host only enumerates the NON-SHARED suffix of each admitted prompt
+    here: tokens covered by a matched prefix are pure page-table pointer
+    writes and never touch the pool, where a dense slot cache would re-copy
+    the whole capacity row per admission.
     """
     new = dict(cache)
     for key in ("k", "v", "k_scale", "v_scale"):

@@ -133,6 +133,132 @@ def test_allocator_capacity_multiplier_counts_logical_pages():
 
 
 # --------------------------------------------------------------------------
+# SlotPages: the continuous scheduler's page policy, host-only
+# --------------------------------------------------------------------------
+
+def _place(sp, members, active=None):
+    """Place one group and check what every placement must hold: each
+    member's table row mirrors its run, and the graft is padded to one
+    shape per prompt length."""
+    active = [False] * len(sp.slots) if active is None else active
+    pl = sp.place(members, active)
+    total = len(members[0][1]) + sp.n_prefix
+    assert all(len(c) == len(sp.slots) * total for c in pl.graft)
+    for i, (s, _, _) in enumerate(members):
+        run = sp.slots[s] if pl.placed[i] else []
+        assert pl.rows[i, :len(run)].tolist() == run
+        assert not pl.rows[i, len(run):].any()
+    return pl
+
+
+def test_slot_pages_rejects_a_prompt_larger_than_the_pool():
+    sp = paging.SlotPages(4, slots=2, capacity=64, num_pages=4)  # 3 usable
+    # pages through the first decode write: 11 tokens -> 3, 12 -> 4
+    assert sp.need(list(range(11))) == 3
+    assert sp.need(list(range(12))) is None
+    # the default pool holds every slot's full row, plus slack and trash
+    assert paging.SlotPages(4, slots=2, capacity=64).num_pages == 1 + 2 * 17
+
+
+def test_slot_pages_fifo_head_blocks_at_the_watermark():
+    sp = paging.SlotPages(4, slots=3, capacity=32, num_pages=7, share=False)
+    _place(sp, [(0, list(range(7)), True)])        # 2 pages, 4 stay free
+    active = [True, False, False]
+    assert sp.reclaimable(active) == 2
+    assert sp.reclaimable([False] * 3) == 0
+    # the head takes 3 pages; the next one's 3 more only fit by preempting
+    reserved = sp.need(list(range(10, 21)))
+    assert reserved == 3 and sp.fits(reserved, active)
+    need = sp.need(list(range(30, 41)))
+    assert sp.fits(need + reserved, active)        # 6 <= 4 free + 2 live
+    assert not sp.fits(need + reserved, [False] * 3)
+    assert not sp.fits(need + reserved + 1, active)  # blocks: no skip-ahead
+
+
+def test_slot_pages_identical_prompts_share_the_tail_then_cow():
+    """Two identical 10-token prompts (not a page multiple) in one group:
+    the second shares all three pages, partial tail included; both decode,
+    so the first member's write page is shared and copied, and the second
+    owns the tail and unpublishes it."""
+    sp = paging.SlotPages(4, slots=2, capacity=32, num_pages=16)
+    toks = list(range(3, 13))
+    pl = _place(sp, [(0, toks, True), (1, toks, True)])
+    assert pl.placed == [True, True] and pl.victims == []
+    a, b, c = sp.slots[1]
+    assert sp.slots[0][:2] == [a, b]
+    (src, dst), = pl.cow
+    assert (src, sp.slots[0][2]) == (c, dst)
+    # the first member grafts every token, into the pages it had before
+    # the copy, so the copy has to follow the graft; the second grafts none
+    n = len(toks)
+    assert pl.graft[0][:n] == [0] * n and pl.graft[1][:n] == list(range(n))
+    assert pl.graft[2][:n] == [a] * 4 + [b] * 4 + [c] * 2
+    assert pl.graft[2][n:] == [paging.TRASH_PAGE] * n
+    sp.sample()
+    assert sp.peaks["cow_copies"] == 1 and sp.peaks["pages_shared"] == 2
+    assert sp.peaks["paged_capacity_multiplier"] == pytest.approx(6 / 4)
+    # the unpublished tail no longer matches: a third copy shares 2 pages
+    assert sp.need(toks) == 1
+
+
+def test_slot_pages_growth_under_exhaustion_preempts_the_newest():
+    sp = paging.SlotPages(4, slots=3, capacity=32, num_pages=16, share=False)
+    for s in range(3):                             # one group each: 0 oldest
+        _place(sp, [(s, list(range(10 * s, 10 * s + 3)), True)])
+    active = [True, True, True]
+    first = list(sp.slots[0])
+    writes, victims = sp.grow(0, 4, active, exhausted=lambda: True)
+    assert victims == [2] and sp.slots[2] == []
+    assert writes == [(1, sp.slots[0][1])] and sp.slots[0][:1] == first
+    active[2] = False
+    # a slot that is itself the newest is evicted before it grows
+    writes, victims = sp.grow(1, 4, active, exhausted=lambda: True)
+    assert (writes, victims) == ([], [1])
+    # a growth inside the run it has takes nothing and asks no fault
+    assert sp.grow(0, 7, [True, False, False], exhausted=None) == ([], [])
+    # a full pool preempts without any injection
+    sp = paging.SlotPages(4, slots=2, capacity=32, num_pages=3, share=False)
+    _place(sp, [(0, [1, 2], True)])
+    _place(sp, [(1, [3, 4], True)])
+    writes, victims = sp.grow(0, 4, [True, True])
+    assert victims == [1] and writes == [(1, sp.slots[0][1])]
+    # ... and evicts the growing slot itself when it is the only one left
+    assert sp.grow(0, 8, [True, False]) == ([], [0]) and sp.slots[0] == []
+
+
+def test_slot_pages_victim_search_skips_all_shared_slots():
+    sp = paging.SlotPages(4, slots=3, capacity=32, num_pages=16)
+    toks = list(range(3, 13))
+    # slots 0 and 1 hold the same three pages and nothing else (neither
+    # decodes, so nobody copies the tail); slot 2 is the newest
+    _place(sp, [(0, toks, False)])
+    assert sp.need(toks) == 1     # all three pages match; the tail is copied
+    _place(sp, [(1, toks, False)])
+    _place(sp, [(2, list(range(40, 45)), True)])
+    assert sp.slots[0] == sp.slots[1]
+    active = [True, True, True]
+    assert sp.victim(active) == 2
+    assert sp.victim(active, spared=[2]) is None   # releasing frees nothing
+    sp.release(1)
+    assert sp.victim([True, False, True], spared=[2]) == 0
+
+
+def test_slot_pages_leak_check_after_every_release():
+    sp = paging.SlotPages(4, slots=4, capacity=32, num_pages=12)
+    toks = list(range(3, 13))
+    _place(sp, [(0, toks, True), (1, toks, True)])
+    _place(sp, [(2, toks + [99, 98], True), (3, [7] * 12, False)])
+    active = [True] * 4
+    for s in range(3):
+        sp.grow(s, len(sp.slots[s]) * 4 + 5, active)
+    for s in (2, 0, 3, 1, 1):                      # a second release: no-op
+        sp.release(s)
+        sp.leak_check()
+    assert sp.alloc.pages_live() == 0
+    assert sp.alloc.free_pages() == sp.num_pages - 1
+
+
+# --------------------------------------------------------------------------
 # Paged flash kernel: page-table index math vs dense flash vs exact oracle
 # --------------------------------------------------------------------------
 
@@ -486,6 +612,22 @@ def test_copy_on_write_divergence_matches_oracle():
     assert stats["cow_copies"] >= 1, "shared partial tail never copied"
     assert stats["pages_shared"] >= 1
     assert stats["paged_capacity_multiplier"] > 1.0
+
+
+def test_identical_prompts_in_one_admission_match_the_oracle():
+    """Two identical prompts admitted in ONE group share their partial
+    tail, and the member that copies it (the first) must read the tokens
+    its own graft wrote: the copy follows the graft.  Copied the other way
+    round, the first member's long decode drifts off the oracle."""
+    cfg = get_config(ARCH, "smoke")
+    shared = np.random.default_rng(0).integers(3, cfg.vocab, size=(10,),
+                                               dtype=np.int32)
+    prompts = [shared.copy(), shared.copy()]
+    gen_lens = [12, 2]
+    stats = serve(ARCH, "smoke", batch=2, eos=NO_EOS, verbose=False,
+                  prompts=prompts, gen_lens=gen_lens, kv_page_size=4)
+    assert stats["cow_copies"] == 1
+    assert stats["outputs"] == _sequential_oracle(prompts, gen_lens)
 
 
 def test_paged_decode_is_one_flash_launch(monkeypatch):

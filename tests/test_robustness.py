@@ -11,8 +11,8 @@ The acceptance contract:
     is a no-op;
   - a preempted request — whether the pressure is real (small pool) or
     injected (fault plan) — is recomputed to BIT-IDENTICAL greedy tokens on
-    both schedulers, dense and paged, int8 KV included, and finishes with
-    status "preempted_resumed";
+    both schedulers (the continuous one always paged, the batch one paged
+    here), int8 KV included, and finishes with status "preempted_resumed";
   - request deadlines cut at decode-round boundaries with status "timeout"
     (deadline 0 deterministically yields exactly the prefill token) without
     disturbing other requests' outputs;
@@ -237,8 +237,9 @@ def test_preempt_recompute_parity_batch_paged():
 
 
 def test_preempt_fault_dense_continuous():
-    """preempt@K force-preempts with no paging at all: the dense continuous
-    scheduler must requeue and resume bit-identically too."""
+    """preempt@K force-preempts with no pool pressure at all (the default
+    pool of 16-token pages never runs short here): the continuous scheduler
+    must requeue and resume bit-identically too."""
     cfg = get_config(ARCH, "smoke")
     prompts = _prompts(3, 8, cfg.vocab, seed=13)
     gen_lens = [6, 7, 5]
@@ -320,8 +321,11 @@ def test_serve_rejects_bad_fault_and_pool_args():
         serve(ARCH, "smoke", requests=1, verbose=False, faults="bogus@1")
     with pytest.raises(ValueError, match="kv_cache='int8'"):
         serve(ARCH, "smoke", requests=1, verbose=False, faults="qscale@1")
+    # the batch scheduler pages only with a page size; the continuous one
+    # always pages, so a pool size alone is enough there
     with pytest.raises(ValueError, match="kv_page_size"):
-        serve(ARCH, "smoke", requests=1, verbose=False, pool_pages=8)
+        serve(ARCH, "smoke", requests=1, verbose=False, pool_pages=8,
+              scheduler="batch")
     with pytest.raises(ValueError, match="pool_pages"):
         serve(ARCH, "smoke", requests=1, verbose=False, kv_page_size=4,
               pool_pages=1)
@@ -449,10 +453,10 @@ def test_quantize_kv_degenerate_blocks():
 
 @pytest.mark.parametrize("page_size", [4, None])
 def test_graft_failure_rolls_back_and_retries(page_size):
-    """graft@0 fails the FIRST admission (continuous scheduler, paged and
-    dense); the scheduler must back the placement out page-exactly and serve
-    every request on retry — end-of-serve leak_check (always on for paged
-    runs) proves conservation."""
+    """graft@0 fails the FIRST admission (continuous scheduler, 4-token
+    pages and the default 16); the scheduler must back the placement out
+    page-exactly and serve every request on retry — end-of-serve leak_check
+    (always on) proves conservation."""
     cfg = get_config(ARCH, "smoke")
     prompts = _prompts(3, 8, cfg.vocab, seed=37)
     gen_lens = [4, 5, 3]

@@ -7,6 +7,8 @@ The acceptance contract for the scheduler:
     admitted BEFORE the batch drains (slot reuse);
   - greedy outputs are identical to the per-request sequential oracle (the
     per-slot ragged-position machinery changes scheduling, not semantics);
+    the continuous scheduler always pages its KV cache (16-token pages when
+    no size is given), and the oracle decodes a dense cache;
   - mean live-slot occupancy and decode-step count beat batch-at-a-time on a
     mixed-length distribution;
   - under the pallas backend the masked decode step still routes through the
@@ -334,21 +336,19 @@ def test_continuous_beats_batch_on_mixed_lengths():
 # Per-slot cache plumbing
 # --------------------------------------------------------------------------
 
-def test_insert_slots_cache_replaces_rows_and_drops_padding():
+def test_continuous_scheduler_pages_by_default():
+    """The continuous scheduler has one KV layout: without a page size it
+    serves 16-token pages, through `serve` as through the scheduler."""
+    from repro.launch import serve as serve_mod
     cfg = get_config(ARCH, "smoke")
-    cache = tf.init_cache(cfg, 3, 16, per_slot=True)
-    assert cache["pos"].shape == (3,)
-    cache = {**cache, "k": cache["k"] + 1.0, "pos": cache["pos"] + 5}
-    mini = tf.init_cache(cfg, 3, 16)
-    row_vals = jnp.asarray([2.0, 3.0, 99.0])[None, :, None, None, None]
-    mini = {**mini, "k": mini["k"] + row_vals, "pos": mini["pos"] + 9}
-    # mini row 0 -> slot 1, row 1 -> slot 2; row 2 is padding (dropped)
-    out = tf.insert_slots_cache(cache, mini, jnp.asarray([1, 2, -1]))
-    k = np.asarray(out["k"])
-    assert (k[:, 1] == 2.0).all() and (k[:, 2] == 3.0).all()  # grafted, residue cleared
-    assert (k[:, 0] == 1.0).all()  # untouched slot
-    assert not (k == 99.0).any()   # padding row dropped
-    assert np.asarray(out["pos"]).tolist() == [5, 9, 9]
+    prompts = _prompts(2, 4, cfg.vocab)
+    stats = serve_mod._serve_continuous(cfg, prompts, [2, 2], 2, 0, NO_EOS)
+    assert stats["kv_page_size"] == serve_mod.PAGE_SIZE == 16
+    assert stats["pages_live"] > 0
+    via_serve = serve(ARCH, "smoke", batch=2, prompts=prompts, gen_lens=[2, 2],
+                      eos=NO_EOS, verbose=False, scheduler="continuous")
+    assert via_serve["kv_page_size"] == 16
+    assert via_serve["outputs"] == stats["outputs"]
 
 
 def test_per_slot_cache_rejects_stateful_families():
@@ -547,8 +547,9 @@ def _run_tp_cells(scheduler, timeout=1200):
 
 def test_tp2_token_parity_continuous_composed_cells():
     """--tp 2 greedy tokens == 1-device on every composed cell:
-    {fp, int8 weights} x {dense, int8 KV} x {dense, paged} x {spec off, 4},
-    continuous scheduler, with the packed-int8 routing spy."""
+    {fp, int8 weights} x {dense, int8 KV} x {16-token (default), 4-token
+    pages} x {spec off, 4}, continuous scheduler, with the packed-int8
+    routing spy."""
     _run_tp_cells("continuous")
 
 

@@ -36,7 +36,7 @@ def _named(stats, name):
     return [(i, r) for i, r in enumerate(stats["spans"]) if r["name"] == name]
 
 
-@pytest.fixture(scope="module", params=[4, None], ids=["paged", "dense"])
+@pytest.fixture(scope="module", params=[4, None], ids=["paged", "default"])
 def run(request):
     prompts = _prompts(PLENS)
     return prompts, _serve(prompts, GENS, kv_page_size=request.param)
